@@ -66,6 +66,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes / few timing reps (CI artifact check)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         from benchmarks import common
         common.SMOKE = True

@@ -1,50 +1,29 @@
-"""JAX version-compatibility shims.
+"""Mesh and shard_map construction in one place.
 
-The repo targets the current JAX API (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``) but must
-also run on older releases (0.4.x) where ``shard_map`` lives in
-``jax.experimental.shard_map`` with a ``check_rep`` flag and mesh axis types
-do not exist yet.  Every mesh/shard_map construction in the repo goes through
-this module so the difference is absorbed in exactly one place.
+Every mesh and ``shard_map`` in the repo goes through these two helpers so
+the axis types and the replication check are chosen once: meshes are built
+with ``AxisType.Auto`` axes, and ``shard_map`` bodies run with
+``check_vma=False`` (the routing bodies insert their own psums at the
+paper's Table-2 aggregation points).
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-
-try:  # new JAX: explicit axis types on the mesh
-    from jax.sharding import AxisType
-    HAS_AXIS_TYPE = True
-except ImportError:  # old JAX: meshes are implicitly "auto"
-    AxisType = None
-    HAS_AXIS_TYPE = False
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with Auto axis types."""
     shape, axes = tuple(shape), tuple(axes)
     kwargs = {"devices": devices} if devices is not None else {}
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes), **kwargs)
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **kwargs)
-    # very old JAX: build the Mesh explicitly from the device list
-    import numpy as np
-    devs = np.asarray(devices if devices is not None
-                      else jax.devices()[: int(np.prod(shape))])
-    return jax.sharding.Mesh(devs.reshape(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes), **kwargs)
 
 
-if hasattr(jax, "shard_map"):            # new JAX (>= 0.6): jax.shard_map
-    def shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                    # old JAX: experimental, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

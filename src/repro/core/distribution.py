@@ -69,6 +69,21 @@ class DeviceModel:
                    n_vault=n_vault)
 
     @classmethod
+    def for_device_kind(cls, device_kind: str,
+                        n_vault: int) -> "DeviceModel":
+        """The planner's operating point for a JAX ``device_kind``.
+
+        "TPU v5 lite" is v5e.  The CPU is named explicitly as the stand-in
+        for a rehearsal of the chip run: a CPU mesh plans with v5e
+        coefficients, so it picks what the chip would.  Any other kind is
+        an error — its coefficients are unknown, not defaulted."""
+        if device_kind in ("TPU v5 lite", "cpu"):
+            return cls.tpu_v5e(n_vault)
+        raise ValueError(
+            f"no planner DeviceModel for device kind {device_kind!r} "
+            "(known: 'TPU v5 lite', 'cpu'); pass ExecutionPlan(device=...)")
+
+    @classmethod
     def hmc(cls, n_vault: int = 32, freq_hz: float = 312.5e6,
             pes_per_vault: int = 16,
             xbar_bytes_per_s: float = 512e9) -> "DeviceModel":

@@ -44,7 +44,7 @@ from typing import Any, Callable, Dict, Mapping, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat, kernels
+from repro import compat
 from repro.core import distribution as dist_lib
 from repro.core import em_routing as em_lib
 from repro.core import pipeline as pipeline_lib
@@ -193,29 +193,31 @@ def registered_algorithms() -> Tuple[str, ...]:
 
 # --- "dynamic" [Sabour et al. 2017] — paper Algorithm 1 --------------------
 
-def _pallas_interpret_mode() -> bool:
-    """Capability check for the Pallas backend — delegates to the shared
-    probe in ``repro.kernels`` (one helper for all pallas entry points)."""
-    return kernels.pallas_interpret_mode()
+def _differentiable_kernel(spec: RouterSpec, shape, axes) -> bool:
+    """Whether a differentiable pallas router runs the procedure
+    megakernel's custom VJP (DESIGN.md §Training) for votes of ``shape``:
+    shard-local, procedure form, and — under fusion="auto" — a backward
+    working set that fits VMEM.  Otherwise it falls back to jnp autodiff
+    (the gradient reference), never to a forward-only kernel that would
+    fail under jax.grad."""
+    from repro.kernels.routing import ops as routing_ops
+    form = routing_ops.resolve_fusion(spec.fusion, shape, spec.stream_dtype,
+                                      sharded=bool(axes))
+    return (form == "procedure" and not axes
+            and (spec.fusion == "procedure"
+                 or routing_ops.procedure_train_fits(
+                     *shape, spec.iterations, spec.stream_dtype)))
 
 
 def _dynamic_run(args, spec: RouterSpec, axes: Mapping[str, str]):
     (u_hat,) = args
     if spec.backend == "pallas" and spec.differentiable:
-        # DESIGN.md §Training: grads flow through the recompute-b custom
-        # VJP of the procedure megakernel.  _validate already rejected
-        # sharded/pipelined plans and use_approx; the only remaining
-        # resolution is the VMEM fit — when the procedure form does not
-        # fit, fall back to jnp autodiff (the gradient reference) instead
-        # of a forward-only kernel that would fail under jax.grad.
+        # _validate already rejected sharded/pipelined plans and use_approx
         from repro.kernels.routing import ops as routing_ops
-        form = routing_ops.resolve_fusion(spec.fusion, jnp.shape(u_hat),
-                                          spec.stream_dtype, sharded=False)
-        if form == "procedure":
+        if _differentiable_kernel(spec, jnp.shape(u_hat), axes):
             return routing_ops.dynamic_routing_procedure_train(
                 u_hat, iterations=spec.iterations,
-                use_approx=spec.use_approx, stream_dtype=spec.stream_dtype,
-                interpret=_pallas_interpret_mode())
+                use_approx=spec.use_approx, stream_dtype=spec.stream_dtype)
         cfg = routing_lib.RoutingConfig(
             iterations=spec.iterations, use_approx=spec.use_approx)
         return routing_lib.dynamic_routing(u_hat, cfg)
@@ -230,8 +232,7 @@ def _dynamic_run(args, spec: RouterSpec, axes: Mapping[str, str]):
             # the Table-2 aggregation points (DESIGN.md §Sharded-fused)
             return routing_ops.dynamic_routing_fused_sharded(
                 u_hat, axes=axes, iterations=spec.iterations,
-                use_approx=spec.use_approx, stream_dtype=spec.stream_dtype,
-                interpret=_pallas_interpret_mode())
+                use_approx=spec.use_approx, stream_dtype=spec.stream_dtype)
         if form == "procedure":
             # whole-procedure megakernel (DESIGN.md §Procedure-fused);
             # int8 û streaming and early exit live only here
@@ -239,12 +240,10 @@ def _dynamic_run(args, spec: RouterSpec, axes: Mapping[str, str]):
             return routing_ops.dynamic_routing_procedure_fused(
                 u_hat, iterations=spec.iterations,
                 use_approx=spec.use_approx, stream_dtype=spec.stream_dtype,
-                early_exit_eps=spec.early_exit_eps,
-                interpret=_pallas_interpret_mode())
+                early_exit_eps=spec.early_exit_eps)
         return routing_ops.dynamic_routing_fused(
             u_hat, iterations=spec.iterations, use_approx=spec.use_approx,
-            stream_dtype=spec.stream_dtype,
-            interpret=_pallas_interpret_mode())
+            stream_dtype=spec.stream_dtype)
     cfg = routing_lib.RoutingConfig(
         iterations=spec.iterations, use_approx=spec.use_approx,
         axes=tuple(sorted(axes.items())) or None)
@@ -273,8 +272,7 @@ def _em_run(args, spec: RouterSpec, axes: Mapping[str, str]):
             beta_a=spec.option("beta_a", 1.0),
             beta_u=spec.option("beta_u", 1.0),
             inv_temp=spec.option("inv_temp", 1.0),
-            eps=spec.option("eps", 1e-9),
-            interpret=_pallas_interpret_mode())
+            eps=spec.option("eps", 1e-9))
     cfg = em_lib.EMRoutingConfig(
         iterations=spec.iterations,
         beta_a=spec.option("beta_a", 1.0),
@@ -480,8 +478,10 @@ def plan_axes(spec: RouterSpec, plan: ExecutionPlan,
     s = plan.rp_shape or derive_rp_shape(spec.algorithm, shapes,
                                          spec.iterations)
     # an explicit DeviceModel keeps its own operating point (e.g. the
-    # paper's 32-vault HMC); only the default model is sized to the mesh.
-    dev = plan.device or dist_lib.DeviceModel.tpu_v5e(n)
+    # paper's 32-vault HMC); the default model follows the mesh's device
+    # kind and is sized to the mesh.
+    dev = plan.device or dist_lib.DeviceModel.for_device_kind(
+        mesh.devices.flat[0].device_kind, n)
     extents = {"B": s.n_b, "L": s.n_l, "H": s.n_h}
     feasible = [d for d in algo.sharded_dims if extents[d] % n == 0]
     if not feasible:
@@ -582,18 +582,18 @@ class Router:
                 and not deep_edge):
             return None, self.spec.stream_dtype, False, None
         from repro.kernels.routing import ops as routing_ops
+        if self.spec.differentiable:
+            # _dynamic_run's differentiable dispatch: the custom VJP, or
+            # jnp autodiff (reported as the jnp 4-tuple)
+            if _differentiable_kernel(self.spec,
+                                      shapes[0] if shapes else None, axes):
+                return "procedure", self.spec.stream_dtype, True, None
+            return None, None, False, None
         form = routing_ops.resolve_fusion(self.spec.fusion,
                                           shapes[0] if shapes else None,
                                           self.spec.stream_dtype,
                                           sharded=bool(axes),
                                           early_exit=early_exit)
-        if self.spec.differentiable:
-            # mirrors _dynamic_run's differentiable dispatch: the custom
-            # VJP exists for the procedure form only; anything else falls
-            # back to jnp autodiff (reported as the jnp 4-tuple).
-            if form == "procedure" and not axes:
-                return "procedure", self.spec.stream_dtype, True, None
-            return None, None, False, None
         return (form, self.spec.stream_dtype, False,
                 self.spec.early_exit_eps)
 

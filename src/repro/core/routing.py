@@ -117,9 +117,7 @@ def dynamic_routing(u_hat: jax.Array, cfg: RoutingConfig = RoutingConfig()
     is the routed H-capsule output.
     """
     if cfg.fused:
-        from repro import kernels
         from repro.kernels.routing import ops as routing_ops
-        interpret = kernels.pallas_interpret_mode()
         axes = dict(cfg.axes or ())
         if not axes and cfg.sharded_dim is not None:
             axes = {cfg.sharded_dim: cfg.axis_name}
@@ -130,10 +128,9 @@ def dynamic_routing(u_hat: jax.Array, cfg: RoutingConfig = RoutingConfig()
             # cannot insert cross-shard psums.)
             return routing_ops.dynamic_routing_fused_sharded(
                 u_hat, axes=axes, iterations=cfg.iterations,
-                use_approx=cfg.use_approx, interpret=interpret)
+                use_approx=cfg.use_approx)
         return routing_ops.dynamic_routing_fused(
-            u_hat, iterations=cfg.iterations, use_approx=cfg.use_approx,
-            interpret=interpret)
+            u_hat, iterations=cfg.iterations, use_approx=cfg.use_approx)
 
     v, _ = _scan_routing(u_hat, cfg)
     return v

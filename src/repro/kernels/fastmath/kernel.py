@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.approx import (EXP_AVG, EXP_RECOVERY, INV_SQRT_RECOVERY,
                                LOG2E, RECIP_RECOVERY, _F32_BIAS, _F32_MANT)
+from repro.kernels import resolve_interpret
 
 _OPS = ("exp", "inv_sqrt", "reciprocal")
 
@@ -55,7 +56,7 @@ def _fastmath_kernel(x_ref, o_ref, *, op: str, recover: bool):
                                              "block_cols", "interpret"))
 def fastmath_2d(x: jax.Array, *, op: str, recover: bool = True,
                 block_rows: int = 256, block_cols: int = 512,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """Apply a PE-approximated special function over a 2D array."""
     R, Ccols = x.shape
     br = min(block_rows, R)
@@ -68,5 +69,5 @@ def fastmath_2d(x: jax.Array, *, op: str, recover: bool = True,
         in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, Ccols), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x.astype(jnp.float32))

@@ -21,7 +21,8 @@ def _as_2d(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
     return flat.reshape(rows, cols), shape
 
 
-def _apply(x: jax.Array, op: str, recover: bool, interpret: bool) -> jax.Array:
+def _apply(x: jax.Array, op: str, recover: bool,
+           interpret: bool | None) -> jax.Array:
     x2d, shape = _as_2d(x)
     r, c = x2d.shape
     out = fastmath_2d(x2d, op=op, recover=recover,
@@ -34,15 +35,18 @@ def _apply(x: jax.Array, op: str, recover: bool, interpret: bool) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("recover", "interpret"))
-def exp(x: jax.Array, recover: bool = True, interpret: bool = True):
+def exp(x: jax.Array, recover: bool = True,
+        interpret: bool | None = None):
     return _apply(x, "exp", recover, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("recover", "interpret"))
-def inv_sqrt(x: jax.Array, recover: bool = True, interpret: bool = True):
+def inv_sqrt(x: jax.Array, recover: bool = True,
+             interpret: bool | None = None):
     return _apply(x, "inv_sqrt", recover, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("recover", "interpret"))
-def reciprocal(x: jax.Array, recover: bool = True, interpret: bool = True):
+def reciprocal(x: jax.Array, recover: bool = True,
+               interpret: bool | None = None):
     return _apply(x, "reciprocal", recover, interpret)

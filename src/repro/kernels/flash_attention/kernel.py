@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 _NEG_INF = -1e30
 
 
@@ -75,7 +77,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
                     block_k: int = 128, scale: float | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D).  Returns (B, Hq, S, D)."""
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]
@@ -109,7 +111,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
@@ -262,7 +264,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "scale", "interpret"))
 def flash_attention_fwd_lse(q, k, v, *, causal=True, block_q=128,
-                            block_k=128, scale=None, interpret=True):
+                            block_k=128, scale=None, interpret=None):
     """Forward returning (o, lse) for the training path.
     q: (B,Hq,S,D); k,v: (B,Hkv,S,D) -> o (B,Hq,S,D), lse (B,Hq,S) fp32."""
     B, Hq, S, D = q.shape
@@ -294,14 +296,14 @@ def flash_attention_fwd_lse(q, k, v, *, causal=True, block_q=128,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "scale", "interpret"))
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, block_q=128,
-                        block_k=128, scale=None, interpret=True):
+                        block_k=128, scale=None, interpret=None):
     """Backward: returns (dq (B,Hq,S,D), dk, dv (B,Hkv,S,D))."""
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]
@@ -332,7 +334,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, block_q=128,
                                lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
 
     # per-Q-head dk/dv (grid: q innermost so each (h,kb) block's revisits
@@ -360,7 +362,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, block_q=128,
                    jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
     dk = dk_h.reshape(B, Hkv, group, S, D).sum(2).astype(k.dtype)
     dv = dv_h.reshape(B, Hkv, group, S, D).sum(2).astype(v.dtype)

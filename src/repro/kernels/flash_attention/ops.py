@@ -20,7 +20,8 @@ def _block(S: int) -> int:
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True, interpret: bool = True) -> jax.Array:
+              causal: bool = True,
+              interpret: bool | None = None) -> jax.Array:
     """Pick MXU-aligned blocks (<=128) that divide S, then call the kernel."""
     block = _block(q.shape[2])
     return flash_attention(q, k, v, causal=causal, block_q=block,
@@ -28,7 +29,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def attention_train(q, k, v, causal: bool = True, interpret: bool = True):
+def attention_train(q, k, v, causal: bool = True,
+                    interpret: bool | None = None):
     """Differentiable flash attention (FlashAttention-2 fwd/bwd kernels).
 
     q: (B, Hq, S, D); k, v: (B, Hkv, S, D) with GQA Hq % Hkv == 0.

@@ -39,122 +39,197 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import routing as routing_lib
-from repro.kernels.routing import ref
-from repro.kernels.routing.kernel import (em_stage_estep, em_stage_stats,
+from repro.kernels.routing.kernel import (VMEM_LIMIT_BYTES, em_stage_estep,
+                                          em_stage_stats,
                                           routing_iteration_fused,
                                           routing_procedure_bwd,
                                           routing_procedure_fused,
                                           routing_stage_update,
                                           routing_stage_update_fold,
-                                          routing_stage_votes)
+                                          routing_stage_votes, squash_packed)
 # spec-level vocabulary lives in vocab.py (importable without pallas —
 # core.router._validate uses it); re-exported here for kernel code and
 # historical callers.
 from repro.kernels.routing.vocab import (FUSION_LEVELS, STREAM_DTYPES,
                                          stream_itemsize as _stream_itemsize)
 
-# û-block VMEM budget for automatic l_tile selection (per buffer; the
-# procedure kernel double-buffers the stream, see procedure_vmem_bytes).
-_U_TILE_BUDGET = 8 * 2 ** 20
-# Total VMEM budget for the procedure megakernel's working set — ~16 MB per
-# v5e core, minus slack for the compiler's own buffers.
-PROCEDURE_VMEM_BUDGET = 14 * 2 ** 20
+# VMEM model (DESIGN.md §Procedure-fused).  Every routing kernel runs under
+# one scoped-VMEM limit (kernel.VMEM_LIMIT_BYTES, passed to Mosaic as
+# vmem_limit_bytes); the tile pickers below size the L tile against the
+# same budget.  Buffers are counted as Mosaic lays them out: a (rows, cols)
+# block occupies whole (sublane, 128-lane) tiles — sublane 8 for fp32, 16
+# for bf16, 32 for int8 — so a lane-packed û row of H·C = 160 takes 256
+# lanes and an (L, H=10) logit array takes 128.
+PROCEDURE_VMEM_BUDGET = VMEM_LIMIT_BYTES
+_LANES = 128
+# Beyond the buffers counted below, a compile for v5e needs Mosaic's
+# internal scratch (~1 MiB) and the kernels' (B, H·C)-sized temporaries
+# (squash, segment sums: ~16 of them live) — measured at Caps-MN1 and
+# Caps-EN3 widths, B = 8 and 100, by bisecting vmem_limit_bytes.
+_MOSAIC_SCRATCH = 2 ** 20
+_VOTE_TEMPS = 16
+# fp32 (B, L_t, H·C)-sized in-kernel temporaries per kernel, in units of
+# one fp32 û block (the dequantised / up-cast û and the products the
+# reductions consume), measured the same way.
+_FWD_TEMPS = 1.25          # procedure megakernel
+_EARLY_EXIT_TEMPS = 1.25   # + the early-exit branch's live values
+_BWD_TEMPS = 2.5           # recompute-b backward megakernel
+_STAGE_TEMPS = 1.25        # per-iteration and stage-split kernels
+_EM_TEMPS = 3.0            # EM M-step / E-step kernels
+
+
+def sublane_tile(stream_dtype: str) -> int:
+    """Rows per (sublane, 128-lane) tile at the stream dtype: 8 fp32,
+    16 bf16, 32 int8 — the alignment an L tile of û must keep."""
+    return 32 // _stream_itemsize(stream_dtype)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _vmem_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """Bytes a (rows, cols) VMEM buffer occupies in whole tiles."""
+    return (_round_up(rows, 32 // itemsize) * _round_up(cols, _LANES)
+            * itemsize)
+
+
+def _slack(B: int, H: int, C: int) -> int:
+    return _MOSAIC_SCRATCH + _VOTE_TEMPS * _vmem_bytes(B, H * C)
+
+
+def _u_row_bytes(B: int, H: int, C: int, itemsize: int = 4) -> int:
+    """One L row of a lane-packed (B, L_t, H·C) block."""
+    return B * _round_up(H * C, _LANES) * itemsize
 
 
 def pick_l_tile(L: int, bytes_budget: int, row_bytes: int,
-                preferred: int = 128) -> int:
-    """Largest divisor of L that is <= preferred and fits the VMEM budget.
+                preferred: int = 128, align: int = 8) -> int:
+    """Largest legal L tile <= preferred that fits the VMEM budget.
 
-    Divisors are enumerated in O(√L) — each i <= √L with L % i == 0 yields
-    the pair (i, L // i) — instead of the old 1..L scan (L is 10³..10⁴ for
-    the Table-1 networks and this runs at every trace)."""
+    Legal tiles are the divisors of L that are multiples of ``align`` (the
+    stream dtype's sublane tiling, :func:`sublane_tile`) and L itself — a
+    block spanning the whole dim is always legal.  When no legal tile fits,
+    the smallest legal one is returned (the caller's fit check then
+    decides).  Divisors are enumerated in O(√L)."""
     cap = max(1, bytes_budget // max(row_bytes, 1))
     lim = min(preferred, cap)
-    best = 1
+    legal = set()
     i = 1
     while i * i <= L:
         if L % i == 0:
-            for d in (i, L // i):
-                if best < d <= lim:
-                    best = d
+            legal.update(d for d in (i, L // i) if d % align == 0 or d == L)
         i += 1
-    return best
-
-
-_pick_l_tile = pick_l_tile    # back-compat alias (pre-PR-4 private name)
+    fitting = [d for d in legal if d <= lim]
+    return max(fitting) if fitting else min(legal)
 
 
 def auto_l_tile(B: int, L: int, H: int, C: int, stream_dtype: str) -> int:
     """The l_tile the per-iteration / stage-split wrappers auto-pick —
-    public so benchmarks can record the exact provenance they ran with."""
-    return pick_l_tile(L, _U_TILE_BUDGET,
-                       B * H * C * _stream_itemsize(stream_dtype))
+    public so benchmarks can record the exact provenance they ran with.
+    Per L row: the double-buffered û block, its fp32 temporaries and up to
+    three double-buffered (L_t, H) logit/coupling blocks."""
+    isz = _stream_itemsize(stream_dtype)
+    row = (2 * _u_row_bytes(B, H, C, isz)
+           + _STAGE_TEMPS * _u_row_bytes(B, H, C)
+           + 3 * 2 * _round_up(H, _LANES) * 4)
+    fixed = 4 * _vmem_bytes(B, H * C) + _slack(B, H, C)
+    return pick_l_tile(L, PROCEDURE_VMEM_BUDGET - fixed, int(row),
+                       align=sublane_tile(stream_dtype))
 
 
-_auto_l_tile = auto_l_tile    # internal alias
+def em_l_tile(B: int, L: int, H: int, C: int) -> int:
+    """l_tile for the EM stage kernels (fp32 votes): per L row the
+    double-buffered votes block, the double-buffered (B, L_t, H)
+    responsibility block and the fp32 temporaries."""
+    row = (2 * _u_row_bytes(B, H, C) + _EM_TEMPS * _u_row_bytes(B, H, C)
+           + 2 * B * _round_up(H, _LANES) * 4)
+    fixed = 8 * _vmem_bytes(B, H * C) + _slack(B, H, C)
+    return pick_l_tile(L, PROCEDURE_VMEM_BUDGET - fixed, int(row))
+
+
+def _procedure_terms(B: int, L: int, H: int, C: int, stream_dtype: str,
+                     early_exit: bool):
+    """(bytes per L row of the tile, fixed bytes) of the megakernel."""
+    isz = _stream_itemsize(stream_dtype)
+    temps = _FWD_TEMPS + (_EARLY_EXIT_TEMPS if early_exit else 0.0)
+    row = 2 * _u_row_bytes(B, H, C, isz) + temps * _u_row_bytes(B, H, C)
+    fixed = (_vmem_bytes(L, H) * (2 if early_exit else 1)   # b (+ frozen c)
+             + 4 * _vmem_bytes(B, H * C)                     # v, s, out x2
+             + _slack(B, H, C))
+    return int(row), fixed
 
 
 def procedure_vmem_bytes(B: int, L: int, H: int, C: int, l_tile: int,
                          stream_dtype: str = "fp32",
                          early_exit: bool = False) -> int:
-    """VMEM working set of the whole-procedure megakernel: the
-    double-buffered û stream block plus the resident b/v/s scratch and the
-    output block (all fp32 regardless of stream dtype).  Early exit adds
-    the (L,H) frozen-coupling scratch plus the per-tile converged flags
-    (DESIGN.md §Quantized-routing); the int8 per-tile scale operand and the
-    4-byte work counter are sub-KB and folded into the flag term."""
-    u_blk = B * l_tile * H * C * _stream_itemsize(stream_dtype)
-    total = 2 * u_blk + L * H * 4 + 3 * B * H * C * 4
-    if early_exit:
-        total += L * H * 4 + (L // max(l_tile, 1)) * 4
-    return total
+    """VMEM working set of the whole-procedure megakernel, tile padding
+    counted: the double-buffered û stream block and its fp32 temporaries,
+    the resident b/v/s scratch and the output block (all fp32 regardless of
+    stream dtype), plus Mosaic's own scratch.  Early exit adds the (L,H)
+    frozen-coupling scratch and the live values of its branch; the int8
+    scales, the converged flags and the work counter live in SMEM."""
+    row, fixed = _procedure_terms(B, L, H, C, stream_dtype, early_exit)
+    return _round_up(l_tile, sublane_tile(stream_dtype)) * row + fixed
 
 
 def procedure_l_tile(B: int, L: int, H: int, C: int,
                      stream_dtype: str = "fp32", *,
                      early_exit: bool = False) -> int:
-    """l_tile for the megakernel: unlike the per-iteration pick, the û
-    block budget *shrinks* to whatever the total procedure budget leaves
-    after the resident b/v/s scratch — so a cap-bound (large B·H·C) shape
-    gets a smaller tile instead of disqualifying procedure fusion.  Early
-    exit doubles the logit-sized fixed cost (frozen-c scratch); the
-    l_tile-dependent flag array is <= L·4 bytes and ignored here (it would
-    make the pick circular)."""
-    fixed = L * H * 4 * (2 if early_exit else 1) + 3 * B * H * C * 4
-    budget = min(_U_TILE_BUDGET,
-                 max(0, PROCEDURE_VMEM_BUDGET - fixed) // 2)
-    return pick_l_tile(L, budget, B * H * C * _stream_itemsize(stream_dtype))
+    """l_tile for the megakernel: the û rows get whatever the budget
+    leaves after the resident b/v/s scratch — so a cap-bound (large B·H·C)
+    shape gets a smaller tile instead of disqualifying procedure fusion."""
+    row, fixed = _procedure_terms(B, L, H, C, stream_dtype, early_exit)
+    return pick_l_tile(L, PROCEDURE_VMEM_BUDGET - fixed, row,
+                       align=sublane_tile(stream_dtype))
+
+
+def _procedure_bwd_terms(B: int, L: int, H: int, C: int, iterations: int,
+                         stream_dtype: str):
+    isz = _stream_itemsize(stream_dtype)
+    T = iterations
+    row = (4 * _u_row_bytes(B, H, C, isz)            # û + ∂û, double-buffered
+           + _BWD_TEMPS * _u_row_bytes(B, H, C))
+    fixed = ((2 * T + 1) * _vmem_bytes(L, H)         # b/∂b + c_t + ∂b_t snaps
+             + (3 * T + 4) * _vmem_bytes(B, H * C)   # v, s, s_t/v_{t-1}/∂s_t,
+             + _slack(B, H, C))                      # ∂v block x2
+    return int(row), fixed
 
 
 def procedure_bwd_vmem_bytes(B: int, L: int, H: int, C: int, l_tile: int,
                              iterations: int = 3,
                              stream_dtype: str = "fp32") -> int:
     """VMEM working set of the backward megakernel
-    (kernel.py::routing_procedure_bwd): double-buffered û *and* ∂û stream
-    blocks, the b/v/s scratch (reused as ∂b / ∂v carry / ∂v accumulator in
-    the reverse phase) plus the per-iteration snapshots — 2T logit-sized
-    (c_t, ∂b_t) and 3T vote-sized (s_t, v_{t-1}, ∂s_t) — and the (B,H,C)
+    (kernel.py::routing_procedure_bwd), tile padding counted:
+    double-buffered û *and* ∂û stream blocks with their fp32 temporaries,
+    the b/v/s scratch (reused as ∂b / ∂v carry / ∂v accumulator in the
+    reverse phase) plus the per-iteration snapshots — 2T logit-sized
+    (c_t, ∂b_t) and 3T vote-sized (s_t, v_{t-1}, ∂s_t) — and the (B,H·C)
     cotangent block."""
-    u_blk = B * l_tile * H * C * _stream_itemsize(stream_dtype)
-    T = iterations
-    return (4 * u_blk                            # û + ∂û, double-buffered
-            + (2 * T + 1) * L * H * 4            # b/∂b + c_t + ∂b_t snaps
-            + (3 * T + 3) * B * H * C * 4)       # v,s,∂g + s_t/v_{t-1}/∂s_t
+    row, fixed = _procedure_bwd_terms(B, L, H, C, iterations, stream_dtype)
+    return _round_up(l_tile, sublane_tile(stream_dtype)) * row + fixed
 
 
 def procedure_train_l_tile(B: int, L: int, H: int, C: int,
                            iterations: int = 3,
                            stream_dtype: str = "fp32") -> int:
-    """l_tile for the *differentiable* megakernel: like procedure_l_tile
-    but the fixed VMEM cost is the backward's (per-iteration snapshots
-    included) and the tile budget splits four ways (û and ∂û blocks, each
-    double-buffered) — the forward reuses the same tile so fwd and bwd
-    share one stream layout."""
-    T = iterations
-    fixed = (2 * T + 1) * L * H * 4 + (3 * T + 3) * B * H * C * 4
-    budget = min(_U_TILE_BUDGET,
-                 max(0, PROCEDURE_VMEM_BUDGET - fixed) // 4)
-    return pick_l_tile(L, budget, B * H * C * _stream_itemsize(stream_dtype))
+    """l_tile for the *differentiable* megakernel: sized against the
+    backward's working set (the larger of the two) — the forward reuses
+    the same tile so fwd and bwd share one stream layout."""
+    row, fixed = _procedure_bwd_terms(B, L, H, C, iterations, stream_dtype)
+    return pick_l_tile(L, PROCEDURE_VMEM_BUDGET - fixed, row,
+                       align=sublane_tile(stream_dtype))
+
+
+def procedure_train_fits(B: int, L: int, H: int, C: int,
+                         iterations: int = 3,
+                         stream_dtype: str = "fp32") -> bool:
+    """Whether the differentiable megakernel fits VMEM: its backward — the
+    larger working set — at :func:`procedure_train_l_tile`."""
+    l_tile = procedure_train_l_tile(B, L, H, C, iterations, stream_dtype)
+    return (procedure_bwd_vmem_bytes(B, L, H, C, l_tile, iterations,
+                                     stream_dtype)
+            <= PROCEDURE_VMEM_BUDGET)
 
 
 def resolve_fusion(fusion: str, shape, stream_dtype: str = "fp32",
@@ -360,24 +435,26 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
 def dynamic_routing_fused(u_hat: jax.Array, *, iterations: int = 3,
                           use_approx: bool = False, l_tile: int | None = None,
                           stream_dtype: str = "fp32",
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """Full routing procedure built from the fused per-iteration kernel.
 
     u_hat: (B, L, H, C) -> v: (B, H, C).  û crosses HBM→VMEM once per
     iteration (at the stream dtype; accumulation is fp32); squash (Eq.3,
-    O(B·H·C)) runs outside the kernel.
+    O(B·H·C)) runs outside the kernel, through the megakernel's own
+    ``squash_packed``.
     """
     u_hat = u_hat.astype(STREAM_DTYPES[stream_dtype])
     B, L, H, C = u_hat.shape
     if l_tile is None:
-        l_tile = _auto_l_tile(B, L, H, C, stream_dtype)
+        l_tile = auto_l_tile(B, L, H, C, stream_dtype)
     b = jnp.zeros((L, H), jnp.float32)
     v = jnp.zeros((B, H, C), jnp.float32)
     for _ in range(iterations):
         s, b = routing_iteration_fused(u_hat, b, v, l_tile=l_tile,
                                        use_approx=use_approx,
                                        interpret=interpret)
-        v = ref.squash(s, use_approx)
+        v = squash_packed(s.reshape(B, H * C), H, C,
+                          use_approx).reshape(B, H, C)
     return v
 
 
@@ -441,7 +518,7 @@ def dynamic_routing_procedure_fused(u_hat: jax.Array, *, iterations: int = 3,
                                     use_approx: bool = False,
                                     l_tile: int | None = None,
                                     stream_dtype: str = "fp32",
-                                    interpret: bool = True,
+                                    interpret: bool | None = None,
                                     early_exit_eps: float | None = None
                                     ) -> jax.Array:
     """Whole-procedure megakernel (DESIGN.md §Procedure-fused).
@@ -470,7 +547,7 @@ def dynamic_routing_procedure_stats(u_hat: jax.Array, *, iterations: int = 3,
                                     use_approx: bool = False,
                                     l_tile: int | None = None,
                                     stream_dtype: str = "fp32",
-                                    interpret: bool = True,
+                                    interpret: bool | None = None,
                                     early_exit_eps: float | None = None):
     """:func:`dynamic_routing_procedure_fused` plus the work counter.
 
@@ -527,7 +604,7 @@ def dynamic_routing_procedure_train(u_hat: jax.Array, *, iterations: int = 3,
                                     use_approx: bool = False,
                                     l_tile: int | None = None,
                                     stream_dtype: str = "fp32",
-                                    interpret: bool = True) -> jax.Array:
+                                    interpret: bool | None = None) -> jax.Array:
     """Differentiable whole-procedure megakernel (DESIGN.md §Training).
 
     Same contract as :func:`dynamic_routing_procedure_fused` — u_hat
@@ -587,7 +664,7 @@ def dynamic_routing_fused_sharded(u_hat: jax.Array, *,
                                   use_approx: bool = False,
                                   l_tile: int | None = None,
                                   stream_dtype: str = "fp32",
-                                  interpret: bool = True) -> jax.Array:
+                                  interpret: bool | None = None) -> jax.Array:
     """Stage-split fused routing with cross-shard aggregation (Table 2).
 
     u_hat: the *per-shard* (B, L, H, C) block — this function runs inside a
@@ -611,7 +688,7 @@ def dynamic_routing_fused_sharded(u_hat: jax.Array, *,
     u_hat = u_hat.astype(STREAM_DTYPES[stream_dtype])
     B, L, H, C = u_hat.shape
     if l_tile is None:
-        l_tile = _auto_l_tile(B, L, H, C, stream_dtype)
+        l_tile = auto_l_tile(B, L, H, C, stream_dtype)
     b_axis, h_axis, l_axis = axes.get("B"), axes.get("H"), axes.get("L")
     # the fold needs the complete db (no pending B psum) and a shard-local
     # softmax denominator (no H psum) inside the kernel
@@ -643,7 +720,7 @@ def em_routing_fused(votes: jax.Array, a_in: jax.Array, *,
                      iterations: int = 3, beta_a: float = 1.0,
                      beta_u: float = 1.0, inv_temp: float = 1.0,
                      eps: float = 1e-9, l_tile: int | None = None,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """EM routing via the stage-split Pallas kernels (paper §2.2 generality).
 
     votes: per-shard (B, L, H, C); a_in: per-shard (B, L).  ``axes`` maps
@@ -663,7 +740,7 @@ def em_routing_fused(votes: jax.Array, a_in: jax.Array, *,
     votes = votes.astype(jnp.float32)
     B, L, H, C = votes.shape
     if l_tile is None:
-        l_tile = _auto_l_tile(B, L, H, C, "fp32")
+        l_tile = em_l_tile(B, L, H, C)
     l_axis = axes.get("L")
     r = jnp.full((B, L, H), 1.0 / H, jnp.float32)
     mu = jnp.zeros((B, H, C), jnp.float32)

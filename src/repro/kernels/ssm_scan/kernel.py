@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _ssm_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, y_ref, h_ref, *,
                 chunk: int):
@@ -48,7 +50,7 @@ def _ssm_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, y_ref, h_ref, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                    C: jax.Array, D: jax.Array, *, chunk: int = 64,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """x, dt: (Bt, T, Din); A: (Din, N); B, C: (Bt, T, N); D: (Din,).
 
     Returns y: (Bt, T, Din).  VMEM per step ≈ chunk·(2·Din + 2·N)·4B plus the
@@ -74,5 +76,5 @@ def selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, Din), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((Bt, T, Din), x.dtype),
         scratch_shapes=[pltpu.VMEM((Din, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, A, B, C, D.reshape(1, Din))

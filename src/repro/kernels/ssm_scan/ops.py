@@ -7,7 +7,8 @@ from repro.kernels.ssm_scan.kernel import selective_scan
 
 
 def scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-         C: jax.Array, D: jax.Array, *, interpret: bool = True) -> jax.Array:
+         C: jax.Array, D: jax.Array, *,
+         interpret: bool | None = None) -> jax.Array:
     T = x.shape[1]
     chunk = 64
     while T % chunk:

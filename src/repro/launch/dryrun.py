@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.configs as C
+from repro.compile_cache import enable_compile_cache
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import lm
@@ -221,6 +222,7 @@ def main() -> None:
                     help="reduced configs + (2,4)/(2,2,4) mesh (CI check)")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cells = []
     archs = C.list_archs() if (args.all or not args.arch) else [args.arch]

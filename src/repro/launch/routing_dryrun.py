@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import compat
+from repro.compile_cache import enable_compile_cache
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
 from repro.core import distribution as D
 from repro.core import routing
@@ -234,6 +235,7 @@ def main():
     ap.add_argument("--batch", type=int, default=POD_BATCH)
     ap.add_argument("--skip-full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     for name in args.configs:
         print(f"[{name}]", flush=True)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 import repro.configs as C
 from repro import compat
+from repro.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.runtime import serve_loop, sharding as sh
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.get_smoke_config(args.arch) if args.smoke \
         else C.get_config(args.arch)

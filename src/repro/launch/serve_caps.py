@@ -51,6 +51,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS, smoke_caps
 from repro.core.router import RouterSpec
 from repro.data.synthetic import SyntheticCapsDataset
@@ -336,6 +337,7 @@ def main():
                     help="FaultPlan.generate seed (same seed = same "
                          "schedule, every run)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         caps_cfg = smoke_caps()

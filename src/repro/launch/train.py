@@ -35,6 +35,7 @@ from repro import compat                           # noqa: E402
 
 import repro.configs as C                          # noqa: E402
 from repro import checkpoint as ck                 # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.data.synthetic import (SyntheticLMDataset,      # noqa: E402
                                   lm_batch_iterator)
 from repro.models import lm                        # noqa: E402
@@ -70,6 +71,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.get_smoke_config(args.arch) if args.smoke \
         else C.get_config(args.arch)

@@ -1,9 +1,5 @@
-"""Mesh construction helpers (Auto axis types pinned for GSPMD).
-
-``AxisType`` does not exist on older JAX releases; construction is delegated
-to ``repro.compat.make_mesh`` which guards the import and falls back to an
-explicit-mesh code path, so the same call works on both old and new JAX.
-"""
+"""Mesh construction helpers (Auto axis types pinned for GSPMD, via
+``repro.compat.make_mesh``)."""
 from __future__ import annotations
 
 from typing import Sequence

@@ -115,3 +115,14 @@ def test_estimated_time_consistent():
     for d in D.DIMS:
         assert D.estimated_time_s(d, s, dev) == pytest.approx(
             1.0 / D.execution_score(d, s, dev))
+
+
+def test_device_model_follows_device_kind():
+    """The planner's default operating point is looked up by JAX
+    device_kind: v5e ("TPU v5 lite") and the CPU rehearsal stand-in get the
+    v5e coefficients; an unknown TPU kind is an error, not a default."""
+    want = D.DeviceModel.tpu_v5e(4)
+    assert D.DeviceModel.for_device_kind("TPU v5 lite", 4) == want
+    assert D.DeviceModel.for_device_kind("cpu", 4) == want
+    with pytest.raises(ValueError, match="no planner DeviceModel"):
+        D.DeviceModel.for_device_kind("TPU v4", 4)

@@ -13,6 +13,7 @@ except ImportError:   # vendored fallback (tests/_hypothesis_compat.py)
 
 from _gradcheck import (check_grad_finite_difference, check_vjp_parity,
                         grad_tol)
+from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
 from repro.core import approx
 from repro.kernels.fastmath import ops as fm_ops
 from repro.kernels.fastmath import ref as fm_ref
@@ -134,10 +135,11 @@ def test_routing_procedure_matches_iteration_fused(key):
 
 def test_routing_procedure_non_divisible_l_fallback(key):
     """L=136 does not divide by the preferred 128-tile: the auto picker
-    must fall back to a real divisor (68) and stay correct; an explicit
+    must fall back to a real divisor that keeps the fp32 sublane tiling
+    (8 — 68 and 34 are not multiples of 8) and stay correct; an explicit
     non-divisor l_tile fails loudly."""
     u_hat = jax.random.normal(key, (2, 136, 6, 8))
-    assert rt_ops.pick_l_tile(136, 8 * 2 ** 20, 2 * 6 * 8 * 4) == 68
+    assert rt_ops.pick_l_tile(136, 8 * 2 ** 20, 2 * 6 * 8 * 4) == 8
     v_k = rt_ops.dynamic_routing_procedure_fused(u_hat, iterations=3)
     want = rt_ref.dynamic_routing_ref(u_hat, 3)
     np.testing.assert_allclose(v_k, want, rtol=1e-5, atol=1e-5)
@@ -147,20 +149,49 @@ def test_routing_procedure_non_divisible_l_fallback(key):
 
 
 def test_pick_l_tile_matches_bruteforce():
-    """The O(sqrt L) divisor enumeration == the old 1..L scan."""
-    def brute(L, budget, row, preferred=128):
+    """The O(sqrt L) divisor enumeration == a 1..L scan over the legal
+    tiles (sublane-aligned divisors, or L itself), for every alignment."""
+    def brute(L, budget, row, preferred=128, align=8):
         cap = max(1, budget // max(row, 1))
-        best = 1
-        for t in range(1, L + 1):
-            if L % t == 0 and t <= min(preferred, cap):
-                best = t
-        return best
+        legal = [t for t in range(1, L + 1)
+                 if L % t == 0 and (t % align == 0 or t == L)]
+        fitting = [t for t in legal if t <= min(preferred, cap)]
+        return max(fitting) if fitting else min(legal)
 
     for L in (1, 2, 17, 64, 96, 136, 576, 1152, 2304):
         for budget, row in ((8 * 2 ** 20, 2 * 6 * 8 * 4),
                             (4096, 512), (64, 512)):
-            assert rt_ops.pick_l_tile(L, budget, row) == brute(L, budget,
-                                                               row)
+            for align in (8, 16, 32):
+                assert rt_ops.pick_l_tile(L, budget, row, align=align) \
+                    == brute(L, budget, row, align=align)
+
+
+@pytest.mark.parametrize("network", sorted(CAPS_BENCHMARKS))
+def test_l_tiles_keep_sublane_tiling_table1(network):
+    """Every tile picker returns a divisor of L that keeps the stream
+    dtype's sublane tiling (8 fp32 / 16 bf16 / 32 int8 rows) — or L
+    itself — for all 12 Table-1 networks at the serving microbatch and the
+    Table-1 batch, and the megakernel's pick fits the VMEM budget."""
+    cfg = CAPS_BENCHMARKS[network]
+    L, H, C = cfg.num_l_caps, cfg.num_h_caps, cfg.h_caps_dim
+
+    def legal(lt, align):
+        return L % lt == 0 and (lt % align == 0 or lt == L)
+
+    for B in (8, cfg.batch_size):
+        for sd, align in (("fp32", 8), ("bf16", 16), ("int8", 32)):
+            lt = rt_ops.procedure_l_tile(B, L, H, C, sd)
+            assert rt_ops.sublane_tile(sd) == align
+            assert legal(lt, align), (sd, B, lt)
+            assert rt_ops.procedure_vmem_bytes(B, L, H, C, lt, sd) \
+                <= rt_ops.PROCEDURE_VMEM_BUDGET
+            assert legal(rt_ops.procedure_l_tile(B, L, H, C, sd,
+                                                 early_exit=True), align)
+            if sd != "int8":
+                assert legal(rt_ops.auto_l_tile(B, L, H, C, sd), align)
+                assert legal(rt_ops.procedure_train_l_tile(
+                    B, L, H, C, cfg.routing_iters, sd), align)
+        assert legal(rt_ops.em_l_tile(B, L, H, C), 8)
 
 
 def test_resolve_fusion_levels():
@@ -185,8 +216,10 @@ def test_resolve_fusion_capbound_shrinks_tile():
     """A cap-bound (large B·H·C row) shape must shrink the megakernel's
     l_tile to fit the total budget, not fall back to the per-iteration
     kernel (regression: the 8MB-per-buffer pick structurally overflowed
-    2x into the 14MB budget whenever the cap bound bit)."""
-    capbound = (128, 128, 16, 16)          # row = 128*16*16*4 = 128 KiB
+    2x into the 14MB budget whenever the cap bound bit).  L = 8192 makes
+    the megakernel's resident (L, H) logits — 4 MiB once padded to 128
+    lanes — cost it rows the per-iteration kernel keeps."""
+    capbound = (128, 8192, 16, 16)         # row = 128*16*16*4 = 128 KiB
     lt = rt_ops.procedure_l_tile(*capbound)
     assert lt < rt_ops.auto_l_tile(*capbound, "fp32")
     assert rt_ops.procedure_vmem_bytes(*capbound, l_tile=lt) \
@@ -406,13 +439,16 @@ def test_dma_model_int8_and_early_exit():
 
 
 def test_vmem_model_early_exit_and_int8():
-    """procedure_vmem_bytes grows by exactly the frozen-c scratch + flag
-    terms under early exit; the int8 tile pick can never be smaller than
+    """procedure_vmem_bytes grows by exactly the frozen-c scratch (its
+    (L, H=10) block padded to 128 lanes) + the early-exit branch's fp32
+    temporaries under early exit — the converged flags and the work
+    counter live in SMEM; the int8 tile pick can never be smaller than
     the fp32 pick (1-byte rows fit more VMEM)."""
     B, L, H, C, lt = 4, 128, 10, 16, 32
     base = rt_ops.procedure_vmem_bytes(B, L, H, C, lt)
     ee = rt_ops.procedure_vmem_bytes(B, L, H, C, lt, early_exit=True)
-    assert ee - base == L * H * 4 + (L // lt) * 4
+    u_rows = lt * B * 256 * 4                 # H·C = 160 -> 256 lanes
+    assert ee - base == L * 128 * 4 + int(rt_ops._EARLY_EXIT_TEMPS * u_rows)
     i8 = rt_ops.procedure_vmem_bytes(B, L, H, C, lt, "int8")
     assert i8 < base
     assert (rt_ops.procedure_l_tile(B, L, H, C, "int8")

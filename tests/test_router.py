@@ -98,11 +98,12 @@ def test_auto_plan_matches_offline_planner_table4():
 
 
 class _FakeMesh:
-    """Shape-only mesh stand-in: plan_axes only reads axis_names/shape, and
-    the container has a single real device, so a 4-shard mesh can't be
-    constructed in-process."""
+    """Shape-only mesh stand-in: plan_axes only reads axis_names/shape and
+    the device kind, and the test process has a single real device, so a
+    4-shard mesh can't be constructed in-process."""
     axis_names = ("vault",)
     shape = {"vault": 4}
+    devices = np.array(jax.devices() * 4)
 
 
 def test_auto_plan_feasibility_filter():
@@ -709,6 +710,23 @@ def test_differentiable_vmem_fallback_is_jnp(monkeypatch):
     assert resolved.fusion is None and not resolved.differentiable
     g = jax.grad(lambda x: jnp.sum(router(x) ** 2))(u)
     assert bool(jnp.all(jnp.isfinite(g)))
+
+
+def test_differentiable_resolution_checks_backward_fit():
+    """The differentiable megakernel is chosen on its *backward's* VMEM
+    fit (the larger working set): Caps-MN1 at its Table-1 batch trains
+    through the custom VJP, while Caps-EN3 (H·C = 992 lanes) at batch 100
+    has a backward that no tile fits and falls back to jnp autodiff."""
+    from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
+    from repro.kernels.routing import ops as rt_ops
+    router = build_router(RouterSpec(backend="pallas", differentiable=True))
+    for name, fused in (("Caps-MN1", True), ("Caps-EN3", False)):
+        c = CAPS_BENCHMARKS[name]
+        shape = (c.batch_size, c.num_l_caps, c.num_h_caps, c.h_caps_dim)
+        assert rt_ops.procedure_train_fits(*shape) == fused
+        resolved = router.resolve(jax.ShapeDtypeStruct(shape, jnp.float32))
+        assert resolved.differentiable == fused
+        assert resolved.fusion == ("procedure" if fused else None)
 
 
 def test_capsnet_train_step_auto_plan_trains_fused(key):
