@@ -11,7 +11,6 @@ the perf trajectory survives the run.
 
 | bench            | paper artifact                                     |
 |------------------|----------------------------------------------------|
-| layer_breakdown  | Fig.4  — per-layer time, RP fraction               |
 | rp_speedup       | Fig.15/16 — naive vs fused vs sharded-fused vs PIM |
 | distribution     | Fig.18 — dimension choice vs PE frequency          |
 | accuracy         | Table 5 — approximation ± recovery accuracy        |
@@ -30,8 +29,8 @@ import sys
 import time
 import traceback
 
-BENCHES = ("layer_breakdown", "rp_speedup", "distribution", "accuracy",
-           "scaling", "pipeline", "serving", "roofline", "train")
+BENCHES = ("rp_speedup", "distribution", "accuracy", "scaling", "pipeline",
+           "serving", "roofline", "train")
 
 
 def _provenance() -> dict:

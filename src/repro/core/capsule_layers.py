@@ -72,7 +72,8 @@ def init_caps_layer(key, n_l: int, n_h: int, c_l: int, c_h: int):
 
 def predict_votes(params, u: jax.Array) -> jax.Array:
     """Eq.1: u_hat[k,i,j] = u[k,i] @ W[i,j].   u:(B,L,C_L) -> (B,L,H,C_H)."""
-    return jnp.einsum("blc,lhcd->blhd", u, params["W"])
+    with jax.named_scope("capsnet.encode"):
+        return jnp.einsum("blc,lhcd->blhd", u, params["W"])
 
 
 def caps_layer_forward(params, u: jax.Array, route) -> jax.Array:
@@ -86,13 +87,14 @@ def caps_layer_forward(params, u: jax.Array, route) -> jax.Array:
         inside its own shard_map (e.g. the full-train dry-run cell).
     """
     u_hat = predict_votes(params, u)
-    if isinstance(route, routing_lib.RoutingConfig):
-        return routing_lib.dynamic_routing(u_hat, route)
-    from repro.core import router as router_lib
-    if isinstance(route, router_lib.RouterSpec):
-        return router_lib.build_router(route)(u_hat)
-    if callable(route):
-        return route(u_hat)
+    with jax.named_scope("capsnet.routing"):
+        if isinstance(route, routing_lib.RoutingConfig):
+            return routing_lib.dynamic_routing(u_hat, route)
+        from repro.core import router as router_lib
+        if isinstance(route, router_lib.RouterSpec):
+            return router_lib.build_router(route)(u_hat)
+        if callable(route):
+            return route(u_hat)
     raise TypeError(
         f"route must be a Router/callable, RouterSpec, or RoutingConfig; "
         f"got {type(route).__name__}")
@@ -118,16 +120,17 @@ def decoder_forward(params, v: jax.Array, labels: jax.Array | None = None
                     ) -> jax.Array:
     """Reconstruction decoder: mask all but the (label|longest) capsule."""
     B, H, C = v.shape
-    norms = jnp.linalg.norm(v, axis=-1)
-    idx = jnp.argmax(norms, axis=-1) if labels is None else labels
-    mask = jax.nn.one_hot(idx, H, dtype=v.dtype)[..., None]
-    h = (v * mask).reshape(B, H * C)
-    n = len(params)
-    for i in range(n):
-        p = params[f"fc{i}"]
-        h = h @ p["w"] + p["b"]
-        h = jax.nn.relu(h) if i < n - 1 else jax.nn.sigmoid(h)
-    return h
+    with jax.named_scope("capsnet.decoder"):
+        norms = jnp.linalg.norm(v, axis=-1)
+        idx = jnp.argmax(norms, axis=-1) if labels is None else labels
+        mask = jax.nn.one_hot(idx, H, dtype=v.dtype)[..., None]
+        h = (v * mask).reshape(B, H * C)
+        n = len(params)
+        for i in range(n):
+            p = params[f"fc{i}"]
+            h = h @ p["w"] + p["b"]
+            h = jax.nn.relu(h) if i < n - 1 else jax.nn.sigmoid(h)
+        return h
 
 
 def margin_loss(v: jax.Array, labels: jax.Array, n_classes: int,

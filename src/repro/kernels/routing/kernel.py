@@ -182,7 +182,8 @@ def _as_stream(u_hat: jax.Array) -> jax.Array:
 
 def _pack(x: jax.Array) -> jax.Array:
     """(..., H, C) -> (..., H·C): the lane-packed layout every kernel uses."""
-    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+    with jax.named_scope("routing.relayout"):
+        return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
 
 
 def _check_tile(L: int, l_tile: int):

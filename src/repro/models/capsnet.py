@@ -44,12 +44,13 @@ def primary_caps(params, images: jax.Array, cfg: CapsConfig) -> jax.Array:
     pc_cfg = CL.PrimaryCapsConfig(
         conv1_channels=cfg.conv_channels, caps_channels=cfg.caps_channels,
         caps_dim=cfg.l_caps_dim)
-    u = CL.primary_caps_forward(params["primary"], images, pc_cfg)
-    n = u.shape[1]
-    if n < cfg.num_l_caps:
-        reps = -(-cfg.num_l_caps // n)
-        u = jnp.tile(u, (1, reps, 1))
-    return u[:, :cfg.num_l_caps]
+    with jax.named_scope("capsnet.encode"):
+        u = CL.primary_caps_forward(params["primary"], images, pc_cfg)
+        n = u.shape[1]
+        if n < cfg.num_l_caps:
+            reps = -(-cfg.num_l_caps // n)
+            u = jnp.tile(u, (1, reps, 1))
+        return u[:, :cfg.num_l_caps]
 
 
 def encode_votes(params, images: jax.Array, cfg: CapsConfig) -> jax.Array:

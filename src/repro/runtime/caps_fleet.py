@@ -841,6 +841,8 @@ class CapsFleet:
         totals = {k: sum(t[k] for t in per_tenant.values())
                   for k in ("submitted", "completed", "shed", "rejected",
                             "goodput", "failed", "pending")}
+        waited = sum(m.queue_wait_s for m in metrics)
+        dequeued = sum(m.dequeued for m in metrics)
         return {
             **totals,
             "replicas": n_active,
@@ -854,6 +856,8 @@ class CapsFleet:
             "wave_errors": sum(m.wave_errors for m in metrics),
             "evacuated": sum(m.evacuated for m in metrics),
             "adopted": sum(m.adopted for m in metrics),
+            "mean_queue_wait_s": (waited / dequeued if dequeued
+                                  else None),
             "health_events": health_events,
             "per_tenant": per_tenant,
             "per_replica": live,

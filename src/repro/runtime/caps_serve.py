@@ -147,8 +147,9 @@ def make_wave_fn(params, caps_cfg, spec: Optional[router_lib.RouterSpec],
     algo = router_lib.get_algorithm(spec.algorithm)
 
     def encode(micro):
-        votes = capsnet.encode_votes(params, micro["images"], caps_cfg)
-        return votes * micro["mask"][:, None, None, None]
+        with jax.named_scope("capsnet.encode"):
+            votes = capsnet.encode_votes(params, micro["images"], caps_cfg)
+            return votes * micro["mask"][:, None, None, None]
 
     if algo.num_inputs == 1:
         stage_a = encode
@@ -173,7 +174,12 @@ def make_wave_fn(params, caps_cfg, spec: Optional[router_lib.RouterSpec],
             mesh=cfg.mesh, axes=axes, auto=auto, pipeline=cfg.pipeline,
             pipeline_axis=cfg.pipeline_axis, stage_a=stage_a)
         router = router_lib.build_router(spec, plan)
-        return jax.jit(lambda micro: score(router(micro)))
+
+        def wave(micro):
+            with jax.named_scope("capsnet.routing"):
+                out = router(micro)
+            return score(out)
+        return jax.jit(wave)
 
     # unpipelined reference arm: same stages, strictly sequential per
     # microbatch (lax.map = scan, so a sharded routing core traces fine).
@@ -183,7 +189,8 @@ def make_wave_fn(params, caps_cfg, spec: Optional[router_lib.RouterSpec],
 
     def run_one(m):
         h = stage_a(m)
-        return core(*h) if isinstance(h, tuple) else core(h)
+        with jax.named_scope("capsnet.routing"):
+            return core(*h) if isinstance(h, tuple) else core(h)
 
     return jax.jit(lambda micro: score(jax.lax.map(run_one, micro)))
 

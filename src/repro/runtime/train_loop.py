@@ -161,11 +161,13 @@ def make_capsnet_train_step(caps_cfg, spec=None, plan=None,
 
     def train_step(params, opt_state, images, labels):
         (loss, metrics), grads = grad_fn(params, images, labels)
-        grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
-        lr_scale = linear_warmup_cosine(opt_state.step + 1, warmup,
-                                        total_steps)
-        params, opt_state = adamw_update(grads, opt_state, params, opt_cfg,
-                                         lr_scale)
+        with jax.named_scope("train.clip"):
+            grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
+        with jax.named_scope("train.adamw"):
+            lr_scale = linear_warmup_cosine(opt_state.step + 1, warmup,
+                                            total_steps)
+            params, opt_state = adamw_update(grads, opt_state, params,
+                                             opt_cfg, lr_scale)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm,
                                    "lr_scale": lr_scale, **metrics}
 

@@ -59,6 +59,8 @@ from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence
 
 import numpy as np
 
+from repro.runtime import tracing
+
 
 class QueueFullError(RuntimeError):
     """``submit()`` under ``overflow="reject"``: the arrival does not fit
@@ -270,6 +272,9 @@ class ServeMetrics:
     evacuated: int = 0     # queued requests pulled off this (dead) replica
     adopted: int = 0       # requests adopted from a dead replica (in submitted)
     wave_errors: int = 0   # wave attempts that raised (incl. the crash)
+    # -- queueing ------------------------------------------------------------
+    dequeued: int = 0      # requests popped into a wave (retries included)
+    queue_wait_s: float = 0.0  # sum over those pops of clock - t_submit
     callback_errors: int = 0   # on_completion callbacks that raised
     last_error: Optional[str] = None
     latencies_s: List[float] = dataclasses.field(default_factory=list)
@@ -283,6 +288,12 @@ class ServeMetrics:
         if t is None:
             t = self.tenants[name] = TenantMetrics()
         return t
+
+    @property
+    def mean_queue_wait_s(self) -> Optional[float]:
+        """Mean time a request waited in the queue before its wave was
+        popped (None before the first pop)."""
+        return self.queue_wait_s / self.dequeued if self.dequeued else None
 
     def summary(self) -> Dict[str, Any]:
         """JSON-safe summary: strictly finite numbers or ``None`` (never
@@ -317,6 +328,7 @@ class ServeMetrics:
             "wave_errors": self.wave_errors,
             "callback_errors": self.callback_errors,
             "last_error": self.last_error,
+            "mean_queue_wait_s": self.mean_queue_wait_s,
             "per_tenant": {name: t.summary()
                            for name, t in sorted(self.tenants.items())},
             "p50_latency_s": pct(0.5),
@@ -690,15 +702,30 @@ class WaveServer:
                 return []
             take = min(len(self._queue), cfg.wave_lanes)
             reqs = [self._pop_next() for _ in range(take)]
+            now = self.clock()
+            self.metrics.queue_wait_s += sum(now - r.t_submit for r in reqs)
+            self.metrics.dequeued += take
             self._inflight += take
             wave_index = self.metrics.waves
+        with tracing.span("serve.step", wave=wave_index, lanes=take):
+            return self._run_wave(reqs, wave_index)
 
-        wave = self.adapter.pack([r.payload for r in reqs], cfg)
+    def _run_wave(self, reqs: List[Request],
+                  wave_index: int) -> List[Completion]:
+        """The body of ``step`` after the queue pop, each phase in its
+        profiler span (``runtime.tracing``)."""
+        cfg = self.cfg
+        take = len(reqs)
+        with tracing.span("serve.pack"):
+            wave = self.adapter.pack([r.payload for r in reqs], cfg)
         try:
             if self.watchdog is not None:
                 self.watchdog.start(wave_index)
-            out = self._wave_fn(wave)
-            if cfg.output_guard and not self.adapter.finite(out):
+            with tracing.span("serve.launch"):
+                out = self._wave_fn(wave)
+            with tracing.span("serve.guard"):
+                finite = not cfg.output_guard or self.adapter.finite(out)
+            if not finite:
                 # quarantine: the wave executable produced NaN/Inf — rerun
                 # the SAME packed wave through the reference executable
                 with self._cv:
@@ -726,10 +753,11 @@ class WaveServer:
             if self.watchdog is not None:
                 self.watchdog.stop()  # no-op when start() never ran
 
-        results = self.adapter.unpack(out, take)
+        with tracing.span("serve.unpack"):
+            results = self.adapter.unpack(out, take)
         t_done = self.clock()
         out_completions = []
-        with self._cv:
+        with tracing.span("serve.complete"), self._cv:
             for r, result in zip(reqs, results):
                 lat = t_done - r.t_submit
                 met = r.deadline is None or t_done <= r.deadline
@@ -794,6 +822,7 @@ class WaveServer:
         server's backlog awaits ``evacuate()``).
         """
         done: List[Completion] = []
+        tracing.watch_gc()
 
         def emit(batch: List[Completion]):
             # `done` and the server metrics are final before any client
