@@ -9,8 +9,8 @@ through ``runtime.train_loop.make_capsnet_train_step`` in four arms:
                   (B sharded over all local devices; on this container the
                   mesh has one device, so the arm documents the plumbing).
 * ``fused``     — ``plan="auto"``: the procedure megakernel with its
-                  recompute-b custom VJP — the backward replays the routing
-                  loop from VMEM instead of spilling b/c/s/v residuals.
+                  custom VJP — the forward keeps û and its small c_t/s_t
+                  state, the backward walks the iterations in reverse.
 * ``fused_bf16``— the same with bf16 û streaming in both directions.
 
 Gates (written into the artifact AND asserted here):
@@ -89,12 +89,13 @@ def _grad_parity(cfg, params, images, labels, routers) -> dict:
 
 def _residual_model(B: int, L: int, H: int, C: int, iters: int) -> dict:
     """Residual-byte accounting (DESIGN.md §Training): what the forward
-    must keep alive for the backward.  recompute-b saves û alone; jnp
-    autodiff of the same procedure drags û plus the per-iteration c, s,
-    v_prev and softmax/squash locals to HBM."""
+    must keep alive for the backward.  The custom VJP saves û plus each
+    iteration's c (L,H) and s (B,H,C); jnp autodiff of the same procedure
+    drags û plus the per-iteration b, c, s, v_prev and softmax/squash
+    locals to HBM."""
     u = B * L * H * C * 4
     per_iter = 2 * L * H * 4 + 2 * B * H * C * 4      # b/c + s/v per iter
-    return {"fused_residual_bytes": u,
+    return {"fused_residual_bytes": u + iters * (L * H + B * H * C) * 4,
             "unfused_residual_bytes": u + iters * per_iter,
             "per_iteration_residual_bytes": per_iter}
 
@@ -153,7 +154,7 @@ def main():
         < dma["backward_fp32"]["naive_bytes"], dma
     residuals = _residual_model(B, L, H, C, iters)
 
-    print("== CapsNet train step: fused(recompute-b VJP) vs jnp arms ==")
+    print("== CapsNet train step: fused(custom VJP) vs jnp arms ==")
     print("arm,median_s,p90_s,loss_before,loss_after,decreased,modeled_only")
     for name, s in arms.items():
         print(f"{name},{s['median_s']:.4f},{s['p90_s']:.4f},"
@@ -165,7 +166,7 @@ def main():
     print(f"# backward DMA model: fused "
           f"{dma['backward_fp32']['total_bytes']:,}B vs unfused-autodiff "
           f"{dma['backward_fp32']['naive_bytes']:,}B; residuals "
-          f"{residuals['fused_residual_bytes']:,}B (û only) vs "
+          f"{residuals['fused_residual_bytes']:,}B (û, c_t, s_t) vs "
           f"{residuals['unfused_residual_bytes']:,}B")
     print("# (interpret-mode pallas arms are modeled_only — wall-clock "
           "documents plumbing; the perf claim is the DMA/residual model)")

@@ -7,9 +7,10 @@ step-indexed resume.
     PYTHONPATH=src python examples/train_capsnet.py --steps 300  # resumes
     PYTHONPATH=src python examples/train_capsnet.py --smoke --routing fused
 
-``--routing fused`` trains through the procedure megakernel's recompute-b
-custom VJP (DESIGN.md §Training) — the backward replays the routing loop
-instead of spilling per-iteration residuals.
+``--routing fused`` trains through the procedure megakernel's custom VJP
+(DESIGN.md §Training) — the forward keeps only û and its small
+per-iteration c/s state, and the backward walks the routing loop in
+reverse from them.
 """
 import argparse
 import os

@@ -20,7 +20,7 @@ if [[ "${1:-}" != "--fast" ]]; then
     "tests/test_kernels.py::test_routing_procedure_matches_iteration_fused" \
     "tests/test_router.py::test_fusion_procedure_matches_jnp"
 
-  echo "== grad parity: recompute-b custom VJP vs jnp autodiff =="
+  echo "== grad parity: procedure custom VJP vs jnp autodiff =="
   python -m pytest -q \
     "tests/test_kernels.py::test_procedure_vjp_grad_parity" \
     "tests/test_router.py::test_differentiable_router_grad_matches_jnp" \

@@ -92,13 +92,13 @@ class RouterSpec(NamedTuple):
                to the fixed grid; None (default) disables the convergence
                scratch entirely.  Same composition rules as int8: forces
                the procedure form, rejects fusion="iteration", sharded
-               plans, and ``differentiable=True`` (the recompute-b
-               backward replays the fixed-grid schedule).
+               plans, and ``differentiable=True`` (the backward
+               megakernel walks the fixed-grid schedule).
     differentiable: the router will be differentiated (``jax.grad`` /
                ``jax.vjp`` through it — DESIGN.md §Training).  The jnp
                backend is differentiable by construction (plain autodiff,
                the gradient reference).  On the pallas backend this routes
-               the 'dynamic' algorithm through the recompute-b custom VJP
+               the 'dynamic' algorithm through the custom VJP
                of the procedure megakernel
                (``dynamic_routing_procedure_train``); plans must be
                shard-local (the stage-split form has no VJP — auto plans
@@ -505,7 +505,7 @@ class ResolvedPlan(tuple):
                   §Procedure-fused); None for the jnp backend.
     stream_dtype: "fp32" | "bf16" | "int8" û streaming dtype; None for jnp.
     differentiable: True iff execution runs the fused procedure kernel
-                  through its recompute-b custom VJP (DESIGN.md §Training)
+                  through its custom VJP (DESIGN.md §Training)
                   — i.e. ``jax.grad`` hits the backward megakernel.  False
                   for the jnp backend (plain autodiff, no fused backward)
                   and for forward-only pallas execution.
@@ -848,8 +848,8 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
         if spec.differentiable:
             raise ValueError(
                 "differentiable=True requires early_exit_eps=None: the "
-                "recompute-b backward replays the fixed-grid schedule "
-                "(data-dependent tile skipping has no replay); train "
+                "backward megakernel walks the fixed-grid schedule "
+                "(data-dependent tile skipping has no transpose); train "
                 "fixed-grid, serve early-exit")
     if spec.stream_dtype == "int8":
         if spec.differentiable:
@@ -870,7 +870,7 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
                 "the Table-2 psums; use an unsharded plan (plan=None or "
                 "'auto')")
     if spec.differentiable and spec.backend == "pallas":
-        # DESIGN.md §Training: the recompute-b custom VJP exists for the
+        # DESIGN.md §Training: the custom VJP exists for the
         # 'dynamic' procedure megakernel only
         if algo.name != "dynamic":
             raise ValueError(

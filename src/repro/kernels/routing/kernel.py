@@ -266,7 +266,7 @@ def routing_iteration_fused(u_hat: jax.Array, b: jax.Array, v_prev: jax.Array,
 def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
                               n_l_tiles: int, iterations: int,
                               use_approx: bool, quantized: bool,
-                              early_exit_eps):
+                              early_exit_eps, residuals: bool):
     """One grid step = one (iteration, L-tile) cell; grid is row-major so the
     L-tiles of iteration t all run before iteration t+1.
 
@@ -278,6 +278,8 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
     scale_ref: (L/L_t,) SMEM per-L-tile dequant scales     [quantized only]
     v_out_ref: (B, H·C) final routed output (written at the last grid step)
     cnt_ref:   (1,) SMEM int32 effective-tile-iterations   [early-exit only]
+    c_res_ref: (T, L, H) every iteration's couplings c_t   [residuals only]
+    s_res_ref: (T, B, H·C) every iteration's vote sums s_t [residuals only]
     b_scr:     (L, H) routing logits       — VMEM-resident ALL iterations
     v_scr:     (B, H·C) previous v         — VMEM-resident ALL iterations
     s_scr:     (B, H·C) vote-sum accum     — VMEM-resident ALL iterations
@@ -288,6 +290,12 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
     iterations and squash (Eq.3) runs in-kernel at the last L-tile of each
     iteration — the only HBM write of the whole procedure is the final v
     (plus the 4-byte work counter under early exit).
+
+    ``residuals`` (set only by the custom VJP's forward, DESIGN.md
+    §Training) also emits c_t and the pre-squash s_t of every iteration:
+    both output blocks have a constant index map, so each is written back
+    to HBM once, after the last grid step.  Their iteration slot is a
+    static int (``pl.when(it == t)`` unrolls).
 
     Early exit (DESIGN.md §Quantized-routing): a tile whose deferred-Eq.4
     logit update satisfied ‖Δb‖∞ < ε at some iteration t ≥ 1 skips the
@@ -304,6 +312,8 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
     scale_ref = refs.pop(0) if quantized else None
     v_out_ref = refs.pop(0)
     cnt_ref = refs.pop(0) if early_exit_eps is not None else None
+    c_res_ref = refs.pop(0) if residuals else None
+    s_res_ref = refs.pop(0) if residuals else None
     b_scr, v_scr, s_scr = refs.pop(0), refs.pop(0), refs.pop(0)
     c_scr = refs.pop(0) if early_exit_eps is not None else None
     conv_scr = refs.pop(0) if early_exit_eps is not None else None
@@ -356,6 +366,12 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
         # computes with bit-identical coup values.
         coup = c_scr[rows, :]
 
+    if residuals:
+        for t in range(iterations):
+            @pl.when(it == t)
+            def _save_c(t=t):
+                c_res_ref[t, rows, :] = coup
+
     # --- Eq.2 partial weighted sum, accumulated in scratch (ALWAYS runs:
     # frozen tiles still contribute their Eq.2 term, keeping the s
     # accumulation structure — and hence ε = 0 bit-identity — intact)
@@ -372,6 +388,12 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
     # --- Eq.3 squash in-kernel at the last L-tile of the iteration
     @pl.when(j == n_l_tiles - 1)
     def _finish_iteration():
+        if residuals:
+            for t in range(iterations):
+                @pl.when(it == t)
+                def _save_s(t=t):
+                    s_res_ref[t] = s_scr[...]
+
         v = squash_packed(s_scr[...], h, c_dim, use_approx)
         v_scr[...] = v
 
@@ -382,17 +404,25 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
 
 @functools.partial(jax.jit, static_argnames=("iterations", "l_tile",
                                              "use_approx", "interpret",
-                                             "early_exit_eps"))
+                                             "early_exit_eps", "residuals"))
 def routing_procedure_fused(u_hat: jax.Array, scales: jax.Array | None = None,
                             *, iterations: int = 3,
                             l_tile: int = 128, use_approx: bool = False,
                             interpret: bool | None = None,
-                            early_exit_eps: float | None = None):
+                            early_exit_eps: float | None = None,
+                            residuals: bool = False):
     """Whole routing procedure in ONE pallas_call.
 
     Returns v (B, H, C), or ``(v, effective_tile_iterations)`` — the int32
     count of (iteration, L-tile) grid cells that did Eq.4/Eq.5 work — when
     ``early_exit_eps`` is set (fixed grid ≡ iterations · L/l_tile).
+
+    ``residuals=True`` is the custom VJP's forward (ops.py,
+    DESIGN.md §Training): it returns ``(v, u_packed, c, s)`` — the
+    lane-packed û (B, L, H·C) the kernel streamed, every iteration's
+    couplings c (T, L, H) and pre-squash vote sums s (T, B, H·C), fp32 —
+    which is all :func:`routing_procedure_bwd` needs.  Not combinable with
+    int8 codes or early exit, which have no backward.
 
     u_hat: (B, L, H, C) in fp32 or bf16 — the *input dtype* is the stream
     dtype (ops.py::dynamic_routing_procedure_fused picks it) — or int8
@@ -422,12 +452,16 @@ def routing_procedure_fused(u_hat: jax.Array, scales: jax.Array | None = None,
     early_exit = early_exit_eps is not None
     if early_exit and not (float(early_exit_eps) >= 0.0):
         raise ValueError(f"early_exit_eps must be >= 0, got {early_exit_eps}")
+    if residuals and (quantized or early_exit):
+        raise ValueError("residuals are the differentiable forward's: no "
+                         "int8 codes, no early exit")
 
     kernel = functools.partial(
         _routing_procedure_kernel, h=H, c_dim=C, l_tile=l_tile,
         n_l_tiles=n_l_tiles, iterations=iterations, use_approx=use_approx,
         quantized=quantized,
-        early_exit_eps=float(early_exit_eps) if early_exit else None)
+        early_exit_eps=float(early_exit_eps) if early_exit else None,
+        residuals=residuals)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [pl.BlockSpec((B, l_tile, H * C), lambda it, j: (0, j, 0))]
@@ -449,6 +483,14 @@ def routing_procedure_fused(u_hat: jax.Array, scales: jax.Array | None = None,
             pltpu.VMEM((L, H), jnp.float32),         # frozen couplings
             pltpu.SMEM((n_l_tiles,), jnp.int32),     # converged flags
         ]
+    if residuals:
+        T = iterations
+        out_specs = [out_specs,
+                     pl.BlockSpec((T, L, H), lambda it, j: (0, 0, 0)),
+                     pl.BlockSpec((T, B, H * C), lambda it, j: (0, 0, 0))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((T, L, H), jnp.float32),
+                     jax.ShapeDtypeStruct((T, B, H * C), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=(iterations, n_l_tiles),
@@ -462,105 +504,76 @@ def routing_procedure_fused(u_hat: jax.Array, scales: jax.Array | None = None,
     if early_exit:
         v, cnt = out
         return v.reshape(B, H, C), cnt[0]
+    if residuals:
+        v, c, s = out
+        return v.reshape(B, H, C), inputs[0], c, s
     return out.reshape(B, H, C)
 
 
 # ---------------------------------------------------------------------------
 # Whole-procedure backward megakernel (DESIGN.md §Training)
 # ---------------------------------------------------------------------------
-# Recompute-b backward: the forward saves ONLY û (and the incoming cotangent
-# ∂v) — none of the per-iteration intermediates (b, c, s, v) spill to HBM as
-# autodiff residuals.  The backward is one pallas_call whose grid prepends a
-# replay phase to a reverse phase:
+# The forward (routing_procedure_fused with ``residuals``) saves the
+# lane-packed û it streamed plus its own small per-iteration state: the
+# couplings c_t (T,L,H) and the pre-squash vote sums s_t (T,B,H·C), fp32 —
+# T·(L·H + B·H·C)·4 bytes next to û's B·L·H·C·itemsize.  The backward is
+# one pallas_call that walks the iterations in reverse:
 #
-#     grid = (2·iterations, n_l_tiles)
-#       rows 0..T-1   REPLAY  — re-run the forward schedule from VMEM,
-#                               snapshotting the *small* per-iteration state
-#                               (c_t (L,H), s_t and v_{t-1} (B,H·C)) into
-#                               VMEM scratch,
-#       rows T..2T-1  REVERSE — walk iterations T-1..0 carrying the cotangent
-#                               ∂v (B,H·C) and the accumulated logit
-#                               cotangent ∂b (L,H), both fp32.
+#     grid = (iterations, n_l_tiles), row r handles iteration t = T-1-r,
+#     carrying the cotangent ∂v (B,H·C) and the accumulated logit cotangent
+#     ∂b (L,H), both fp32, in VMEM scratch.
 #
 # Per reverse iteration t (derived from the lazy-update schedule; verified
 # against jnp autodiff in tests/_gradcheck.py users):
 #
-#     gs   = squash_vjp(s_t, gv)                                  (B,H,C)
+#     v_{t-1} = squash(s_{t-1})  (v_{-1} = 0)           at j == 0  (B,H,C)
+#     gs   = squash_vjp(s_t, gv)                       at j == 0  (B,H,C)
 #     gc   = Σ_{k,c} û·gs                                         (L,H)
 #     gb  += c_t ⊙ (gc − Σ_H c_t·gc)      softmax_H vjp, Eq.5     (L,H)
 #     ∂û  += c_t ⊗ gs  +  gb ⊗ v_{t-1}    Eq.2 + deferred Eq.4 terms
 #     gv   = Σ_l gb·û                     carry to iteration t-1  (B,H,C)
 #
 # At t = 0 the v_{-1} = 0 start kills the Eq.4 term and b₀ = 0 receives no
-# input gradient, so ∂û is complete after the t = 0 reverse row.  ∂û is
-# accumulated in fp32 from the per-iteration snapshots and written to HBM
-# exactly once per L-tile (at the final grid row), at the û stream dtype —
-# the backward's DMA bill is 2T û-streams in + 1 û-sized ∂û out (see
-# ops.py::dma_bytes_per_call(backward=True)).
+# input gradient, so the t = 0 row needs only gs_0 — no gc, gb, gv and no
+# û — and ∂û is complete after it.  ∂û is summed in fp32 from the
+# per-iteration gs_t, ∂b_t and v_{t-1} scratch at that final row; its
+# output block's index stays at 0 until then, so each ∂û tile reaches HBM
+# once, at the û stream dtype, and the û block's index stays put during it.
+# The DMA bill is T-1 û streams in + one û-sized ∂û out + the residuals and
+# ∂v read once (see ops.py::dma_bytes_per_call(backward=True)).
 
 
-def _routing_procedure_bwd_kernel(u_ref, g_ref, du_ref,
-                                  b_scr, v_scr, s_scr,
-                                  c_all, s_all, vp_all, gs_all, gb_all, *,
+def _routing_procedure_bwd_kernel(u_ref, g_ref, c_ref, s_ref, du_ref,
+                                  gb_scr, gv_scr, acc_scr,
+                                  gs_all, gb_all, vp_all, *,
                                   h: int, c_dim: int, l_tile: int,
                                   n_l_tiles: int, iterations: int,
                                   use_approx: bool):
-    """One grid step = one (phase-row, L-tile) cell.
+    """One grid step = one (reverse row, L-tile) cell.
 
-    u_ref:  (B, L_t, H·C) lane-packed û tile (streamed once per grid row)
+    u_ref:  (B, L_t, H·C) lane-packed û tile (streamed once per grid row
+            but the final, which reads none)
     g_ref:  (B, H·C) incoming cotangent ∂v (same block every step)
-    du_ref: (B, L_t, H·C) ∂û tile, written once at the final grid row
+    c_ref:  (T, L, H) the forward's couplings c_t (same block every step)
+    s_ref:  (T, B, H·C) the forward's vote sums s_t (same block every step)
+    du_ref: (B, L_t, H·C) ∂û tile, written at the final grid row only
 
     Scratch (all fp32, VMEM-resident across the whole grid):
-    b_scr:  (L, H)      replay: logits b      | reverse: accumulated ∂b
-    v_scr:  (B, H·C)    replay: previous v    | reverse: carried ∂v
-    s_scr:  (B, H·C)    replay: vote-sum s    | reverse: next ∂v accumulator
-    c_all:  (T, L, H)   per-iteration coupling coefficients c_t
-    s_all:  (T, B, H·C) per-iteration pre-squash vote sums s_t
-    vp_all: (T, B, H·C) per-iteration previous v (v_{t-1})
+    gb_scr: (L, H)      accumulated ∂b
+    gv_scr: (B, H·C)    carried ∂v
+    acc_scr:(B, H·C)    next ∂v accumulator
     gs_all: (T, B, H·C) per-iteration ∂s (squash vjp of the carried ∂v)
-    gb_all: (T, L, H)   snapshot of accumulated ∂b after folding row t
+    gb_all: (T-1, L, H) accumulated ∂b after folding row t ≥ 1 (slot t-1)
+    vp_all: (T-1, B, H·C) previous v (v_{t-1}) of iteration t ≥ 1
+            (slot t-1); one unused slot each when T = 1
 
-    The phase row index is compared against *Python* constants only
-    (static unroll via ``pl.when(row == k)``) so every scratch slot index
-    is a static int — no dynamically-indexed VMEM addressing.
+    The row index is compared against *Python* constants only (static
+    unroll via ``pl.when(row == k)``) so every iteration slot index is a
+    static int — no dynamically-indexed VMEM addressing.
     """
     row = pl.program_id(0)
     j = pl.program_id(1)
-    u = u_ref[...].astype(jnp.float32)           # fp32 accumulation
     rows = _rows(j, l_tile)
-
-    def _replay(t: int):
-        """Forward iteration t, mirroring _routing_procedure_kernel but
-        snapshotting (v_{t-1}, c_t, s_t) into the per-iteration scratch."""
-        if t == 0:
-            @pl.when(j == 0)
-            def _reset():
-                b_scr[...] = jnp.zeros_like(b_scr)
-                v_scr[...] = jnp.zeros_like(v_scr)
-
-        @pl.when(j == 0)
-        def _snap_vprev():
-            vp_all[t] = v_scr[...]
-
-        b_new = b_scr[rows, :] + _agreement(u, v_scr[...], h, c_dim)
-        b_scr[rows, :] = b_new
-        coup = _softmax_h_inkernel(b_new, use_approx)        # (L_t, H)
-        c_all[t, rows, :] = coup
-        s_part = _votes(u, coup, c_dim)
-
-        @pl.when(j == 0)
-        def _init():
-            s_scr[...] = s_part
-
-        @pl.when(j != 0)
-        def _acc():
-            s_scr[...] += s_part
-
-        @pl.when(j == n_l_tiles - 1)
-        def _finish_iteration():
-            s_all[t] = s_scr[...]
-            v_scr[...] = squash_packed(s_scr[...], h, c_dim, use_approx)
 
     def _reverse(t: int):
         """Backward through forward iteration t (t runs T-1 .. 0)."""
@@ -568,96 +581,105 @@ def _routing_procedure_bwd_kernel(u_ref, g_ref, du_ref,
         def _start_iteration():
             if t == iterations - 1:
                 # seed the reverse sweep: ∂v := incoming cotangent, ∂b := 0
-                v_scr[...] = g_ref[...].astype(jnp.float32)
-                b_scr[...] = jnp.zeros_like(b_scr)
-            # Eq.3 transpose of the *exact* squash at the replayed s_t
+                gv_scr[...] = g_ref[...].astype(jnp.float32)
+                gb_scr[...] = jnp.zeros_like(gb_scr)
+            if t > 0:
+                # the forward's v_{t-1}, from its own s_{t-1}
+                vp_all[t - 1] = squash_packed(s_ref[t - 1], h, c_dim,
+                                              use_approx)
+                acc_scr[...] = jnp.zeros_like(acc_scr)   # next ∂v
+            # Eq.3 transpose of the *exact* squash at the saved s_t
             # (use_approx mode gets the exact-surrogate gradient; the
             # Router refuses differentiable+approx anyway).
-            gs_all[t] = _squash_vjp_inkernel(s_all[t], v_scr[...], h, c_dim)
-            s_scr[...] = jnp.zeros_like(s_scr)   # next ∂v accumulator
+            gs_all[t] = _squash_vjp_inkernel(s_ref[t], gv_scr[...], h, c_dim)
 
-        gs = gs_all[t]                                       # (B, H·C)
-        # Eq.2 transpose into the logits: gc[l,h] = Σ_{k,c} û·gs
-        gc = _agreement(u, gs, h, c_dim)                     # (L_t, H)
-        coup = c_all[t, rows, :]
-        # Eq.5 softmax_H vjp, folded into the running ∂b
-        gb = b_scr[rows, :] + coup * (
-            gc - jnp.sum(coup * gc, axis=-1, keepdims=True))
-        b_scr[rows, :] = gb
+        coup = c_ref[t, rows, :]                             # (L_t, H)
         if t > 0:
-            gb_all[t, rows, :] = gb
-        # deferred-Eq.4 transpose: carry ∂v_{t-1}[k,h,c] += Σ_l gb·û
-        s_scr[...] += _votes(u, gb, c_dim)
+            u = u_ref[...].astype(jnp.float32)       # fp32 accumulation
+            # Eq.2 transpose into the logits: gc[l,h] = Σ_{k,c} û·gs
+            gc = _agreement(u, gs_all[t], h, c_dim)          # (L_t, H)
+            # Eq.5 softmax_H vjp, folded into the running ∂b
+            gb = gb_scr[rows, :] + coup * (
+                gc - jnp.sum(coup * gc, axis=-1, keepdims=True))
+            gb_scr[rows, :] = gb
+            gb_all[t - 1, rows, :] = gb
+            # deferred-Eq.4 transpose: carry ∂v_{t-1}[k,h,c] += Σ_l gb·û
+            acc_scr[...] += _votes(u, gb, c_dim)
 
-        @pl.when(j == n_l_tiles - 1)
-        def _finish_iteration():
-            v_scr[...] = s_scr[...]              # becomes ∂v for row t-1
-
-        if t == 0:
-            # ∂û for this L-tile, summed over all iterations from the
-            # snapshots:  ∂û = Σ_t [ c_t ⊗ gs_t + gb_t ⊗ v_{t-1} ]
-            # (the t = 0 Eq.4 term vanishes: v_{-1} = 0).
-            acc = _caps_bcast(coup, c_dim)[None] * gs[:, None]
+            @pl.when(j == n_l_tiles - 1)
+            def _finish_iteration():
+                gv_scr[...] = acc_scr[...]       # becomes ∂v for row t-1
+        else:
+            # t = 0: b₀'s update Σ û·v_{-1} is 0 (v_{-1} = 0), so neither
+            # ∂b₀ nor ∂v_{-1} has a reader and this row reads no û.  ∂û for
+            # this L-tile, summed over all iterations:
+            #   ∂û = Σ_t [ c_t ⊗ gs_t + gb_t ⊗ v_{t-1} ]  (no t = 0 Eq.4 term)
+            acc = _caps_bcast(coup, c_dim)[None] * gs_all[0][:, None]
             for tp in range(1, iterations):
-                acc += (_caps_bcast(c_all[tp, rows, :], c_dim)[None]
+                acc += (_caps_bcast(c_ref[tp, rows, :], c_dim)[None]
                         * gs_all[tp][:, None])
-                acc += (_caps_bcast(gb_all[tp, rows, :], c_dim)[None]
-                        * vp_all[tp][:, None])
+                acc += (_caps_bcast(gb_all[tp - 1, rows, :], c_dim)[None]
+                        * vp_all[tp - 1][:, None])
             du_ref[...] = acc.astype(du_ref.dtype)
 
-    for t in range(iterations):                  # replay rows 0..T-1
-        pl.when(row == t)(functools.partial(_replay, t))
-    for t in range(iterations - 1, -1, -1):      # reverse rows T..2T-1
-        pl.when(row == 2 * iterations - 1 - t)(functools.partial(_reverse, t))
+    for t in range(iterations - 1, -1, -1):      # rows 0..T-1
+        pl.when(row == iterations - 1 - t)(functools.partial(_reverse, t))
 
 
-@functools.partial(jax.jit, static_argnames=("iterations", "l_tile",
-                                             "use_approx", "interpret"))
-def routing_procedure_bwd(u_hat: jax.Array, g: jax.Array, *,
-                          iterations: int = 3, l_tile: int = 128,
+@functools.partial(jax.jit, static_argnames=("l_tile", "use_approx",
+                                             "interpret"))
+def routing_procedure_bwd(u_packed: jax.Array, g: jax.Array, c: jax.Array,
+                          s: jax.Array, *, l_tile: int = 128,
                           use_approx: bool = False,
                           interpret: bool | None = None) -> jax.Array:
-    """Backward of :func:`routing_procedure_fused`: (û (B,L,H,C), ∂v (B,H,C))
-    -> ∂û (B,L,H,C) at û's (stream) dtype.
+    """Backward of :func:`routing_procedure_fused`: the forward's residuals
+    (lane-packed û (B, L, H·C), c (T, L, H), s (T, B, H·C)) and ∂v
+    (B, H, C) -> ∂û (B, L, H, C) at û's (stream) dtype.
 
-    ONE pallas_call, grid (2·iterations, L/l_tile): replay rows reconstruct
-    the per-iteration b/c/s/v from VMEM, reverse rows accumulate ∂û in fp32
-    (see the module-level derivation above _routing_procedure_bwd_kernel).
-    VMEM working set beyond the forward's: the per-iteration snapshots —
+    ONE pallas_call, grid (T, L/l_tile): each row walks one iteration in
+    reverse, streaming û (but the t = 0 row), and ∂û is accumulated in
+    fp32 and written once per L-tile (see the derivation above
+    _routing_procedure_bwd_kernel).  VMEM working set:
     ops.py::procedure_bwd_vmem_bytes, which procedure_train_l_tile sizes
     the tile against.
     """
-    B, L, H, C = u_hat.shape
+    B, L, HC = u_packed.shape
+    T, _, H = c.shape
+    C = HC // H
+    prev = max(T - 1, 1)                     # slots of iterations t ≥ 1
     _check_tile(L, l_tile)
-    if u_hat.dtype not in (jnp.float32, jnp.bfloat16):
-        u_hat = u_hat.astype(jnp.float32)
-    T = iterations
-    HC = H * C
+    n = L // l_tile
     kernel = functools.partial(
         _routing_procedure_bwd_kernel, h=H, c_dim=C, l_tile=l_tile,
-        n_l_tiles=L // l_tile, iterations=T, use_approx=use_approx)
+        n_l_tiles=n, iterations=T, use_approx=use_approx)
     du = pl.pallas_call(
         kernel,
-        grid=(2 * T, L // l_tile),
+        grid=(T, n),
         in_specs=[
-            pl.BlockSpec((B, l_tile, HC), lambda it, j: (0, j, 0)),
-            pl.BlockSpec((B, HC), lambda it, j: (0, 0)),
+            # the final row reads no û: hold the block the row before it
+            # ended on, so û streams T - 1 times
+            pl.BlockSpec((B, l_tile, HC),
+                         lambda r, j: (0, jnp.where(r == T - 1, n - 1, j), 0)),
+            pl.BlockSpec((B, HC), lambda r, j: (0, 0)),
+            pl.BlockSpec((T, L, H), lambda r, j: (0, 0, 0)),
+            pl.BlockSpec((T, B, HC), lambda r, j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((B, l_tile, HC), lambda it, j: (0, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, L, HC), u_hat.dtype),
+        # block 0 until the final row: each ∂û tile reaches HBM once
+        out_specs=pl.BlockSpec(
+            (B, l_tile, HC),
+            lambda r, j: (0, jnp.where(r == T - 1, j, 0), 0)),
+        out_shape=jax.ShapeDtypeStruct((B, L, HC), u_packed.dtype),
         scratch_shapes=[
-            pltpu.VMEM((L, H), jnp.float32),         # b     | ∂b
-            pltpu.VMEM((B, HC), jnp.float32),        # v     | ∂v carry
-            pltpu.VMEM((B, HC), jnp.float32),        # s     | ∂v accum
-            pltpu.VMEM((T, L, H), jnp.float32),      # c_t snapshots
-            pltpu.VMEM((T, B, HC), jnp.float32),     # s_t snapshots
-            pltpu.VMEM((T, B, HC), jnp.float32),     # v_{t-1} snapshots
-            pltpu.VMEM((T, B, HC), jnp.float32),     # ∂s_t snapshots
-            pltpu.VMEM((T, L, H), jnp.float32),      # ∂b snapshots
+            pltpu.VMEM((L, H), jnp.float32),         # ∂b
+            pltpu.VMEM((B, HC), jnp.float32),        # ∂v carry
+            pltpu.VMEM((B, HC), jnp.float32),        # ∂v accum
+            pltpu.VMEM((T, B, HC), jnp.float32),     # ∂s_t
+            pltpu.VMEM((prev, L, H), jnp.float32),   # ∂b_t, t ≥ 1
+            pltpu.VMEM((prev, B, HC), jnp.float32),  # v_{t-1}, t ≥ 1
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=resolve_interpret(interpret),
-    )(_pack(u_hat), _pack(g.astype(jnp.float32)))
+    )(u_packed, _pack(g.astype(jnp.float32)), c, s)
     return du.reshape(B, L, H, C)
 
 

@@ -73,7 +73,7 @@ _VOTE_TEMPS = 16
 # reductions consume), measured the same way.
 _FWD_TEMPS = 1.25          # procedure megakernel
 _EARLY_EXIT_TEMPS = 1.25   # + the early-exit branch's live values
-_BWD_TEMPS = 2.5           # recompute-b backward megakernel
+_BWD_TEMPS = 0.75          # backward megakernel (0.52 at most, 18 shapes)
 _STAGE_TEMPS = 1.25        # per-iteration and stage-split kernels
 _EM_TEMPS = 3.0            # EM M-step / E-step kernels
 
@@ -188,11 +188,14 @@ def _procedure_bwd_terms(B: int, L: int, H: int, C: int, iterations: int,
                          stream_dtype: str):
     isz = _stream_itemsize(stream_dtype)
     T = iterations
+    prev = max(T - 1, 1)          # ∂b_t and v_{t-1} slots: iterations t ≥ 1
     row = (4 * _u_row_bytes(B, H, C, isz)            # û + ∂û, double-buffered
            + _BWD_TEMPS * _u_row_bytes(B, H, C))
-    fixed = ((2 * T + 1) * _vmem_bytes(L, H)         # b/∂b + c_t + ∂b_t snaps
-             + (3 * T + 4) * _vmem_bytes(B, H * C)   # v, s, s_t/v_{t-1}/∂s_t,
-             + _slack(B, H, C))                      # ∂v block x2
+    # logit-sized: c_t block x2, ∂b, ∂b_t; vote-sized: s_t block x2, ∂s_t,
+    # v_{t-1}, ∂v carry and accumulator, ∂v block x2
+    fixed = ((2 * T + 1 + prev) * _vmem_bytes(L, H)
+             + (3 * T + 4 + prev) * _vmem_bytes(B, H * C)
+             + _slack(B, H, C))
     return int(row), fixed
 
 
@@ -202,10 +205,10 @@ def procedure_bwd_vmem_bytes(B: int, L: int, H: int, C: int, l_tile: int,
     """VMEM working set of the backward megakernel
     (kernel.py::routing_procedure_bwd), tile padding counted:
     double-buffered û *and* ∂û stream blocks with their fp32 temporaries,
-    the b/v/s scratch (reused as ∂b / ∂v carry / ∂v accumulator in the
-    reverse phase) plus the per-iteration snapshots — 2T logit-sized
-    (c_t, ∂b_t) and 3T vote-sized (s_t, v_{t-1}, ∂s_t) — and the (B,H·C)
-    cotangent block."""
+    the forward's residual blocks c_t (T, L, H) and s_t (T, B, H·C) and the
+    (B, H·C) cotangent block (each double-buffered), and the scratch: ∂b
+    and the per-iteration ∂b_t (logit-sized), the ∂v carry and accumulator
+    and the per-iteration ∂s_t and v_{t-1} (vote-sized)."""
     row, fixed = _procedure_bwd_terms(B, L, H, C, iterations, stream_dtype)
     return _round_up(l_tile, sublane_tile(stream_dtype)) * row + fixed
 
@@ -214,8 +217,11 @@ def procedure_train_l_tile(B: int, L: int, H: int, C: int,
                            iterations: int = 3,
                            stream_dtype: str = "fp32") -> int:
     """l_tile for the *differentiable* megakernel: sized against the
-    backward's working set (the larger of the two) — the forward reuses
-    the same tile so fwd and bwd share one stream layout."""
+    backward's working set — the larger of the two in every term: the
+    forward with residuals holds one double-buffered û block, 2T + 1
+    logit-sized and 2T + 4 vote-sized buffers, the backward two
+    double-buffered stream blocks and more of each — so the forward
+    reuses the same tile and fwd and bwd share one stream layout."""
     row, fixed = _procedure_bwd_terms(B, L, H, C, iterations, stream_dtype)
     return pick_l_tile(L, PROCEDURE_VMEM_BUDGET - fixed, row,
                        align=sublane_tile(stream_dtype))
@@ -344,17 +350,18 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
     einsums) plus materialised intermediates — measured ~5x the fused bound
     on the pod dry-run (EXPERIMENTS.md §Perf routing cell).
 
-    ``backward=True`` models the recompute-b backward megakernel
-    (DESIGN.md §Training) — defined for ``form="procedure"`` only (the
-    other forms have no custom VJP).  û streams 2T times (T replay + T
-    reverse rows), a û-sized ∂û is written once at the stream dtype
-    (``du_stream_bytes``) and the only other traffic is the (B,H,C) fp32
-    cotangent read — every per-iteration residual (b, c, s, v and their
-    cotangents) stays in VMEM.  ``naive_bytes`` then models unfused jnp
-    autodiff of the same procedure: û re-read twice per iteration by the
-    einsum transposes, a û-sized ∂û accumulator read+written per
-    iteration, and the per-iteration b/c/s/v residuals spilled forward
-    and re-read backward.
+    ``backward=True`` models the backward megakernel (DESIGN.md
+    §Training) — defined for ``form="procedure"`` only (the other forms
+    have no custom VJP).  û streams T − 1 times (one reverse row per
+    iteration; the t = 0 row reads none), a û-sized ∂û is written once at
+    the stream dtype (``du_stream_bytes``: its output block stays put
+    until the final row) and the only other traffic is reading, once
+    each, the (B,H,C) fp32 cotangent and the forward's residuals c_t (L,H)
+    and s_t (B,H,C) of every iteration — every cotangent stays in VMEM.  ``naive_bytes``
+    then models unfused jnp autodiff of the same procedure: û re-read
+    twice per iteration by the einsum transposes, a û-sized ∂û
+    accumulator read+written per iteration, and the per-iteration b/c/s/v
+    residuals spilled forward and re-read backward.
     """
     f = 4  # fp32: logits / vote-sum / output blocks are always fp32
     u = B * L * H * C * _stream_itemsize(stream_dtype)
@@ -377,7 +384,7 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
     if backward:
         if form != "procedure":
             raise ValueError(
-                "backward=True models the recompute-b VJP of the procedure "
+                "backward=True models the custom VJP of the procedure "
                 f"megakernel only (form={form!r} has no custom VJP)")
         if stream_dtype == "int8":
             raise ValueError(
@@ -389,10 +396,10 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
             "fold": fold,
             "stream_dtype": stream_dtype,
             "backward": True,
-            "u_hat_stream_bytes": 2 * iterations * u,
+            "u_hat_stream_bytes": (iterations - 1) * u,
             "du_stream_bytes": u,
-            "roundtrip_bytes": vhc,
-            "total_bytes": 2 * iterations * u + u + vhc,
+            "roundtrip_bytes": vhc + iterations * (bh + vhc),
+            "total_bytes": iterations * u + vhc + iterations * (bh + vhc),
             "u_hat_bytes": u_f32,
             "naive_bytes": iterations * (2 * u_f32 + 2 * u_f32
                                          + 2 * (2 * bh + 2 * vhc)),
@@ -564,12 +571,15 @@ def dynamic_routing_procedure_stats(u_hat: jax.Array, *, iterations: int = 3,
 # ---------------------------------------------------------------------------
 # Differentiable procedure megakernel (DESIGN.md §Training)
 # ---------------------------------------------------------------------------
-# The recompute-b custom VJP: the forward is routing_procedure_fused
-# unchanged; the only residual it saves is û itself (the backward replays
-# the cheap routing loop from VMEM — kernel.py::routing_procedure_bwd — so
-# none of the per-iteration b/c/s/v ever spill to HBM as autodiff
-# residuals).  ∂û comes back at û's stream dtype with fp32 in-kernel
-# accumulation; the differentiable stream-dtype cast in
+# The custom VJP: the forward is routing_procedure_fused in its residual
+# mode — the same kernel and the same v, which also writes back, once, its
+# own per-iteration couplings c_t (T,L,H) and vote sums s_t (T,B,H·C).  The
+# residuals are those plus the lane-packed û the forward streamed:
+# û + T·(L·H + B·H·C)·4 bytes, where jnp autodiff keeps per-iteration
+# O(B·L·H·C) intermediates.  The backward (kernel.py::routing_procedure_bwd)
+# walks the iterations in reverse, streaming û in all but the t = 0 row,
+# and replays nothing.  ∂û comes back at û's stream dtype with fp32
+# in-kernel accumulation; the differentiable stream-dtype cast in
 # dynamic_routing_procedure_train transposes it back to the caller's fp32.
 
 
@@ -581,16 +591,17 @@ def _procedure_train_core(u_hat, iterations, l_tile, use_approx, interpret):
 
 
 def _procedure_train_fwd(u_hat, iterations, l_tile, use_approx, interpret):
-    v = routing_procedure_fused(u_hat, iterations=iterations, l_tile=l_tile,
-                                use_approx=use_approx, interpret=interpret)
-    return v, u_hat      # recompute-b: û is the ONLY saved residual
+    v, u_packed, c, s = routing_procedure_fused(
+        u_hat, iterations=iterations, l_tile=l_tile, use_approx=use_approx,
+        interpret=interpret, residuals=True)
+    return v, (u_packed, c, s)
 
 
 def _procedure_train_bwd(iterations, l_tile, use_approx, interpret,
-                         u_hat, g):
-    du = routing_procedure_bwd(u_hat, g, iterations=iterations,
-                               l_tile=l_tile, use_approx=use_approx,
-                               interpret=interpret)
+                         res, g):
+    u_packed, c, s = res
+    du = routing_procedure_bwd(u_packed, g, c, s, l_tile=l_tile,
+                               use_approx=use_approx, interpret=interpret)
     return (du,)
 
 
@@ -609,10 +620,12 @@ def dynamic_routing_procedure_train(u_hat: jax.Array, *, iterations: int = 3,
 
     Same contract as :func:`dynamic_routing_procedure_fused` — u_hat
     (B, L, H, C) -> v (B, H, C) — but ``jax.grad`` flows through a custom
-    VJP whose backward is a second megakernel replaying the routing loop
-    from VMEM (recompute-b), not jnp autodiff.  ``stream_dtype`` applies to
-    both directions: û streams at it in the 2T backward rows and ∂û is
-    written once at it (fp32 accumulation throughout).  The tile is sized
+    VJP, not jnp autodiff: the forward keeps its per-iteration c_t and s_t
+    (O(T·(L·H + B·H·C))) beside the packed û, and the backward is a second
+    megakernel that walks the iterations in reverse from them.
+    ``stream_dtype`` applies to both directions: û streams at it in T − 1
+    of the T backward rows and ∂û is written once at it (fp32 accumulation
+    throughout).  The tile is sized
     by :func:`procedure_train_l_tile` so forward and backward share one
     stream layout that fits the backward's larger VMEM working set.
 

@@ -117,7 +117,7 @@ def make_capsnet_train_step(caps_cfg, spec=None, plan=None,
     ``differentiable=True`` stamped on the spec (DESIGN.md §Training) —
     grads are about to flow through the router, so the pallas backend must
     resolve to the fused form that HAS a backward (the procedure
-    megakernel's recompute-b custom VJP) rather than a forward-only
+    megakernel's custom VJP) rather than a forward-only
     kernel:
 
       spec=None, plan=None      exact jnp routing (the autodiff reference)

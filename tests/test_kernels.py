@@ -503,7 +503,7 @@ def test_stage_update_fold_matches_split(key):
 @pytest.mark.parametrize("stream_dtype", ["fp32", "bf16"])
 @pytest.mark.parametrize("L", [64, 136])   # 136: non-divisible -> l_tile 68
 def test_procedure_vjp_grad_parity(key, iters, stream_dtype, L):
-    """jax.grad through the recompute-b backward megakernel vs jnp
+    """jax.grad through the backward megakernel vs jnp
     autodiff of the oracle, per stream dtype's GRAD_ATOL (the ISSUE's
     ≤1e-4 fp32 / ≤2e-2 bf16 per-element acceptance), across iteration
     counts and a non-divisible L tiling."""
@@ -515,26 +515,142 @@ def test_procedure_vjp_grad_parity(key, iters, stream_dtype, L):
 
 
 def test_procedure_vjp_saves_only_u_hat(key):
-    """The recompute-b claim itself: the VJP's residual set is û alone —
-    no per-iteration (L,H)/(B,H,C) intermediate survives the forward as
-    an autodiff residual."""
+    """The residual contract: the lane-packed û is the VJP's only
+    O(B·L·H·C) residual; the rest is the forward's own per-iteration
+    state, c_t (T, L, H) and s_t (T, B, H·C) in fp32 — no b, v or
+    cotangent-sized intermediate survives the forward."""
+    T = 3
     u_hat = jax.random.normal(key, (2, 64, 6, 8))
     B, L, H, C = u_hat.shape
     _, f_vjp = jax.vjp(functools.partial(
-        rt_ops.dynamic_routing_procedure_train, iterations=3), u_hat)
+        rt_ops.dynamic_routing_procedure_train, iterations=T), u_hat)
     residuals = [l for l in jax.tree.leaves(f_vjp)
                  if hasattr(l, "shape") and hasattr(l, "dtype")]
-    big = [r.shape for r in residuals if r.size > B * H * C]
-    assert big == [u_hat.shape], (
-        "recompute-b must keep û as the only large residual; "
+    big = [r.shape for r in residuals if r.size >= B * L * H * C]
+    assert big == [(B, L, H * C)], (
+        "the packed û must be the only large residual; "
         f"found {[r.shape for r in residuals]}")
+    small = sorted((r.shape, r.dtype) for r in residuals
+                   if r.size < B * L * H * C)
+    assert small == sorted([((T, L, H), jnp.float32),
+                            ((T, B, H * C), jnp.float32)]), small
     # jnp autodiff of the oracle, by contrast, drags per-iteration
     # O(B·L·H·C) residuals along — that contrast is the point
     _, ref_vjp = jax.vjp(functools.partial(
-        rt_ref.dynamic_routing_ref, iterations=3), u_hat)
+        rt_ref.dynamic_routing_ref, iterations=T), u_hat)
     ref_big = [l for l in jax.tree.leaves(ref_vjp)
-               if hasattr(l, "size") and l.size > B * H * C]
+               if hasattr(l, "size") and l.size >= B * L * H * C]
     assert len(ref_big) > 1
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation in ``jaxpr`` and its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _pallas_calls(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("iters", [1, 3])
+def test_procedure_residual_mode_matches_forward_and_ref(key, iters):
+    """The forward's residual mode returns bitwise the plain forward's v
+    and the packed û it streamed, and its c_t / s_t are the reference
+    loop's per-iteration couplings and vote sums."""
+    from repro.kernels.routing.kernel import routing_procedure_fused
+    u_hat = jax.random.normal(key, (2, 64, 6, 8))
+    B, L, H, C = u_hat.shape
+    plain = routing_procedure_fused(u_hat, iterations=iters, l_tile=16)
+    v, u_packed, c, s = routing_procedure_fused(
+        u_hat, iterations=iters, l_tile=16, residuals=True)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(plain))
+    np.testing.assert_array_equal(np.asarray(u_packed),
+                                  np.asarray(u_hat).reshape(B, L, H * C))
+    assert c.shape == (iters, L, H) and s.shape == (iters, B, H * C)
+    b = jnp.zeros((L, H), jnp.float32)
+    v_ref = jnp.zeros((B, H, C), jnp.float32)
+    for t in range(iters):
+        s_ref, b = rt_ref.routing_iteration_ref(u_hat, b, v_ref)
+        v_ref = rt_ref.squash(s_ref)
+        np.testing.assert_allclose(c[t], rt_ref.softmax_h(b),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(s[t], s_ref.reshape(B, H * C),
+                                   rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="no early exit"):
+        routing_procedure_fused(u_hat, l_tile=16, residuals=True,
+                                early_exit_eps=0.0)
+
+
+def test_procedure_bwd_grid_writes_du_once(key):
+    """The backward pallas_call under jax.grad walks one row per
+    iteration — grid (T, L/l_tile), no replay rows — and its ∂û block
+    index stays 0 before the final row, so each ∂û tile reaches HBM once;
+    the final row (t = 0) holds the û block the row before ended on, so
+    û streams T − 1 times."""
+    T, l_tile = 3, 16
+    u_hat = jax.random.normal(key, (2, 64, 6, 8))
+    L = u_hat.shape[1]
+    jaxpr = jax.make_jaxpr(jax.grad(lambda u: rt_ops.
+                                    dynamic_routing_procedure_train(
+                                        u, iterations=T,
+                                        l_tile=l_tile).sum()))(u_hat)
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert len(calls) == 2                       # forward, backward
+    gms = [e.params["grid_mapping"] for e in calls]
+    (bwd,) = [gm for gm in gms if gm.num_inputs == 4]
+    assert bwd.grid == (T, L // l_tile)
+    assert bwd.num_outputs == 1
+    du_map = bwd.block_mappings[bwd.num_inputs].index_map_jaxpr
+    u_map = bwd.block_mappings[0].index_map_jaxpr
+    n = L // l_tile
+    for r in range(T):
+        for j in range(n):
+            idx = [int(x) for x in jax.core.eval_jaxpr(
+                du_map.jaxpr, du_map.consts, r, j)]
+            assert idx == [0, j if r == T - 1 else 0, 0], (r, j, idx)
+            idx = [int(x) for x in jax.core.eval_jaxpr(
+                u_map.jaxpr, u_map.consts, r, j)]
+            assert idx == [0, n - 1 if r == T - 1 else j, 0], (r, j, idx)
+    # the forward writes its residuals once: constant index maps
+    (fwd,) = [gm for gm in gms if gm.num_outputs == 3]
+    for bm in fwd.block_mappings[fwd.num_inputs:]:
+        m = bm.index_map_jaxpr
+        assert {tuple(int(x) for x in jax.core.eval_jaxpr(
+            m.jaxpr, m.consts, r, j))
+            for r in range(T) for j in range(n)} == {(0,) * len(
+                bm.block_shape)}
+
+
+def test_procedure_serving_kernel_unchanged(key):
+    """With the residual mode off — every call but the custom VJP's
+    forward — routing_procedure_fused traces to the plain forward: the
+    same jaxpr as an explicit ``residuals=False``, and one pallas_call
+    with the plain kernel's refs (û in, v out, b/v/s scratch), one
+    output and no residual blocks."""
+    from repro.kernels.routing.kernel import routing_procedure_fused
+    u_hat = jax.random.normal(key, (2, 64, 6, 8))
+    B, L, H, C = u_hat.shape
+    plain = jax.make_jaxpr(functools.partial(
+        routing_procedure_fused, iterations=3, l_tile=16))(u_hat)
+    off = jax.make_jaxpr(functools.partial(
+        routing_procedure_fused, iterations=3, l_tile=16,
+        residuals=False))(u_hat)
+    assert str(plain) == str(off)
+    served = jax.make_jaxpr(functools.partial(
+        rt_ops.dynamic_routing_procedure_fused, iterations=3,
+        l_tile=16))(u_hat)
+    (call,) = list(_pallas_calls(served.jaxpr))
+    (ref_call,) = list(_pallas_calls(plain.jaxpr))
+    assert str(call.params["jaxpr"]) == str(ref_call.params["jaxpr"])
+    gm = call.params["grid_mapping"]
+    assert gm.grid == (3, L // 16) and gm.num_outputs == 1
+    assert len(call.params["jaxpr"].invars) == 5
+    assert [a.shape for a in call.params["out_avals"]] == [(B, H * C)]
 
 
 def test_procedure_vjp_finite_difference(key):
@@ -547,10 +663,11 @@ def test_procedure_vjp_finite_difference(key):
 
 
 def test_procedure_bwd_dma_model():
-    """Backward DMA model invariants: 2T û streams + one û-sized ∂û write
-    + the (B,H,C) cotangent read; bf16 halves both û-sized terms; fused
-    backward beats the modeled unfused-autodiff bill; non-procedure forms
-    have no backward model."""
+    """Backward DMA model invariants: T − 1 û streams (one per reverse
+    row but t = 0, which reads none) + one û-sized ∂û write + the (B,H,C)
+    cotangent and the T per-iteration (L,H) + (B,H,C) residuals read once;
+    bf16 halves both û-sized terms; fused backward beats the modeled
+    unfused-autodiff bill; non-procedure forms have no backward model."""
     B, L, H, C, iters = 4, 128, 10, 16, 3
     bw = rt_ops.dma_bytes_per_call(B, L, H, C, iters, form="procedure",
                                    backward=True)
@@ -558,10 +675,12 @@ def test_procedure_bwd_dma_model():
     bf = rt_ops.dma_bytes_per_call(B, L, H, C, iters, form="procedure",
                                    stream_dtype="bf16", backward=True)
     u = B * L * H * C * 4
-    assert bw["u_hat_stream_bytes"] == 2 * fw["u_hat_stream_bytes"]
+    res = iters * (L * H + B * H * C) * 4
+    assert fw["u_hat_stream_bytes"] == iters * u
+    assert bw["u_hat_stream_bytes"] == (iters - 1) * u
     assert bw["du_stream_bytes"] == u
-    assert bw["roundtrip_bytes"] == B * H * C * 4
-    assert bw["total_bytes"] == 2 * iters * u + u + B * H * C * 4
+    assert bw["roundtrip_bytes"] == B * H * C * 4 + res
+    assert bw["total_bytes"] == iters * u + B * H * C * 4 + res
     assert 2 * bf["u_hat_stream_bytes"] == bw["u_hat_stream_bytes"]
     assert 2 * bf["du_stream_bytes"] == bw["du_stream_bytes"]
     assert bw["total_bytes"] < bw["naive_bytes"]

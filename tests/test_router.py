@@ -493,7 +493,7 @@ def test_deep_edge_error_surface():
         build_router(pall._replace(early_exit_eps=0.1), sharded)
     with pytest.raises(ValueError, match="shard-local"):
         build_router(pall._replace(stream_dtype="int8"), sharded)
-    # ... and forward-only: the recompute-b VJP replays the fixed grid
+    # ... and forward-only: the backward walks the fixed grid
     # and has no dequant path
     with pytest.raises(ValueError, match="early_exit_eps=None"):
         build_router(pall._replace(differentiable=True, early_exit_eps=0.1))
@@ -651,7 +651,7 @@ def test_capsnet_serve_and_train_entry_points(key):
 
 
 def test_differentiable_router_grad_matches_jnp(key, u_hat):
-    """jax.grad through the differentiable pallas router (recompute-b
+    """jax.grad through the differentiable pallas router (the procedure
     custom VJP) == jax.grad through the jnp-backend autodiff reference,
     and resolve() reports the fused-differentiable execution."""
     fused = build_router(RouterSpec(backend="pallas", differentiable=True))

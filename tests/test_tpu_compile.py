@@ -79,7 +79,7 @@ def test_procedure_early_exit_compiles(one_chip, B):
 
 @pytest.mark.parametrize("B", BATCHES)
 def test_procedure_backward_compiles(one_chip, B):
-    """The recompute-b backward megakernel — the train path's kernel."""
+    """The backward megakernel — the train path's kernel."""
     def loss(u):
         return rt_ops.dynamic_routing_procedure_train(
             u, iterations=3, interpret=False).sum()
