@@ -157,6 +157,8 @@ class Algorithm:
         inputs/outputs under that axes mapping.
     sharded_dims: logical dims this algorithm can shard ("B"/"L"/"H").
     backends: supported kernel backends.
+    optional_inputs: trailing inputs a call may leave out (``in_specs``
+        lists them after the ``num_inputs`` required ones).
     """
     name: str
     run: Callable[[tuple, RouterSpec, Mapping[str, str]], Any]
@@ -165,6 +167,7 @@ class Algorithm:
     sharded_dims: Tuple[str, ...] = ("B", "L", "H")
     backends: Tuple[str, ...] = ("jnp",)
     num_inputs: int = 1
+    optional_inputs: int = 0
     describe: str = ""
 
 
@@ -264,31 +267,37 @@ DYNAMIC = register_algorithm(Algorithm(
 # --- "em" [Hinton, Sabour, Frosst 2018] ------------------------------------
 
 def _em_run(args, spec: RouterSpec, axes: Mapping[str, str]):
-    votes, a_in = args
+    """``args``: votes, a_in and optionally β_a, β_u — one per parent type
+    (H,) — which take the place of the scalar options.  Options: "conv"
+    (an ``em_routing.ConvGeometry``: convolutional connectivity, votes as
+    patch batches) and "logits" (return λ(β_a − Σ cost) for a_out)."""
+    votes, a_in, *betas = args
+    beta_a = betas[0] if betas else spec.option("beta_a", 1.0)
+    beta_u = betas[1] if len(betas) > 1 else spec.option("beta_u", 1.0)
     if spec.backend == "pallas":
         from repro.kernels.routing import ops as routing_ops
         return routing_ops.em_routing_fused(
             votes, a_in, axes=axes, iterations=spec.iterations,
-            beta_a=spec.option("beta_a", 1.0),
-            beta_u=spec.option("beta_u", 1.0),
+            beta_a=beta_a, beta_u=beta_u,
             inv_temp=spec.option("inv_temp", 1.0),
-            eps=spec.option("eps", 1e-9))
+            eps=spec.option("eps", 1e-9), conv=spec.option("conv"),
+            logits=spec.option("logits", False))
     cfg = em_lib.EMRoutingConfig(
         iterations=spec.iterations,
-        beta_a=spec.option("beta_a", 1.0),
-        beta_u=spec.option("beta_u", 1.0),
         inv_temp=spec.option("inv_temp", 1.0),
         eps=spec.option("eps", 1e-9),
         sharded_dim="L" if "L" in axes else None,
-        axis_name=axes.get("L"))
-    return em_lib.em_routing(votes, a_in, cfg)
+        axis_name=axes.get("L"),
+        conv=spec.option("conv"),
+        logits=spec.option("logits", False))
+    return em_lib.em_routing(votes, a_in, cfg, beta_a, beta_u)
 
 
 EM = register_algorithm(Algorithm(
     name="em",
     run=_em_run,
     in_specs=lambda ax: (P(ax.get("B"), ax.get("L"), None, None),
-                         P(ax.get("B"), ax.get("L"))),
+                         P(ax.get("B"), ax.get("L")), P(), P()),
     # pose (B,H,C) + activations (B,H); the L-psums leave outputs
     # replicated on L's axis, so only B stays sharded.
     out_specs=lambda ax: (P(ax.get("B"), None, None), P(ax.get("B"), None)),
@@ -296,7 +305,9 @@ EM = register_algorithm(Algorithm(
     sharded_dims=("B", "L"),
     backends=("jnp", "pallas"),
     num_inputs=2,
-    describe="EM routing: votes (B,L,H,C) + a_in (B,L) -> (pose, a_out)",
+    optional_inputs=2,
+    describe="EM routing: votes (B,L,H,C) + a_in (B,L) [+ beta_a, beta_u "
+             "(H,)] -> (pose, a_out)",
 ))
 
 
@@ -626,7 +637,8 @@ class Router:
 
     # -- executor construction ---------------------------------------------
 
-    def _core_fn(self, axes: Tuple[Tuple[str, str], ...]) -> Callable:
+    def _core_fn(self, axes: Tuple[Tuple[str, str], ...],
+                 n_inputs: Optional[int] = None) -> Callable:
         # invalid compositions (un-shardable dims with sharded axes) were
         # rejected in _validate; auto plans only resolve to dims that pass
         # the same filters (plan_axes).  Both backends are sharding-aware:
@@ -638,16 +650,16 @@ class Router:
             return lambda *args: algo.run(args, spec, {})
         return compat.shard_map(
             lambda *args: algo.run(args, spec, ax),
-            self._mesh(), tuple(algo.in_specs(ax)), algo.out_specs(ax))
+            self._mesh(), tuple(algo.in_specs(ax))[:n_inputs],
+            algo.out_specs(ax))
 
     def _stage_b(self, axes: Tuple[Tuple[str, str], ...]) -> Callable:
         """Pipeline stage B: the algorithm body consuming the stage-A
         hand-off — a bare votes array for 1-input algorithms, a tuple for
         multi-input ones (EM's (votes, a_in) — DESIGN.md §Serving)."""
-        core = self._core_fn(axes)
         if self.algorithm.num_inputs == 1:
-            return core
-        return lambda h: core(*h)
+            return self._core_fn(axes)
+        return lambda h: self._core_fn(axes, len(h))(*h)
 
     def _pipelined_fn(self, micro) -> Callable:
         plan = self.plan
@@ -692,9 +704,9 @@ class Router:
         plan, algo, spec = self.plan, self.algorithm, self.spec
         mesh = self._mesh()
         ax = dict(axes)
-        in_specs = tuple(algo.in_specs(ax))
         structs = list(hidden) if algo.num_inputs > 1 else [hidden]
-        if len(structs) != len(in_specs):
+        in_specs = tuple(algo.in_specs(ax))[:len(structs)]
+        if len(structs) < algo.num_inputs or len(structs) != len(in_specs):
             raise ValueError(
                 f"stage_a must hand off {algo.num_inputs} leaves in "
                 f"{algo.name!r}'s argument order; got {len(structs)}")
@@ -758,16 +770,18 @@ class Router:
                 fn = self._pipelined_fn(args[0])
             else:
                 fn = self._core_fn(self._resolve_shapes(
-                    tuple(jnp.shape(a) for a in args)))
+                    tuple(jnp.shape(a) for a in args)), len(args))
             self._cache[key] = fn
         return fn
 
     def __call__(self, *args):
+        algo = self.algorithm
         if (self.plan.pipeline is None
-                and len(args) != self.algorithm.num_inputs):
+                and not algo.num_inputs <= len(args)
+                <= algo.num_inputs + algo.optional_inputs):
             raise TypeError(
                 f"{self.spec.algorithm!r} router takes "
-                f"{self.algorithm.num_inputs} input(s) "
+                f"{algo.num_inputs} input(s) "
                 f"({self.algorithm.describe or 'see registry entry'}); "
                 f"got {len(args)}")
         return self._executor(args)(*args)
@@ -897,6 +911,17 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
                 "train with backend='jnp' under sharded/pipelined plans, "
                 "or use plan=None/'auto' (auto resolves unsharded when "
                 "differentiable)")
+    conv = spec.option("conv")
+    if conv is not None:
+        if algo.name != "em" or not isinstance(conv, em_lib.ConvGeometry):
+            raise ValueError(
+                "option conv= takes an em_routing.ConvGeometry and the 'em' "
+                f"algorithm; got {conv!r} for {algo.name!r}")
+        if plan.axes or plan.auto:
+            raise ValueError(
+                "convolutional EM routing is shard-local: a child's "
+                "responsibilities normalise across the windows of its "
+                "image, which a sharded plan would split; use plan=None")
     bad = [d for d, _ in plan.axes if d not in algo.sharded_dims]
     if bad:
         raise ValueError(

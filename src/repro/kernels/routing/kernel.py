@@ -901,7 +901,7 @@ def _em_stats_kernel(v_ref, rw_ref, rsum_ref, rv_ref, rv2_ref, *, c_dim: int):
 
 
 def _em_estep_kernel(v_ref, mu_ref, isig_ref, bias_ref, r_ref, *, h: int,
-                     c_dim: int):
+                     c_dim: int, normalize: bool):
     """EM E-step, one grid step = one L tile: responsibilities.
 
     v_ref:    (B, L_t, H·C) votes tile (streamed, read once)
@@ -910,11 +910,16 @@ def _em_estep_kernel(v_ref, mu_ref, isig_ref, bias_ref, r_ref, *, h: int,
               kernel is MAC-only, like the paper's PE datapath)
     bias_ref: (B, H) log a_out - ½ Σ_c log(2πσ²) (host-precomputed)
     r_ref:    (B, L_t, H) output responsibilities (softmax over H; H is
-              fully resident — EM never shards H)
+              fully resident — EM never shards H) — or, without
+              ``normalize``, the logits, for a convolutional layer whose
+              children normalise across windows (em_routing.ConvGeometry)
     """
     d = v_ref[...].astype(jnp.float32) - mu_ref[...][:, None]
     dist = _caps_sum(d * d * isig_ref[...][:, None], h, c_dim)  # (B, L_t, H)
     logits = bias_ref[...][:, None] - 0.5 * dist
+    if not normalize:
+        r_ref[...] = logits
+        return
     m = jnp.max(logits, axis=-1, keepdims=True)
     e = jnp.exp(logits - m)
     r_ref[...] = e / jnp.sum(e, axis=-1, keepdims=True)
@@ -953,15 +958,18 @@ def em_stage_stats(votes: jax.Array, r: jax.Array, a_in: jax.Array, *,
     return rsum, rv.reshape(B, H, C), rv2.reshape(B, H, C)
 
 
-@functools.partial(jax.jit, static_argnames=("l_tile", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("l_tile", "normalize", "interpret"))
 def em_stage_estep(votes: jax.Array, mu: jax.Array, inv_sigma2: jax.Array,
                    bias: jax.Array, *, l_tile: int = 128,
-                   interpret: bool | None = None):
-    """EM E-step: -> responsibilities r (B, L, H)."""
+                   normalize: bool = True, interpret: bool | None = None):
+    """EM E-step: -> responsibilities r (B, L, H), each row's softmax over
+    H; ``normalize=False`` returns the logits instead."""
     B, L, H, C = votes.shape
     _check_tile(L, l_tile)
     return pl.pallas_call(
-        functools.partial(_em_estep_kernel, h=H, c_dim=C),
+        functools.partial(_em_estep_kernel, h=H, c_dim=C,
+                          normalize=normalize),
         grid=(L // l_tile,),
         in_specs=[
             pl.BlockSpec((B, l_tile, H * C), lambda i: (0, i, 0)),

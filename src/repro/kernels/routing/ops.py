@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import em_routing as em_lib
 from repro.core import routing as routing_lib
 from repro.kernels.routing.kernel import (VMEM_LIMIT_BYTES, em_stage_estep,
                                           em_stage_stats,
@@ -730,34 +731,43 @@ def dynamic_routing_fused_sharded(u_hat: jax.Array, *,
 
 def em_routing_fused(votes: jax.Array, a_in: jax.Array, *,
                      axes: Mapping[str, str],
-                     iterations: int = 3, beta_a: float = 1.0,
-                     beta_u: float = 1.0, inv_temp: float = 1.0,
-                     eps: float = 1e-9, l_tile: int | None = None,
+                     iterations: int = 3, beta_a=1.0, beta_u=1.0,
+                     inv_temp: float = 1.0, eps: float = 1e-9,
+                     conv: Optional[em_lib.ConvGeometry] = None,
+                     logits: bool = False, l_tile: int | None = None,
                      interpret: bool | None = None):
     """EM routing via the stage-split Pallas kernels (paper §2.2 generality).
 
-    votes: per-shard (B, L, H, C); a_in: per-shard (B, L).  ``axes`` maps
+    votes: per-shard (B, L, H, C); a_in: per-shard (B, L); ``beta_a``,
+    ``beta_u``: scalars or one per parent type (H,).  ``axes`` maps
     sharded dims to mesh axes — "L" psums the M-step sufficient statistics
     (the Table-2 aggregation); "B" shards are fully independent (EM keeps
     no cross-batch state, so no collective is needed); H is rejected by the
     Router (per-H Gaussian statistics cannot split).
 
+    With ``conv`` (a convolutional layer, shard-local) the rows are patch
+    batches: the E-step kernel emits logits and
+    ``em_routing.cross_window_softmax`` normalises each child over every
+    window that holds it before the next M-step.
+
     σ² is recombined from streamed sufficient statistics
     (Σrw·v² - 2μ·Σrw·v + μ²·Σrw — one votes pass instead of the naive
     two with a materialised (votes-μ)² tensor), clamped at 0 before the
-    +eps floor against catastrophic cancellation.  Matches
+    +eps floor against catastrophic cancellation.  The last iteration's
+    E-step has no reader and is skipped.  Matches
     ``core.em_routing.em_routing`` to float tolerance.
 
-    Returns (pose μ (B, H, C), a_out (B, H)).
+    Returns (pose μ (B, H, C), a_out (B, H)) — with ``logits`` the
+    activation logits λ(β_a − Σ cost) in place of a_out.
     """
     votes = votes.astype(jnp.float32)
     B, L, H, C = votes.shape
     if l_tile is None:
         l_tile = em_l_tile(B, L, H, C)
     l_axis = axes.get("L")
-    r = jnp.full((B, L, H), 1.0 / H, jnp.float32)
-    mu = jnp.zeros((B, H, C), jnp.float32)
-    a_out = jnp.zeros((B, H), jnp.float32)
+    b_a = jnp.asarray(beta_a, jnp.float32)
+    b_u = jnp.asarray(beta_u, jnp.float32)[..., None]
+    r = em_lib.initial_responsibilities((B, L, H), conv)
     for it in range(iterations):
         lam = inv_temp * (1.0 - 0.95 ** (it + 1))
         # ---- M-step: one streamed pass + cross-shard psum over L ----
@@ -770,12 +780,17 @@ def em_routing_fused(votes: jax.Array, a_in: jax.Array, *,
         mu = rv / r_sum[..., None]
         var = rv2 - 2.0 * mu * rv + jnp.square(mu) * rsum_raw[..., None]
         sigma2 = jnp.maximum(var, 0.0) / r_sum[..., None] + eps
-        cost = (beta_u + 0.5 * jnp.log(sigma2)) * r_sum[..., None]
-        a_out = jax.nn.sigmoid(lam * (beta_a - jnp.sum(cost, axis=-1)))
+        cost = (b_u + 0.5 * jnp.log(sigma2)) * r_sum[..., None]
+        logit = lam * (b_a - jnp.sum(cost, axis=-1))
+        a_out = jax.nn.sigmoid(logit)
+        if it + 1 == iterations:
+            break
         # ---- E-step: host precomputes the Gaussian constants so the
         # ---- kernel pass is MAC-only ----
         bias = jnp.log(a_out + eps) - 0.5 * jnp.sum(
             jnp.log(2.0 * jnp.pi * sigma2), axis=-1)            # (B, H)
         r = em_stage_estep(votes, mu, 1.0 / sigma2, bias, l_tile=l_tile,
-                           interpret=interpret)
-    return mu, a_out
+                           normalize=conv is None, interpret=interpret)
+        if conv is not None:
+            r = em_lib.cross_window_softmax(r, conv)
+    return mu, (logit if logits else a_out)

@@ -86,7 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import router as router_lib
-from repro.models import capsnet
+from repro.models import capsnet, matrix_capsnet
 from repro.runtime import wave_serve
 from repro.runtime.wave_serve import (  # noqa: F401 — pre-WaveServe API
     OVERFLOW_POLICIES,
@@ -250,6 +250,42 @@ class CapsAdapter(wave_serve.WorkloadAdapter):
         # fleets cache compiled waves per (spec, cfg) — the pre-WaveServe
         # key, so test fixtures seeding {(None, cfg): wave_fn} still hit
         return self.spec
+
+
+class MatrixCapsAdapter(CapsAdapter):
+    """Matrix capsules with EM routing (``models.matrix_capsnet``) as a
+    ``WorkloadAdapter``: ``CapsAdapter``'s payloads, packing and lane
+    mask, and a wave of the matrix-capsule forward over the
+    ``(n_micro, microbatch)`` lanes, one microbatch after another (at
+    microbatch 8 one ConvCaps1 call holds 170 MB of votes; a 128-lane
+    wave at once would hold 3.9 GB).  ``cfg.pipeline`` and
+    ``cfg.routing_plan`` do not apply: EM's convolutional layers route
+    shard-local.  The wave's output is (class activation logits
+    (n_micro, mb, E), class poses (n_micro, mb, E, 16)); a request's
+    result is its argmax class.  A padded lane's child activations are 0
+    in every layer, and EM keeps no state across lanes, so padding cannot
+    move a real lane."""
+
+    def make_wave_fn(self, cfg: ServeConfig) -> Callable:
+        return matrix_capsnet.make_wave_fn(self.params, self.caps_cfg,
+                                           self._em_spec())
+
+    def make_reference_wave_fn(self, cfg: ServeConfig) -> Callable:
+        return matrix_capsnet.make_wave_fn(
+            self.params, self.caps_cfg,
+            router_lib.reference_spec(self._em_spec()))
+
+    def _em_spec(self) -> router_lib.RouterSpec:
+        return self.spec or router_lib.RouterSpec(algorithm="em")
+
+    def unpack(self, out, n: int) -> List[int]:
+        return super().unpack(out[0], n)
+
+    def finite(self, out) -> bool:
+        return all(bool(np.isfinite(np.asarray(x)).all()) for x in out)
+
+    def cache_key(self):
+        return ("matrix", self.spec)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,10 @@ Spans the program opens (PERF.md names the metric that reads each):
 Named scopes inside the jitted programs (``jax.named_scope``) are set
 where the code they cover lives: ``capsnet.encode``, ``capsnet.routing``,
 ``capsnet.decoder``, ``routing.relayout``, ``train.clip``,
-``train.adamw``.
+``train.adamw``; in the matrix-capsule model ``emcaps.convcaps1``,
+``emcaps.convcaps2`` and ``emcaps.class`` around each routed layer (its
+votes under ``capsnet.encode``, its route call under ``capsnet.routing``)
+and ``routing.em_fold`` around EM's cross-window normalisation.
 """
 from __future__ import annotations
 

@@ -1,8 +1,9 @@
 """Compile the routing kernels of the main path for a described TPU v5e.
 
 No chip is attached: the TPU compiler is installed, so each test lowers a
-kernel at Caps-MN1 widths (L=1152, H=10, C=16) for the first device of a
-described ``v5e:2x2`` topology and compiles it — Mosaic refuses here what
+kernel at Caps-MN1 widths (L=1152, H=10, C=16) — and the EM stage kernels
+at the matrix-capsule cell's shapes — for the first device of a described
+``v5e:2x2`` topology and compiles it — Mosaic refuses here what
 it would refuse on the chip (shape casts it cannot lay out, blocks that
 break the tiling, more scoped VMEM than the limit).  The ops are called
 with ``interpret=False``; nothing runs.
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
+from repro.core.em_routing import ConvGeometry
 from repro.kernels.routing import kernel as rt_kernel
 from repro.kernels.routing import ops as rt_ops
 
@@ -108,3 +110,29 @@ def test_em_stages_compile(one_chip, B):
     _compile(functools.partial(rt_ops.em_routing_fused, axes={},
                                iterations=3, interpret=False),
              _votes(one_chip, B), a_in)
+
+
+# The emnorb-serve-rate cell (bench/configs/emcaps-norb.json): ConvCaps1
+# and ConvCaps2 as patch batches of a microbatch of 8 (B' = 8·36 and 8·16
+# windows of L = 3·3·32 children, H = 32, C = 16) with the cross-window
+# fold between the stage kernels, and the class layer (L = 4·4·32, H = 5).
+EM_NORB = {"convcaps1": (8 * 36, 288, 32, (3, 2, 14, 14)),
+           "convcaps2": (8 * 16, 288, 32, (3, 1, 6, 6)),
+           "class": (8, 512, 5, None)}
+
+
+@pytest.mark.parametrize("layer", sorted(EM_NORB))
+def test_em_norb_layers_compile(one_chip, layer):
+    rows, L, H, geom = EM_NORB[layer]
+    conv = ConvGeometry(*geom) if geom else None
+    structs = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+               for s in ((rows, L, H, C), (rows, L), (H,), (H,))]
+
+    def layer_fn(votes, a_in, beta_a, beta_u):
+        return rt_ops.em_routing_fused(
+            votes, a_in, axes={}, iterations=3, beta_a=beta_a,
+            beta_u=beta_u, conv=conv, logits=conv is None,
+            interpret=False)
+
+    text = _compile(layer_fn, *structs)
+    assert "em_stage_stats" in text and "em_stage_estep" in text
