@@ -106,17 +106,37 @@ def _dot(a, b):
                    preferred_element_type=jnp.float32)
 
 
-def _caps_sum(x, h: int, c_dim: int):
+def _seg_dot(x, seg):
+    """x @ seg for a 0/1 segment matrix, exact to fp32 accumulation in
+    three single-pass bf16 MXU products.  x splits exactly into three
+    bf16 terms (hi = bf16(x), then bf16 of each remainder: 8 significant
+    bits apiece, 24 in all) and a 0/1 matrix is exact in bf16, so every
+    product is exact — what ``HIGHEST`` gives for such a matrix, which
+    splits both operands and takes six passes.  The EM kernels take it:
+    their segment dots (K or N = H, a fraction of the MXU) bound them at
+    ``HIGHEST``."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    seg = seg.astype(jnp.bfloat16)
+    out = jnp.dot(hi, seg, preferred_element_type=jnp.float32)
+    for part in (mid, lo):
+        out = out + jnp.dot(part, seg, preferred_element_type=jnp.float32)
+    return out
+
+
+def _caps_sum(x, h: int, c_dim: int, dot=_dot):
     """(..., H·C) -> (..., H): the sum over each capsule's C lanes."""
     lead = x.shape[:-1]
-    y = _dot(x.reshape(-1, h * c_dim), _segment(h, c_dim))
+    y = dot(x.reshape(-1, h * c_dim), _segment(h, c_dim))
     return y.reshape(lead + (h,))
 
 
-def _caps_bcast(y, c_dim: int):
+def _caps_bcast(y, c_dim: int, dot=_dot):
     """(..., H) -> (..., H·C): each capsule's value on its C lanes."""
     lead, h = y.shape[:-1], y.shape[-1]
-    x = _dot(y.reshape(-1, h), _segment(h, c_dim, transpose=True))
+    x = dot(y.reshape(-1, h), _segment(h, c_dim, transpose=True))
     return x.reshape(lead + (h * c_dim,))
 
 
@@ -882,7 +902,7 @@ def _em_stats_kernel(v_ref, rw_ref, rsum_ref, rv_ref, rv2_ref, *, c_dim: int):
     """
     v = v_ref[...].astype(jnp.float32)
     rw = rw_ref[...]
-    rw_c = _caps_bcast(rw, c_dim)                            # (B, L_t, H·C)
+    rw_c = _caps_bcast(rw, c_dim, _seg_dot)                  # (B, L_t, H·C)
     rsum_p = jnp.sum(rw, axis=1)                             # (B, H)
     rv_p = jnp.sum(rw_c * v, axis=1)                         # (B, H·C)
     rv2_p = jnp.sum(rw_c * (v * v), axis=1)                  # (B, H·C)
@@ -915,7 +935,8 @@ def _em_estep_kernel(v_ref, mu_ref, isig_ref, bias_ref, r_ref, *, h: int,
               children normalise across windows (em_routing.ConvGeometry)
     """
     d = v_ref[...].astype(jnp.float32) - mu_ref[...][:, None]
-    dist = _caps_sum(d * d * isig_ref[...][:, None], h, c_dim)  # (B, L_t, H)
+    dist = _caps_sum(d * d * isig_ref[...][:, None], h, c_dim,
+                     _seg_dot)                               # (B, L_t, H)
     logits = bias_ref[...][:, None] - 0.5 * dist
     if not normalize:
         r_ref[...] = logits

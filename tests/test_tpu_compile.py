@@ -16,9 +16,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro import compat
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
 from repro.core.em_routing import ConvGeometry
 from repro.kernels.routing import kernel as rt_kernel
@@ -135,4 +138,22 @@ def test_em_norb_layers_compile(one_chip, layer):
             interpret=False)
 
     text = _compile(layer_fn, *structs)
+    assert "em_stage_stats" in text and "em_stage_estep" in text
+
+
+def test_em_l_sharded_compiles(topo):
+    """EM with L sharded over the four chips: the statistics pass, the
+    Table-2 psum of its sums, then the E-step pass."""
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    spec = P(None, "x")
+    structs = [jax.ShapeDtypeStruct(s, jnp.float32,
+                                    sharding=NamedSharding(mesh, spec))
+               for s in ((8, 512, 32, C), (8, 512))]
+
+    def routed(votes, a_in):
+        return rt_ops.em_routing_fused(votes, a_in, axes={"L": "x"},
+                                       iterations=3, interpret=False)
+
+    text = _compile(compat.shard_map(routed, mesh, in_specs=(spec, spec),
+                                     out_specs=(P(), P())), *structs)
     assert "em_stage_stats" in text and "em_stage_estep" in text
