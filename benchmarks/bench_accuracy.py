@@ -72,11 +72,12 @@ def train(cfg, key):
     return params, ds
 
 
-def evaluate(params, ds, cfg, rc=None, router=None):
-    """Top-1 accuracy of ``params`` under either a RoutingConfig (``rc``)
-    or a built Router (``router`` — how the deep-edge arms route)."""
+def evaluate(params, ds, cfg, router):
+    """Top-1 accuracy of ``params`` routed by ``router``: a RoutingConfig
+    (the jnp reference) or a built Router (how the deep-edge arms
+    route)."""
     fwd = jax.jit(functools.partial(capsnet.forward, cfg=cfg,
-                                    routing_cfg=rc, router=router))
+                                    router=router))
     hits = n = 0
     for i in range(1000, 1000 + EVAL_BATCHES):
         b = ds.batch(i, EVAL_BS)
@@ -124,7 +125,7 @@ def main():
     def deep_edge(**kw):
         r = build_router(RouterSpec(algorithm="dynamic", backend="pallas",
                                     iterations=it, **kw))
-        return evaluate(params, ds, cfg, router=r)
+        return evaluate(params, ds, cfg, r)
 
     acc_int8 = deep_edge(stream_dtype="int8")
     acc_ee = deep_edge(early_exit_eps=EARLY_EXIT_EPS)

@@ -14,12 +14,12 @@ Four complementary measurements:
     at the Table-2 aggregation points.
 
 (3) MEASURED, procedure-fused arms (fp32 + bf16 û streaming):
-    ``RouterSpec(backend="pallas", fusion="procedure")`` — the
-    whole-procedure megakernel (DESIGN.md §Procedure-fused).  Every row
-    cross-checks the measured output against the jnp backend (<=1e-5 for
-    fp32 arms) and attaches the modeled DMA bytes of all three kernel
-    forms; the model itself is self-checked (procedure eliminates the
-    (L,H)/(B,H,C) round-trips, bf16 halves the û stream bytes).
+    ``RouterSpec(backend="pallas")`` — the whole-procedure megakernel
+    (DESIGN.md §Procedure-fused).  Every row cross-checks the measured
+    output against the jnp backend (<=1e-5 for fp32 arms) and attaches
+    the modeled DMA bytes of both kernel forms; the model itself is
+    self-checked (procedure keeps only the final v write beside the û
+    stream, bf16 halves the û stream bytes).
 
     Off-TPU every pallas arm runs in interpret mode and carries
     ``"modeled_only": true`` — its wall-clock documents plumbing, not
@@ -83,8 +83,8 @@ def dma_model_row(B: int, L: int, H: int, C: int, iters: int) -> dict:
     acceptance cross-checks applied (raise = the model or the kernel
     regressed, fail the bench):
 
-    * procedure-fusion eliminates the per-iteration (L,H)/(B,H,C)
-      round-trips — only the final v write remains;
+    * the procedure megakernel keeps b/v/s on chip — only the final v
+      write remains beside the û stream;
     * bf16 û streaming halves the stream bytes of the only large operand;
     * int8 û streaming quarters them (per-tile scales are O(L/l_tile),
       not modeled — DESIGN.md §Quantized-routing);
@@ -98,8 +98,6 @@ def dma_model_row(B: int, L: int, H: int, C: int, iters: int) -> dict:
     """
     ee_floor = min(iters, 2) / iters
     model = {
-        "iteration_fused": rt_ops.dma_bytes_per_call(
-            B, L, H, C, iters, form="iteration"),
         "procedure_fused_fp32": rt_ops.dma_bytes_per_call(
             B, L, H, C, iters, form="procedure"),
         "procedure_fused_bf16": rt_ops.dma_bytes_per_call(
@@ -116,8 +114,8 @@ def dma_model_row(B: int, L: int, H: int, C: int, iters: int) -> dict:
         "sharded_stage_split_unfolded": rt_ops.dma_bytes_per_call(
             B, L, H, C, iters, form="stage_split"),
     }
-    it, pf = model["iteration_fused"], model["procedure_fused_fp32"]
-    assert pf["roundtrip_bytes"] == B * H * C * 4 < it["roundtrip_bytes"], (
+    pf = model["procedure_fused_fp32"]
+    assert pf["roundtrip_bytes"] == B * H * C * 4, (
         "procedure-fused roundtrip traffic not eliminated", model)
     assert (2 * model["procedure_fused_bf16"]["u_hat_stream_bytes"]
             == pf["u_hat_stream_bytes"]), (
@@ -132,7 +130,6 @@ def dma_model_row(B: int, L: int, H: int, C: int, iters: int) -> dict:
             == int(round(pf["u_hat_stream_bytes"] * ee_floor))), (
         "early-exit floor row must scale exactly the û stream", model)
     assert ee["early_exit_work_fraction"] == ee_floor, model
-    assert pf["total_bytes"] < it["total_bytes"], model
     assert (model["sharded_stage_split_unfolded"]["total_bytes"]
             - model["sharded_stage_split"]["total_bytes"]
             == iters * 2 * L * H * 4), (
@@ -218,15 +215,14 @@ def measured_speedups(batch: int = 2):
         # procedure-fused arms: the whole-procedure megakernel, fp32 and
         # bf16 û streaming (DESIGN.md §Procedure-fused)
         proc = build_router(RouterSpec(
-            algorithm="dynamic", backend="pallas", iterations=iters,
-            fusion="procedure"))
+            algorithm="dynamic", backend="pallas", iterations=iters))
         proc_bf16 = build_router(RouterSpec(
             algorithm="dynamic", backend="pallas", iterations=iters,
-            fusion="procedure", stream_dtype="bf16"))
+            stream_dtype="bf16"))
         # deep-edge arm: int8 û streaming (DESIGN.md §Quantized-routing)
         proc_int8 = build_router(RouterSpec(
             algorithm="dynamic", backend="pallas", iterations=iters,
-            fusion="procedure", stream_dtype="int8"))
+            stream_dtype="int8"))
 
         # measured-output cross-check vs the jnp backend (acceptance:
         # <=1e-5 for fp32 arms; bf16/int8 deltas recorded with loose
@@ -350,10 +346,8 @@ def main():
           "[modeled_only]; the traffic claim is the kernel DMA model, "
           "kernels/routing/ops.py::dma_bytes_per_call)")
     d0 = measured[0]["dma_model"]
-    print(f"# DMA model ({measured[0]['network']}): iteration-fused "
-          f"{d0['iteration_fused']['total_bytes']:,}B -> procedure-fused "
+    print(f"# DMA model ({measured[0]['network']}): procedure-fused "
           f"{d0['procedure_fused_fp32']['total_bytes']:,}B (roundtrip "
-          f"{d0['iteration_fused']['roundtrip_bytes']:,}B -> "
           f"{d0['procedure_fused_fp32']['roundtrip_bytes']:,}B), bf16 û "
           f"stream {d0['procedure_fused_bf16']['u_hat_stream_bytes']:,}B, "
           f"int8 {d0['procedure_fused_int8']['u_hat_stream_bytes']:,}B")

@@ -69,7 +69,8 @@ def main():
               + f"{auto_axes or 'unsharded'}")
 
     # 4 — fused-kernel backend (Pallas; the capability check selects
-    #     interpret mode off-TPU), replacing the old fused=True bool.
+    #     interpret mode off-TPU): unsharded, the whole-procedure
+    #     megakernel.
     router_fused = build_router(RouterSpec(iterations=cfg.routing_iters,
                                            backend="pallas"))
     out_fused = capsnet.forward(params, images, cfg, router=router_fused)

@@ -17,7 +17,6 @@ if [[ "${1:-}" != "--fast" ]]; then
   echo "== parity: procedure-fused megakernel vs jnp backend =="
   python -m pytest -q \
     "tests/test_kernels.py::test_routing_procedure_fused_vs_jnp" \
-    "tests/test_kernels.py::test_routing_procedure_matches_iteration_fused" \
     "tests/test_router.py::test_fusion_procedure_matches_jnp"
 
   echo "== grad parity: procedure custom VJP vs jnp autodiff =="
@@ -69,8 +68,7 @@ for row in arms:
                     "procedure_fused_bf16", "procedure_fused_int8"):
             assert row[arm]["modeled_only"] is True, (arm, row)
     dma = row["dma_model"]
-    it, pf = dma["iteration_fused"], dma["procedure_fused_fp32"]
-    assert pf["roundtrip_bytes"] < it["roundtrip_bytes"], dma
+    pf = dma["procedure_fused_fp32"]
     assert (2 * dma["procedure_fused_bf16"]["u_hat_stream_bytes"]
             == pf["u_hat_stream_bytes"]), dma
     # int8 quarters the û stream, leaves the fp32 b/v/s roundtrip alone

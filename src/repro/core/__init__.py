@@ -3,7 +3,7 @@
 The paper's primary contribution as a composable JAX module — see DESIGN.md.
 """
 from repro.core.routing import (RoutingConfig, dynamic_routing,
-                                routing_iteration, make_sharded_routing)
+                                routing_iteration)
 from repro.core.distribution import (RPShape, DeviceModel, plan, score_table,
                                      workload_E, comm_M, execution_score,
                                      moe_plan, MoEShape, rmas_optimal_grant)
@@ -16,9 +16,9 @@ __all__ = [
     # unified Router API (DESIGN.md §Router) — the preferred entry point
     "RouterSpec", "ExecutionPlan", "Router", "build_router", "as_router",
     "Algorithm", "register_algorithm", "registered_algorithms", "router",
-    # legacy surface (kept; make_sharded_* are deprecation shims)
+    # the jnp reference path and the §5.1 planner
     "RoutingConfig", "dynamic_routing", "routing_iteration",
-    "make_sharded_routing", "RPShape", "DeviceModel", "plan", "score_table",
+    "RPShape", "DeviceModel", "plan", "score_table",
     "workload_E", "comm_M", "execution_score", "moe_plan", "MoEShape",
     "rmas_optimal_grant", "approx", "capsule_layers", "em_routing", "pipeline",
 ]

@@ -82,7 +82,7 @@ def caps_layer_forward(params, u: jax.Array, route) -> jax.Array:
     ``route`` selects the routing execution (DESIGN.md §Router):
       * a built ``repro.core.router.Router`` (or any callable u_hat -> v),
       * a ``RouterSpec`` (built on the spot, unsharded plan),
-      * a legacy ``RoutingConfig`` — runs ``dynamic_routing`` directly so
+      * a ``RoutingConfig`` — runs the jnp ``dynamic_routing`` directly so
         ambient-axis collectives still work when the caller is already
         inside its own shard_map (e.g. the full-train dry-run cell).
     """

@@ -184,27 +184,3 @@ def em_routing(votes: jax.Array, a_in: jax.Array,
              else cross_window_softmax(logits, cfg.conv))
     return mu, (logit if cfg.logits else a_out)
 
-
-def make_sharded_em_routing(mesh, dim: str, axis_name: str,
-                            cfg: EMRoutingConfig = EMRoutingConfig(),
-                            backend: str = "jnp"):
-    """DEPRECATED shim — use ``repro.core.router.build_router`` instead.
-
-    The paper's §5.1 distribution applied to EM routing (its claimed
-    generality: "can be easily applied to other routing algorithms").
-
-    dim "L": the M-step's three L-aggregations become psums on
-    ``axis_name`` (the same Table-2 structure as Dynamic Routing's Eq.2);
-    dim "B": every batch shard is independent — no collectives at all
-    (EM's statistics are per-input, unlike Dynamic Routing's shared b).
-    backend "pallas" routes the heavy M/E-step passes through the
-    stage-split kernels (DESIGN.md §Sharded-fused).
-    """
-    from repro.core import router as router_lib
-    spec = router_lib.RouterSpec(
-        algorithm="em", backend=backend,
-        iterations=cfg.iterations).with_options(
-            beta_a=cfg.beta_a, beta_u=cfg.beta_u,
-            inv_temp=cfg.inv_temp, eps=cfg.eps)
-    plan = router_lib.ExecutionPlan(mesh=mesh, axes=((dim, axis_name),))
-    return router_lib.build_router(spec, plan)

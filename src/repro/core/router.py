@@ -15,14 +15,13 @@ Three orthogonal choices compose:
   * RouterSpec — WHAT to route: an algorithm from the registry ("dynamic"
     [Sabour et al. 2017] or "em" [Hinton et al. 2018], both over the common
     (B, L, H, C) vote layout) and a kernel backend ("jnp" | "pallas"; the
-    Pallas backend replaces the old ``RoutingConfig.fused`` bool and runs
-    the fused kernels, in interpret mode off-TPU).  The ``fusion`` knob
-    picks between the whole-procedure megakernel and the per-iteration
-    kernel (DESIGN.md §Procedure-fused), ``stream_dtype`` selects fp32 or
-    bf16 û streaming.  With a sharded plan the Pallas backend switches to
-    the stage-split sharded-fused form: per-shard Pallas stages with
-    cross-shard psums at the paper's Table-2 aggregation points (DESIGN.md
-    §Sharded-fused).
+    Pallas backend runs the fused kernels, in interpret mode off-TPU).
+    Shard-local, the Pallas backend runs the whole-procedure megakernel
+    (DESIGN.md §Procedure-fused); ``stream_dtype`` selects fp32, bf16 or
+    int8 û streaming.  With a sharded plan it switches to the stage-split
+    sharded-fused form: per-shard Pallas stages with cross-shard psums at
+    the paper's Table-2 aggregation points (DESIGN.md §Sharded-fused).
+    The code picks the form from the plan; no option does.
   * ExecutionPlan — WHERE/HOW to run it: unsharded, one dim sharded over a
     mesh axis (the paper's inter-vault distribution), several dims at once
     (2D torus), or the paper's §4 host||PIM two-stage pipeline.  With
@@ -63,25 +62,18 @@ class RouterSpec(NamedTuple):
     """Static routing specification (hashable; safe as a jit static arg).
 
     algorithm: registry name ("dynamic" | "em" | user-registered).
-    backend:   "jnp" (pure-XLA path) or "pallas" (fused kernels; replaces
-               the old ``RoutingConfig.fused`` bool; composes with sharded
-               plans via the stage-split sharded-fused form).
-    fusion:    pallas-backend fusion level (DESIGN.md §Procedure-fused):
-               "auto" (default — whole-procedure megakernel when the plan
-               is shard-local and the VMEM model fits, per-iteration kernel
-               otherwise), "procedure" (force the megakernel; rejects
-               sharded plans) or "iteration" (force the per-iteration
-               kernel).  Under a sharded plan execution is always the
-               stage-split form; ``resolve()`` reports the concrete level.
+    backend:   "jnp" (pure-XLA path) or "pallas" (fused kernels: the
+               whole-procedure megakernel shard-local, the stage-split
+               sharded-fused form under sharded plans; ``resolve()``
+               reports which — DESIGN.md §Procedure-fused).
     stream_dtype: dtype û streams HBM→VMEM at on the pallas backend —
                "fp32", "bf16" or "int8" (fp32 in-kernel accumulation in
                every case; bf16 halves the DMA bytes of the only large
                operand, int8 quarters them via per-L-tile symmetric
                quantization — DESIGN.md §Quantized-routing).  int8 is
-               procedure-megakernel-only: it forces the procedure form
-               under fusion="auto", rejects fusion="iteration", sharded
-               plans and ``differentiable=True`` (quantization rounding
-               has no derivative — train fp32/bf16, serve int8), and is
+               procedure-megakernel-only: it rejects sharded plans and
+               ``differentiable=True`` (quantization rounding has no
+               derivative — train fp32/bf16, serve int8), and is
                accuracy-gated by bench_accuracy, not the 1e-5 parity gate.
     early_exit_eps: per-capsule early exit inside the procedure megakernel
                (DESIGN.md §Quantized-routing): L-tiles whose deferred-Eq.4
@@ -90,9 +82,8 @@ class RouterSpec(NamedTuple):
                couplings frozen in VMEM scratch — effective work becomes
                proportional to unconverged capsules.  ε=0 is bit-identical
                to the fixed grid; None (default) disables the convergence
-               scratch entirely.  Same composition rules as int8: forces
-               the procedure form, rejects fusion="iteration", sharded
-               plans, and ``differentiable=True`` (the backward
+               scratch entirely.  Same composition rules as int8: rejects
+               sharded plans and ``differentiable=True`` (the backward
                megakernel walks the fixed-grid schedule).
     differentiable: the router will be differentiated (``jax.grad`` /
                ``jax.vjp`` through it — DESIGN.md §Training).  The jnp
@@ -104,8 +95,8 @@ class RouterSpec(NamedTuple):
                shard-local (the stage-split form has no VJP — auto plans
                resolve unsharded) and ``use_approx`` is rejected (the
                §5.2.2 bit manipulations have no derivative).  When the
-               procedure form does not fit VMEM the router falls back to
-               jnp autodiff rather than a forward-only kernel.
+               backward megakernel does not fit VMEM the router falls back
+               to jnp autodiff rather than a forward-only kernel.
     options:   algorithm-specific extras as a sorted (name, value) tuple,
                e.g. (("beta_a", 1.0),) for EM.  Use ``spec.option(name)``.
     """
@@ -114,7 +105,6 @@ class RouterSpec(NamedTuple):
     iterations: int = 3
     use_approx: bool = False
     options: Tuple[Tuple[str, Any], ...] = ()
-    fusion: str = "auto"
     stream_dtype: str = "fp32"
     differentiable: bool = False
     early_exit_eps: Optional[float] = None
@@ -134,11 +124,11 @@ class RouterSpec(NamedTuple):
 def reference_spec(spec: RouterSpec) -> RouterSpec:
     """The jnp reference twin of ``spec``: same algorithm, iterations and
     options, but the pure-XLA backend with every pallas-only knob reset
-    (fusion/stream_dtype/early_exit/approx).  This is the fallback target
+    (stream_dtype/early_exit/approx).  This is the fallback target
     shared by the differentiable pallas path (VMEM non-fit, DESIGN.md
     §Training) and the serving output guard's NaN/Inf quarantine
     (runtime.caps_serve, DESIGN.md §Faults)."""
-    return spec._replace(backend="jnp", fusion="auto", stream_dtype="fp32",
+    return spec._replace(backend="jnp", stream_dtype="fp32",
                          early_exit_eps=None, use_approx=False)
 
 
@@ -199,17 +189,12 @@ def registered_algorithms() -> Tuple[str, ...]:
 def _differentiable_kernel(spec: RouterSpec, shape, axes) -> bool:
     """Whether a differentiable pallas router runs the procedure
     megakernel's custom VJP (DESIGN.md §Training) for votes of ``shape``:
-    shard-local, procedure form, and — under fusion="auto" — a backward
-    working set that fits VMEM.  Otherwise it falls back to jnp autodiff
-    (the gradient reference), never to a forward-only kernel that would
-    fail under jax.grad."""
+    shard-local, with a backward working set that fits VMEM.  Otherwise it
+    falls back to jnp autodiff (the gradient reference), never to a
+    forward-only kernel that would fail under jax.grad."""
     from repro.kernels.routing import ops as routing_ops
-    form = routing_ops.resolve_fusion(spec.fusion, shape, spec.stream_dtype,
-                                      sharded=bool(axes))
-    return (form == "procedure" and not axes
-            and (spec.fusion == "procedure"
-                 or routing_ops.procedure_train_fits(
-                     *shape, spec.iterations, spec.stream_dtype)))
+    return not axes and routing_ops.procedure_train_fits(
+        *shape, spec.iterations, spec.stream_dtype)
 
 
 def _dynamic_run(args, spec: RouterSpec, axes: Mapping[str, str]):
@@ -227,8 +212,7 @@ def _dynamic_run(args, spec: RouterSpec, axes: Mapping[str, str]):
     if spec.backend == "pallas":
         from repro.kernels.routing import ops as routing_ops
         form = routing_ops.resolve_fusion(
-            spec.fusion, jnp.shape(u_hat), spec.stream_dtype,
-            sharded=bool(axes),
+            spec.stream_dtype, sharded=bool(axes),
             early_exit=spec.early_exit_eps is not None)
         if form == "stage_split":
             # sharded-fused: stage-split kernels + cross-shard psums at
@@ -236,17 +220,12 @@ def _dynamic_run(args, spec: RouterSpec, axes: Mapping[str, str]):
             return routing_ops.dynamic_routing_fused_sharded(
                 u_hat, axes=axes, iterations=spec.iterations,
                 use_approx=spec.use_approx, stream_dtype=spec.stream_dtype)
-        if form == "procedure":
-            # whole-procedure megakernel (DESIGN.md §Procedure-fused);
-            # int8 û streaming and early exit live only here
-            # (DESIGN.md §Quantized-routing)
-            return routing_ops.dynamic_routing_procedure_fused(
-                u_hat, iterations=spec.iterations,
-                use_approx=spec.use_approx, stream_dtype=spec.stream_dtype,
-                early_exit_eps=spec.early_exit_eps)
-        return routing_ops.dynamic_routing_fused(
-            u_hat, iterations=spec.iterations, use_approx=spec.use_approx,
-            stream_dtype=spec.stream_dtype)
+        # whole-procedure megakernel (DESIGN.md §Procedure-fused); int8 û
+        # streaming and early exit live only here (§Quantized-routing)
+        return routing_ops.dynamic_routing_procedure_fused(
+            u_hat, iterations=spec.iterations,
+            use_approx=spec.use_approx, stream_dtype=spec.stream_dtype,
+            early_exit_eps=spec.early_exit_eps)
     cfg = routing_lib.RoutingConfig(
         iterations=spec.iterations, use_approx=spec.use_approx,
         axes=tuple(sorted(axes.items())) or None)
@@ -511,9 +490,10 @@ class ResolvedPlan(tuple):
     tuple of concrete (dim, mesh_axis) pairs (len / indexing / iteration),
     plus the resolved kernel execution attributes:
 
-    fusion:       "procedure" | "iteration" | "stage_split" — the concrete
-                  kernel form a pallas-backend router will run (DESIGN.md
-                  §Procedure-fused); None for the jnp backend.
+    fusion:       "procedure" | "stage_split" — the concrete kernel form a
+                  pallas-backend router will run (DESIGN.md
+                  §Procedure-fused); None for the jnp backend and for a
+                  differentiable router that falls back to jnp autodiff.
     stream_dtype: "fp32" | "bf16" | "int8" û streaming dtype; None for jnp.
     differentiable: True iff execution runs the fused procedure kernel
                   through its custom VJP (DESIGN.md §Training)
@@ -525,8 +505,10 @@ class ResolvedPlan(tuple):
                   None when early exit is off or the backend is jnp.
     """
 
-    def __new__(cls, axes=(), fusion=None, stream_dtype=None,
-                differentiable=False, early_exit_eps=None):
+    _ATTRS = ("fusion", "stream_dtype", "differentiable", "early_exit_eps")
+
+    def __new__(cls, axes, fusion, stream_dtype, differentiable,
+                early_exit_eps):
         self = super().__new__(cls, tuple(axes))
         self.fusion = fusion
         self.stream_dtype = stream_dtype
@@ -535,10 +517,8 @@ class ResolvedPlan(tuple):
         return self
 
     def __repr__(self):
-        return (f"ResolvedPlan(axes={tuple(self)}, fusion={self.fusion!r}, "
-                f"stream_dtype={self.stream_dtype!r}, "
-                f"differentiable={self.differentiable!r}, "
-                f"early_exit_eps={self.early_exit_eps!r})")
+        attrs = "".join(f", {a}={getattr(self, a)!r}" for a in self._ATTRS)
+        return f"ResolvedPlan(axes={tuple(self)}{attrs})"
 
 
 class Router:
@@ -575,36 +555,28 @@ class Router:
 
     def _resolve_fusion(self, axes, shapes):
         """(fusion, stream_dtype, differentiable, early_exit_eps) the pallas
-        backend will execute with — the same ``resolve_fusion`` the run
-        path calls, so the report can never drift from execution.  jnp
-        backend: (None, None, False, None); a no-arg ``resolve()``
-        (historically legal for static plans) reports None for fusion when
-        the "auto" fit check would need the votes shape — except for the
-        deep-edge knobs (int8 / early exit), which resolve "procedure"
-        without a shape."""
+        backend will execute with — the same choices the run path makes,
+        so the report can never drift from execution.  jnp backend:
+        (None, None, False, None).  A no-arg ``resolve()`` (legal for
+        static plans) of a differentiable router reports None for fusion:
+        the backward's VMEM fit needs the votes shape."""
         if self.spec.backend != "pallas":
             return None, None, False, None
         if self.spec.algorithm != "dynamic":
             # EM: stage-split is the only form
             return "stage_split", "fp32", False, None
-        early_exit = self.spec.early_exit_eps is not None
-        deep_edge = self.spec.stream_dtype == "int8" or early_exit
-        if (not shapes and not axes and self.spec.fusion == "auto"
-                and not deep_edge):
-            return None, self.spec.stream_dtype, False, None
         from repro.kernels.routing import ops as routing_ops
         if self.spec.differentiable:
             # _dynamic_run's differentiable dispatch: the custom VJP, or
             # jnp autodiff (reported as the jnp 4-tuple)
-            if _differentiable_kernel(self.spec,
-                                      shapes[0] if shapes else None, axes):
+            if not shapes:
+                return None, self.spec.stream_dtype, False, None
+            if _differentiable_kernel(self.spec, shapes[0], axes):
                 return "procedure", self.spec.stream_dtype, True, None
             return None, None, False, None
-        form = routing_ops.resolve_fusion(self.spec.fusion,
-                                          shapes[0] if shapes else None,
-                                          self.spec.stream_dtype,
-                                          sharded=bool(axes),
-                                          early_exit=early_exit)
+        form = routing_ops.resolve_fusion(
+            self.spec.stream_dtype, sharded=bool(axes),
+            early_exit=self.spec.early_exit_eps is not None)
         return (form, self.spec.stream_dtype, False,
                 self.spec.early_exit_eps)
 
@@ -789,7 +761,6 @@ class Router:
     def __repr__(self):
         return (f"Router(algorithm={self.spec.algorithm!r}, "
                 f"backend={self.spec.backend!r}, "
-                f"fusion={self.spec.fusion!r}, "
                 f"stream_dtype={self.spec.stream_dtype!r}, "
                 f"differentiable={self.spec.differentiable!r}, "
                 f"early_exit_eps={self.spec.early_exit_eps!r}, "
@@ -798,9 +769,9 @@ class Router:
 
 
 def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
-    # fusion / stream-dtype vocabularies live in kernels/routing/vocab.py —
-    # a light module with no pallas import, so every build_router no longer
-    # drags the kernel package in just to spell-check two strings.
+    # the stream-dtype vocabulary lives in kernels/routing/vocab.py — a
+    # light module with no pallas import, so build_router does not drag the
+    # kernel package in just to spell-check a string.
     from repro.kernels.routing import vocab as routing_vocab
     if spec.backend not in BACKENDS:
         raise ValueError(f"unknown backend {spec.backend!r}; expected one "
@@ -810,29 +781,16 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
             f"algorithm {algo.name!r} has no {spec.backend!r} backend "
             f"(supported: {algo.backends}); register a kernel for it or "
             "use backend='jnp'")
-    if spec.fusion not in routing_vocab.FUSION_LEVELS:
-        raise ValueError(f"unknown fusion level {spec.fusion!r}; expected "
-                         f"one of {routing_vocab.FUSION_LEVELS}")
     if spec.stream_dtype not in routing_vocab.STREAM_DTYPES:
         raise ValueError(f"unknown stream_dtype {spec.stream_dtype!r}; "
                          f"expected one of "
                          f"{tuple(sorted(routing_vocab.STREAM_DTYPES))}")
     _pallas_dynamic = spec.backend == "pallas" and algo.name == "dynamic"
-    if spec.fusion != "auto" and not _pallas_dynamic:
-        raise ValueError(
-            f"fusion={spec.fusion!r} is a pallas-backend knob of the "
-            "'dynamic' algorithm (EM and the jnp backend have no fused "
-            "megakernel); leave fusion='auto'")
     if spec.stream_dtype != "fp32" and not _pallas_dynamic:
         raise ValueError(
             f"stream_dtype={spec.stream_dtype!r} requires the 'dynamic' "
             "algorithm on the pallas backend (the jnp path and the EM "
             "kernels stream fp32)")
-    if spec.fusion == "procedure" and plan.axes:
-        raise ValueError(
-            "fusion='procedure' is shard-local (the megakernel keeps b/v/s "
-            "in VMEM across iterations and cannot surface for the Table-2 "
-            "psums); use fusion='auto' or 'iteration' with sharded plans")
     # --- deep-edge tier (DESIGN.md §Quantized-routing): int8 û streaming
     # and early exit exist only in the forward procedure megakernel
     if spec.early_exit_eps is not None:
@@ -848,11 +806,6 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
                 "early_exit_eps is a pallas-backend knob of the 'dynamic' "
                 "algorithm (only the procedure megakernel tracks per-tile "
                 "convergence); leave early_exit_eps=None")
-        if spec.fusion == "iteration":
-            raise ValueError(
-                "early_exit_eps requires the procedure megakernel: "
-                "fusion='iteration' has no per-tile convergence scratch; "
-                "use fusion='auto' or 'procedure'")
         if plan.axes:
             raise ValueError(
                 "early-exit routing is shard-local: the per-tile "
@@ -872,11 +825,6 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
                 "'bf16': int8 û quantization rounds to the nearest code "
                 "(no derivative) and the backward megakernel has no "
                 "dequant path; train fp32/bf16, serve int8")
-        if spec.fusion == "iteration":
-            raise ValueError(
-                "stream_dtype='int8' requires the procedure megakernel "
-                "(per-tile scales and dequant are megakernel-only); use "
-                "fusion='auto' or 'procedure'")
         if plan.axes:
             raise ValueError(
                 "stream_dtype='int8' is shard-local: only the procedure "
@@ -898,11 +846,6 @@ def _validate(algo: Algorithm, spec: RouterSpec, plan: ExecutionPlan):
                 "bit-manipulation approximations have no derivative "
                 "(bitcast is not differentiable); train exact, serve "
                 "approx")
-        if spec.fusion == "iteration":
-            raise ValueError(
-                "fusion='iteration' has no custom VJP; the differentiable "
-                "fused form is the procedure megakernel — use "
-                "fusion='auto' or 'procedure' with differentiable=True")
         if plan.axes or plan.pipeline is not None:
             raise ValueError(
                 "differentiable pallas routing is shard-local: the "
@@ -976,19 +919,3 @@ def as_router(spec=None, plan=None, *, default_iterations: int = 3):
         return spec
     return build_router(spec, plan)
 
-
-# ---------------------------------------------------------------------------
-# Legacy bridge (deprecation shims in core.routing / core.em_routing)
-# ---------------------------------------------------------------------------
-
-def from_routing_config(cfg: routing_lib.RoutingConfig,
-                        mesh: Optional[jax.sharding.Mesh] = None) -> Router:
-    """RoutingConfig -> Router (deprecation bridge, DESIGN.md §Shims)."""
-    spec = RouterSpec(algorithm="dynamic",
-                      backend="pallas" if cfg.fused else "jnp",
-                      iterations=cfg.iterations, use_approx=cfg.use_approx)
-    axes = tuple(cfg.axes or ())
-    if not axes and cfg.sharded_dim is not None:
-        axes = ((cfg.sharded_dim, cfg.axis_name),)
-    plan = ExecutionPlan(mesh=mesh, axes=axes) if axes else None
-    return build_router(spec, plan)

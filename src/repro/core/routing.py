@@ -38,7 +38,8 @@ ShardedDim = Optional[Literal["B", "L", "H"]]
 
 
 class RoutingConfig(NamedTuple):
-    """Static routing configuration.
+    """Static configuration of the jnp reference path.  The Pallas kernels
+    are reached through ``repro.core.router.build_router`` only.
 
     iterations:   paper Table 1 "Iter" (3..9).
     use_approx:   paper §5.2.2 PE approximations for exp / rsqrt / div.
@@ -48,17 +49,11 @@ class RoutingConfig(NamedTuple):
                   {"B": axis, "L": axis, ...} shards several logical dims at
                   once (e.g. B over "data" x L over "model" on the 2D
                   torus); overrides sharded_dim/axis_name when set.
-    fused:        route via the Pallas kernels (kernels/routing); pure-jnp
-                  path otherwise.  Composes with sharded_dim/axes: the
-                  stage-split sharded-fused form inserts the cross-shard
-                  psums between per-shard Pallas stages (DESIGN.md
-                  §Sharded-fused).
     """
     iterations: int = 3
     use_approx: bool = False
     sharded_dim: ShardedDim = None
     axis_name: Optional[str] = None
-    fused: bool = False
     axes: Optional[tuple] = None    # tuple of (dim, axis_name) pairs
 
     def axis_of(self, dim: str) -> Optional[str]:
@@ -116,22 +111,6 @@ def dynamic_routing(u_hat: jax.Array, cfg: RoutingConfig = RoutingConfig()
     sequential dependency, §2.2 summary point (1)).  The final iteration's v
     is the routed H-capsule output.
     """
-    if cfg.fused:
-        from repro.kernels.routing import ops as routing_ops
-        axes = dict(cfg.axes or ())
-        if not axes and cfg.sharded_dim is not None:
-            axes = {cfg.sharded_dim: cfg.axis_name}
-        if axes:
-            # sharded-fused (DESIGN.md §Sharded-fused): stage-split kernels
-            # with the Table-2 psums inserted on the ambient mesh axes.
-            # (Historically this raised — the single-pass fused kernel
-            # cannot insert cross-shard psums.)
-            return routing_ops.dynamic_routing_fused_sharded(
-                u_hat, axes=axes, iterations=cfg.iterations,
-                use_approx=cfg.use_approx)
-        return routing_ops.dynamic_routing_fused(
-            u_hat, iterations=cfg.iterations, use_approx=cfg.use_approx)
-
     v, _ = _scan_routing(u_hat, cfg)
     return v
 
@@ -162,29 +141,3 @@ def dynamic_routing_with_stats(u_hat: jax.Array,
     v, b = _scan_routing(u_hat, cfg)
     return v, b, _softmax(b, cfg)
 
-
-def make_sharded_routing(mesh: jax.sharding.Mesh, dim: ShardedDim,
-                         axis_name: str, cfg: RoutingConfig):
-    """DEPRECATED shim — use ``repro.core.router.build_router`` instead.
-
-    Wraps dynamic_routing in shard_map with ``dim`` sharded over
-    ``axis_name``: the executable form of the paper's inter-vault
-    distribution.  Kept so pre-Router call sites keep working; delegates to
-    the unified Router API (DESIGN.md §Router, deprecation policy §Shims).
-    """
-    return make_multi_sharded_routing(mesh, ((dim, axis_name),), cfg)
-
-
-def make_multi_sharded_routing(mesh: jax.sharding.Mesh, axes, cfg):
-    """DEPRECATED shim — use ``repro.core.router.build_router`` instead.
-
-    Multi-dim generalization (e.g. B over "data" x L over "model" on the
-    pod's 2D torus).  axes: tuple of (dim, mesh_axis) pairs.
-    """
-    from repro.core import router as router_lib
-    spec = router_lib.RouterSpec(
-        algorithm="dynamic",
-        backend="pallas" if cfg.fused else "jnp",
-        iterations=cfg.iterations, use_approx=cfg.use_approx)
-    plan = router_lib.ExecutionPlan(mesh=mesh, axes=tuple(axes))
-    return router_lib.build_router(spec, plan)

@@ -1,24 +1,21 @@
-"""Fused dynamic-routing kernels (per-iteration and whole-procedure) as
-Pallas TPU kernels.
+"""Fused dynamic-routing kernels (whole-procedure forward and backward, and
+the stage-split stages) as Pallas TPU kernels.
 
 Paper hook (§5.2, DESIGN.md §2): the intra-vault PEs process the RP chain next
-to the data so intermediates never cross the off-chip boundary.  Two TPU-native
-forms live here:
-
-* ``routing_iteration_fused`` — one ``pallas_call`` per routing iteration that
-  streams the only large operand — the prediction vectors ``u_hat`` (B,L,H,C)
-  — HBM→VMEM exactly once, and keeps every intermediate (b-update, softmax,
-  weighted partial sums) VMEM-resident.  The (L,H) logits and (B,H,C) vote
-  sums still surface to HBM between iterations and squash runs outside.
-* ``routing_procedure_fused`` — ONE ``pallas_call`` for the *whole* procedure
-  (DESIGN.md §Procedure-fused): grid = (iterations, num_L_tiles) with the
-  logits ``b`` (L,H), the previous-iteration ``v`` and the vote-sum
-  accumulator ``s`` held in VMEM *scratch* across all grid steps; squash
-  (Eq.3) runs in-kernel at the last L-tile of each iteration.  Nothing but
-  the final v ever crosses back to HBM — the paper's "intermediates never
-  leave the vault" claim, whole-procedure.  û may be streamed in bf16
-  (``stream_dtype``) with fp32 in-kernel accumulation — halving the DMA
-  bytes of the memory-bound operand.
+to the data so intermediates never cross the off-chip boundary.  The
+TPU-native form of that is ``routing_procedure_fused`` — ONE ``pallas_call``
+for the *whole* procedure (DESIGN.md §Procedure-fused): grid = (iterations,
+num_L_tiles) with the logits ``b`` (L,H), the previous-iteration ``v`` and
+the vote-sum accumulator ``s`` held in VMEM *scratch* across all grid steps;
+squash (Eq.3) runs in-kernel at the last L-tile of each iteration.  It
+streams the only large operand — the prediction vectors ``u_hat``
+(B,L,H,C) — HBM→VMEM once per iteration, and nothing but the final v ever
+crosses back to HBM — the paper's "intermediates never leave the vault"
+claim, whole-procedure.  û may be streamed in bf16 (``stream_dtype``) with
+fp32 in-kernel accumulation — halving the DMA bytes of the memory-bound
+operand.  Under a sharded plan the stage-split kernels below run one
+(iteration, stage) at a time so the Table-2 psums can sit between them
+(DESIGN.md §Sharded-fused).
 
 Lane packing (every kernel here): û streams as (B, L, H·C) so the trailing
 dim fills the 128-lane vregs (C alone, 8..16, under-fills them), and it
@@ -30,8 +27,8 @@ every (B,H,C) vote-sized block is carried packed as (B, H·C).
 
 The naive formulation (ref.py / the paper's GPU baseline) materialises
 O(B·L·H·C) intermediates per iteration *twice* (c·û products and agreement
-tensors) and re-reads û twice; both fused forms read û once per iteration and
-write nothing bigger than (B,H,C)/(L,H).
+tensors) and re-reads û twice; the megakernel reads û once per iteration and
+writes nothing bigger than (B,H,C)/(L,H).
 
 Lazy-update schedule (proved equivalent in ref.py): when a tile of L rows is
 resident for iteration t we first fold in iteration t-1's agreement update for
@@ -143,8 +140,7 @@ def _caps_bcast(y, c_dim: int, dot=_dot):
 def squash_packed(s, h: int, c_dim: int, use_approx: bool):
     """Eq.3 squash of packed (..., H·C) vote sums, per capsule, mirroring
     approx.exact_squash / approx.approx_squash so backend parity holds for
-    both modes.  Plain jnp: the per-iteration path squashes with it on the
-    host, so every fused form shares one squash."""
+    both modes.  Every fused kernel squashes through it."""
     n2 = _caps_sum(s * s, h, c_dim)
     if use_approx:
         n2 = n2 + 1e-9
@@ -216,68 +212,6 @@ def _rows(j, l_tile: int):
     return pl.ds(pl.multiple_of(j * l_tile, l_tile), l_tile)
 
 
-def _routing_iter_kernel(u_ref, b_ref, v_ref, s_ref, b_out_ref, *,
-                         h: int, c_dim: int, use_approx: bool):
-    """One grid step = one L tile.
-
-    u_ref: (B, L_t, H·C) û tile           (streamed, read once)
-    b_ref: (L_t, H) routing logits tile   (read)
-    v_ref: (B, H·C) previous v            (small, replicated across steps)
-    s_ref: (B, H·C) output partial sums   (accumulated across grid steps)
-    b_out_ref: (L_t, H) updated logits    (written once per tile)
-    """
-    u = u_ref[...].astype(jnp.float32)
-    b_new = b_ref[...] + _agreement(u, v_ref[...], h, c_dim)
-    b_out_ref[...] = b_new
-    s_part = _votes(u, _softmax_h_inkernel(b_new, use_approx), c_dim)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        s_ref[...] = s_part
-
-    @pl.when(pl.program_id(0) != 0)
-    def _acc():
-        s_ref[...] += s_part
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("l_tile", "use_approx", "interpret"))
-def routing_iteration_fused(u_hat: jax.Array, b: jax.Array, v_prev: jax.Array,
-                            *, l_tile: int = 128, use_approx: bool = False,
-                            interpret: bool | None = None):
-    """One fused routing iteration.  Returns (s (B,H,C), b_new (L,H)).
-
-    l_tile sizes the VMEM working set — the double-buffered (B, l_tile,
-    H·C) û block plus its fp32 temporaries, with tile padding counted:
-    ops.py::auto_l_tile picks it against ``VMEM_LIMIT_BYTES``.
-    """
-    B, L, H, C = u_hat.shape
-    _check_tile(L, l_tile)
-    kernel = functools.partial(_routing_iter_kernel, h=H, c_dim=C,
-                               use_approx=use_approx)
-    s, b_new = pl.pallas_call(
-        kernel,
-        grid=(L // l_tile,),
-        in_specs=[
-            pl.BlockSpec((B, l_tile, H * C), lambda i: (0, i, 0)),
-            pl.BlockSpec((l_tile, H), lambda i: (i, 0)),
-            pl.BlockSpec((B, H * C), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((B, H * C), lambda i: (0, 0)),
-            pl.BlockSpec((l_tile, H), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H * C), jnp.float32),
-            jax.ShapeDtypeStruct((L, H), jnp.float32),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=resolve_interpret(interpret),
-    )(_pack(_as_stream(u_hat)), b.astype(jnp.float32),
-      _pack(v_prev.astype(jnp.float32)))
-    return s.reshape(B, H, C), b_new
-
-
 # ---------------------------------------------------------------------------
 # Whole-procedure megakernel (DESIGN.md §Procedure-fused)
 # ---------------------------------------------------------------------------
@@ -306,10 +240,10 @@ def _routing_procedure_kernel(*refs, h: int, c_dim: int, l_tile: int,
     c_scr:     (L, H) frozen couplings     [early-exit only]
     conv_scr:  (L/L_t,) SMEM int32 converged flags  [early-exit only]
 
-    Unlike the per-iteration kernel, b/v/s never cross back to HBM between
-    iterations and squash (Eq.3) runs in-kernel at the last L-tile of each
-    iteration — the only HBM write of the whole procedure is the final v
-    (plus the 4-byte work counter under early exit).
+    b/v/s never cross back to HBM between iterations and squash (Eq.3)
+    runs in-kernel at the last L-tile of each iteration — the only HBM
+    write of the whole procedure is the final v (plus the 4-byte work
+    counter under early exit).
 
     ``residuals`` (set only by the custom VJP's forward, DESIGN.md
     §Training) also emits c_t and the pre-squash s_t of every iteration:

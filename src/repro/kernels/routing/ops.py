@@ -1,6 +1,7 @@
 """Jitted public wrappers for the fused routing kernels.
 
-Three execution shapes (DESIGN.md §Procedure-fused, §Sharded-fused):
+Two execution shapes, one per regime (DESIGN.md §Procedure-fused,
+§Sharded-fused):
 
 * ``dynamic_routing_procedure_fused`` — the whole-procedure megakernel: ONE
   ``pallas_call`` with grid (iterations, L_tiles); b/v/s live in VMEM
@@ -10,10 +11,8 @@ Three execution shapes (DESIGN.md §Procedure-fused, §Sharded-fused):
   (per-L-tile symmetric scale, ``quantize_u_stream``) quarters them, and
   ``early_exit_eps`` skips converged L-tiles' Eq.4/Eq.5 work
   (``dynamic_routing_procedure_stats`` reports the effective work —
-  DESIGN.md §Quantized-routing).  Shard-local only.
-* ``dynamic_routing_fused`` — the single-pass per-iteration kernel; every
-  Table-2 aggregation is shard-local, so it only runs unsharded.  Kept as
-  the fallback when the procedure kernel's VMEM working set does not fit.
+  DESIGN.md §Quantized-routing).  Shard-local only: it runs at whatever L
+  tile :func:`procedure_l_tile` leaves room for.
 * ``dynamic_routing_fused_sharded`` / ``em_routing_fused`` — the stage-split
   form: per-shard Pallas stages compute the heavy O(B·L·H·C) passes, and
   this module inserts the cross-shard ``lax.psum`` between them at exactly
@@ -25,9 +24,9 @@ Three execution shapes (DESIGN.md §Procedure-fused, §Sharded-fused):
   kernel (``routing_stage_update_fold``) — the iteration-resident treatment
   extended to the distributed path.
 
-``resolve_fusion`` is the single source of truth for the Router's
-``fusion="auto"`` knob: procedure-fusion when the plan is shard-local and
-``procedure_vmem_bytes`` fits the budget, per-iteration fusion otherwise.
+``resolve_fusion`` is the single source of truth for which form the Router
+runs: the megakernel when the plan is shard-local, the stage-split form
+under a sharded plan.
 """
 from __future__ import annotations
 
@@ -42,16 +41,15 @@ from repro.core import em_routing as em_lib
 from repro.core import routing as routing_lib
 from repro.kernels.routing.kernel import (VMEM_LIMIT_BYTES, em_stage_estep,
                                           em_stage_stats,
-                                          routing_iteration_fused,
                                           routing_procedure_bwd,
                                           routing_procedure_fused,
                                           routing_stage_update,
                                           routing_stage_update_fold,
-                                          routing_stage_votes, squash_packed)
+                                          routing_stage_votes)
 # spec-level vocabulary lives in vocab.py (importable without pallas —
 # core.router._validate uses it); re-exported here for kernel code and
 # historical callers.
-from repro.kernels.routing.vocab import (FUSION_LEVELS, STREAM_DTYPES,
+from repro.kernels.routing.vocab import (STREAM_DTYPES,
                                          stream_itemsize as _stream_itemsize)
 
 # VMEM model (DESIGN.md §Procedure-fused).  Every routing kernel runs under
@@ -75,7 +73,7 @@ _VOTE_TEMPS = 16
 _FWD_TEMPS = 1.25          # procedure megakernel
 _EARLY_EXIT_TEMPS = 1.25   # + the early-exit branch's live values
 _BWD_TEMPS = 0.75          # backward megakernel (0.52 at most, 18 shapes)
-_STAGE_TEMPS = 1.25        # per-iteration and stage-split kernels
+_STAGE_TEMPS = 1.25        # stage-split kernels
 _EM_TEMPS = 3.0            # EM M-step / E-step kernels
 
 
@@ -111,8 +109,8 @@ def pick_l_tile(L: int, bytes_budget: int, row_bytes: int,
     Legal tiles are the divisors of L that are multiples of ``align`` (the
     stream dtype's sublane tiling, :func:`sublane_tile`) and L itself — a
     block spanning the whole dim is always legal.  When no legal tile fits,
-    the smallest legal one is returned (the caller's fit check then
-    decides).  Divisors are enumerated in O(√L)."""
+    the smallest legal one is returned.  Divisors are enumerated in
+    O(√L)."""
     cap = max(1, bytes_budget // max(row_bytes, 1))
     lim = min(preferred, cap)
     legal = set()
@@ -126,7 +124,7 @@ def pick_l_tile(L: int, bytes_budget: int, row_bytes: int,
 
 
 def auto_l_tile(B: int, L: int, H: int, C: int, stream_dtype: str) -> int:
-    """The l_tile the per-iteration / stage-split wrappers auto-pick —
+    """The l_tile the stage-split wrappers auto-pick —
     public so benchmarks can record the exact provenance they ran with.
     Per L row: the double-buffered û block, its fp32 temporaries and up to
     three double-buffered (L_t, H) logit/coupling blocks."""
@@ -239,73 +237,37 @@ def procedure_train_fits(B: int, L: int, H: int, C: int,
             <= PROCEDURE_VMEM_BUDGET)
 
 
-def resolve_fusion(fusion: str, shape, stream_dtype: str = "fp32",
-                   sharded: bool = False, early_exit: bool = False) -> str:
-    """Resolve a RouterSpec ``fusion`` knob to the concrete kernel form.
-
-    Returns "procedure" | "iteration" for shard-local execution and
-    "stage_split" under a sharded plan (where the per-iteration stage-split
-    kernels are the only legal form — the megakernel cannot surface for the
-    Table-2 psums).  ``fusion="auto"`` picks procedure-fusion whenever the
-    plan is shard-local and ``procedure_vmem_bytes`` at the
-    budget-shrunk ``procedure_l_tile`` fits; ``shape`` is only consulted on
-    that branch.
+def resolve_fusion(stream_dtype: str = "fp32", sharded: bool = False,
+                   early_exit: bool = False) -> str:
+    """The kernel form a pallas dynamic router runs: "procedure" (the
+    megakernel, at :func:`procedure_l_tile`) for shard-local execution and
+    "stage_split" under a sharded plan — the megakernel keeps b/v/s in
+    VMEM and cannot surface for the Table-2 psums.
 
     The deep-edge knobs (DESIGN.md §Quantized-routing) are
     procedure-megakernel-only: int8 dequant and the per-tile convergence
     scratch exist nowhere else, so ``stream_dtype="int8"`` or
-    ``early_exit=True`` resolve "auto" to "procedure" unconditionally —
-    a VMEM-overflow shape runs with a budget-shrunk (worst case 1-row)
-    tile rather than falling back — and raise under a sharded plan or an
-    explicit ``fusion="iteration"``.
+    ``early_exit=True`` raise under a sharded plan.
     """
-    if fusion not in FUSION_LEVELS:
-        raise ValueError(f"unknown fusion level {fusion!r}; expected one of "
-                         f"{FUSION_LEVELS}")
-    deep_edge = stream_dtype == "int8" or early_exit
-    if sharded:
-        if fusion == "procedure":
-            raise ValueError(
-                "fusion='procedure' is shard-local (the megakernel keeps "
-                "b/v/s in VMEM and cannot surface for the Table-2 psums); "
-                "use fusion='auto' or 'iteration' with sharded plans")
-        if stream_dtype == "int8":
-            raise ValueError(
-                "stream_dtype='int8' is shard-local: only the procedure "
-                "megakernel has a dequant path, and it cannot surface for "
-                "the Table-2 psums; use an unsharded plan (plan=None or "
-                "'auto')")
-        if early_exit:
-            raise ValueError(
-                "early-exit routing is shard-local: the per-tile "
-                "convergence scratch lives in the procedure megakernel, "
-                "which cannot surface for the Table-2 psums; use an "
-                "unsharded plan (plan=None or 'auto')")
-        return "stage_split"
-    if fusion != "auto":
-        if fusion == "iteration" and deep_edge:
-            knob = ("stream_dtype='int8'" if stream_dtype == "int8"
-                    else "early_exit_eps")
-            raise ValueError(
-                f"{knob} requires the procedure megakernel; "
-                "fusion='iteration' has no "
-                + ("dequant path" if stream_dtype == "int8"
-                   else "per-tile convergence scratch")
-                + " — use fusion='auto' or 'procedure'")
-        return fusion
-    if deep_edge:
+    if not sharded:
         return "procedure"
-    if shape is None:
-        raise ValueError("fusion='auto' needs the votes shape to resolve")
-    B, L, H, C = shape
-    l_tile = procedure_l_tile(B, L, H, C, stream_dtype)
-    fits = (procedure_vmem_bytes(B, L, H, C, l_tile, stream_dtype)
-            <= PROCEDURE_VMEM_BUDGET)
-    return "procedure" if fits else "iteration"
+    if stream_dtype == "int8":
+        raise ValueError(
+            "stream_dtype='int8' is shard-local: only the procedure "
+            "megakernel has a dequant path, and it cannot surface for "
+            "the Table-2 psums; use an unsharded plan (plan=None or "
+            "'auto')")
+    if early_exit:
+        raise ValueError(
+            "early-exit routing is shard-local: the per-tile "
+            "convergence scratch lives in the procedure megakernel, "
+            "which cannot surface for the Table-2 psums; use an "
+            "unsharded plan (plan=None or 'auto')")
+    return "stage_split"
 
 
 def dma_bytes_per_call(B: int, L: int, H: int, C: int,
-                       iterations: int = 3, *, form: str = "iteration",
+                       iterations: int = 3, *, form: str = "procedure",
                        stream_dtype: str = "fp32",
                        fold: bool = False,
                        backward: bool = False,
@@ -313,15 +275,10 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
     """HBM<->VMEM traffic per routing call, derived from the BlockSpecs of
     each kernel form (kernel.py):
 
-    * ``iteration`` — per iteration the grid streams the û tile set once
-      (B·L·H·C at the stream itemsize), reads+writes the (L,H) logits and
-      the (B,H,C) v/s blocks, and the host squash round-trips (B,H,C) twice
-      more: roundtrip = iterations · (2·LH + 4·BHC) · 4.
-    * ``procedure`` — û still streams once per iteration (it does not fit
-      VMEM), but b/v/s stay in scratch across ALL iterations and squash is
+    * ``procedure`` — û streams once per iteration (it does not fit VMEM),
+      but b/v/s stay in scratch across ALL iterations and squash is
       in-kernel, so the only non-stream traffic is the single final v
-      write: roundtrip = BHC · 4.  This is exactly the (L,H)/(B,H,C)
-      round-trip traffic the megakernel eliminates.
+      write: roundtrip = BHC · 4.
     * ``stage_split`` — û crosses twice per iteration (once per stage; the
       price of distribution) and the inter-stage tensors cross at each
       host/psum boundary: c and db written+read (4·LH), b read+written
@@ -352,8 +309,8 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
     on the pod dry-run (EXPERIMENTS.md §Perf routing cell).
 
     ``backward=True`` models the backward megakernel (DESIGN.md
-    §Training) — defined for ``form="procedure"`` only (the other forms
-    have no custom VJP).  û streams T − 1 times (one reverse row per
+    §Training) — defined for ``form="procedure"`` only (the stage-split
+    form has no custom VJP).  û streams T − 1 times (one reverse row per
     iteration; the t = 0 row reads none), a û-sized ∂û is written once at
     the stream dtype (``du_stream_bytes``: its output block stays put
     until the final row) and the only other traffic is reading, once
@@ -405,10 +362,7 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
             "naive_bytes": iterations * (2 * u_f32 + 2 * u_f32
                                          + 2 * (2 * bh + 2 * vhc)),
         }
-    if form == "iteration":
-        u_stream = iterations * u
-        roundtrip = iterations * (2 * bh + 4 * vhc)
-    elif form == "procedure":
+    if form == "procedure":
         u_stream = iterations * u
         if early_exit_work_fraction is not None:
             u_stream = int(round(u_stream * early_exit_work_fraction))
@@ -417,8 +371,8 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
         u_stream = iterations * 2 * u
         roundtrip = iterations * ((4 if fold else 6) * bh + 3 * vhc)
     else:
-        raise ValueError(f"unknown form {form!r}; expected 'iteration', "
-                         "'procedure' or 'stage_split'")
+        raise ValueError(f"unknown form {form!r}; expected 'procedure' or "
+                         "'stage_split'")
     if fold and form != "stage_split":
         raise ValueError("fold=True models the softmax-folded STAGE 2 of "
                          f"the stage_split form only; got form={form!r}")
@@ -435,35 +389,6 @@ def dma_bytes_per_call(B: int, L: int, H: int, C: int,
         "naive_bytes": iterations * (2 * u_f32 + 2 * bh + 4 * vhc
                                      + 2 * B * L * H * f),
     }
-
-
-@functools.partial(jax.jit, static_argnames=("iterations", "use_approx",
-                                             "l_tile", "stream_dtype",
-                                             "interpret"))
-def dynamic_routing_fused(u_hat: jax.Array, *, iterations: int = 3,
-                          use_approx: bool = False, l_tile: int | None = None,
-                          stream_dtype: str = "fp32",
-                          interpret: bool | None = None) -> jax.Array:
-    """Full routing procedure built from the fused per-iteration kernel.
-
-    u_hat: (B, L, H, C) -> v: (B, H, C).  û crosses HBM→VMEM once per
-    iteration (at the stream dtype; accumulation is fp32); squash (Eq.3,
-    O(B·H·C)) runs outside the kernel, through the megakernel's own
-    ``squash_packed``.
-    """
-    u_hat = u_hat.astype(STREAM_DTYPES[stream_dtype])
-    B, L, H, C = u_hat.shape
-    if l_tile is None:
-        l_tile = auto_l_tile(B, L, H, C, stream_dtype)
-    b = jnp.zeros((L, H), jnp.float32)
-    v = jnp.zeros((B, H, C), jnp.float32)
-    for _ in range(iterations):
-        s, b = routing_iteration_fused(u_hat, b, v, l_tile=l_tile,
-                                       use_approx=use_approx,
-                                       interpret=interpret)
-        v = squash_packed(s.reshape(B, H * C), H, C,
-                          use_approx).reshape(B, H, C)
-    return v
 
 
 @functools.partial(jax.jit, static_argnames=("l_tile",))

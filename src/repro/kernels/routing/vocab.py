@@ -1,8 +1,8 @@
 """Routing-kernel vocabulary — the spec-level constants, importable light.
 
-``core.router._validate`` needs only the legal ``fusion`` / ``stream_dtype``
-vocabularies to reject a bad ``RouterSpec`` at construction; importing them
-from ``ops.py`` dragged the whole Pallas kernel package (kernel.py →
+``core.router._validate`` needs only the legal ``stream_dtype`` vocabulary
+to reject a bad ``RouterSpec`` at construction; importing it from
+``ops.py`` dragged the whole Pallas kernel package (kernel.py →
 ``jax.experimental.pallas``) into every ``build_router`` call (ROADMAP
 item 5 nit).  This module holds the vocabulary with no kernel imports —
 ``ops.py`` re-exports it, so kernel code and historical callers see the
@@ -18,10 +18,6 @@ import jax.numpy as jnp
 # tier, DESIGN.md §Quantized-routing).  int8 is procedure-megakernel-only
 # and inference-only; ops.resolve_fusion / router._validate enforce both.
 STREAM_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
-
-# RouterSpec.fusion vocabulary (DESIGN.md §Procedure-fused): "auto" resolves
-# to the megakernel when the plan is shard-local and the VMEM model fits.
-FUSION_LEVELS = ("auto", "iteration", "procedure")
 
 
 def stream_itemsize(stream_dtype: str) -> int:

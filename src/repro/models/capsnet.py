@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from repro.configs.caps_benchmarks import CapsConfig
 from repro.core import capsule_layers as CL
 from repro.core import router as router_lib
-from repro.core import routing as routing_lib
 
 
 def init_capsnet(key, cfg: CapsConfig) -> dict:
@@ -66,30 +65,27 @@ def encode_votes(params, images: jax.Array, cfg: CapsConfig) -> jax.Array:
     return CL.predict_votes(params["digit"], u)
 
 
-def forward(params, images: jax.Array, cfg: CapsConfig,
-            routing_cfg: Optional[routing_lib.RoutingConfig] = None,
-            labels: Optional[jax.Array] = None,
-            router=None) -> Dict[str, jax.Array]:
+def forward(params, images: jax.Array, cfg: CapsConfig, router=None,
+            labels: Optional[jax.Array] = None) -> Dict[str, jax.Array]:
     """Full inference: returns {v, class_probs, reconstruction}.
 
-    ``router`` (preferred): a built ``core.router.Router`` / callable or a
-    ``RouterSpec`` — the unified Router API.  ``routing_cfg`` (legacy): a
-    ``RoutingConfig``; still honoured for pre-Router call sites.
+    ``router``: what ``caps_layer_forward`` takes — a built Router or any
+    callable, a ``RouterSpec``, or a ``RoutingConfig`` (the jnp reference,
+    which also runs inside a caller's own shard_map); default the
+    unsharded jnp RouterSpec at ``cfg.routing_iters``.
     """
-    route = router if router is not None else routing_cfg
-    if route is None:
-        route = router_lib.RouterSpec(iterations=cfg.routing_iters)
+    if router is None:
+        router = router_lib.RouterSpec(iterations=cfg.routing_iters)
     u = primary_caps(params, images, cfg)
-    v = CL.caps_layer_forward(params["digit"], u, route)    # (B, H, C_H)
+    v = CL.caps_layer_forward(params["digit"], u, router)   # (B, H, C_H)
     probs = jnp.linalg.norm(v, axis=-1)
     recon = CL.decoder_forward(params["decoder"], v, labels)
     return {"v": v, "class_probs": probs, "reconstruction": recon}
 
 
 def loss_fn(params, images: jax.Array, labels: jax.Array, cfg: CapsConfig,
-            routing_cfg: Optional[routing_lib.RoutingConfig] = None,
-            recon_weight: float = 0.0005, router=None):
-    out = forward(params, images, cfg, routing_cfg, labels, router=router)
+            router=None, recon_weight: float = 0.0005):
+    out = forward(params, images, cfg, router, labels)
     margin = CL.margin_loss(out["v"], labels, cfg.num_h_caps)
     flat = images.reshape(images.shape[0], -1)
     recon = jnp.mean(jnp.square(out["reconstruction"] - flat))

@@ -9,6 +9,7 @@ import pytest
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS, smoke_caps
 from repro.core import capsule_layers as CL
 from repro.core import pipeline, routing
+from repro.core.router import RouterSpec
 from repro.data.synthetic import SyntheticCapsDataset
 from repro.models import capsnet
 
@@ -68,8 +69,8 @@ def test_table5_accuracy_delta(trained):
         ("exact", routing.RoutingConfig(iterations=cfg.routing_iters)),
         ("approx", routing.RoutingConfig(iterations=cfg.routing_iters,
                                          use_approx=True)),
-        ("fused", routing.RoutingConfig(iterations=cfg.routing_iters,
-                                        fused=True)),
+        ("fused", RouterSpec(backend="pallas",
+                             iterations=cfg.routing_iters)),
     ]:
         hits = n = 0
         for i in range(200, 204):

@@ -22,7 +22,6 @@ from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.routing import ops as rt_ops
 from repro.kernels.routing import ref as rt_ref
-from repro.kernels.routing.kernel import routing_iteration_fused
 from repro.kernels.ssm_scan import ops as ssm_ops
 from repro.kernels.ssm_scan import ref as ssm_ref
 
@@ -42,24 +41,13 @@ ROUTING_SHAPES = [
 @pytest.mark.parametrize("shape", ROUTING_SHAPES)
 @pytest.mark.parametrize("l_tile", [32, 64])
 def test_routing_iteration_vs_ref(key, shape, l_tile):
-    B, L, H, C = shape
+    """The megakernel over several L tiles per iteration (L / l_tile from
+    1 to 8) == the jnp oracle built from ``routing_iteration_ref``."""
     u_hat = jax.random.normal(key, shape)
-    b = jax.random.normal(jax.random.fold_in(key, 1), (L, H))
-    v_prev = jax.random.normal(jax.random.fold_in(key, 2), (B, H, C))
-    s_k, b_k = routing_iteration_fused(u_hat, b, v_prev, l_tile=l_tile)
-    s_r, b_r = rt_ref.routing_iteration_ref(u_hat, b, v_prev)
-    np.testing.assert_allclose(s_k, s_r, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(b_k, b_r, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("iters", [1, 3])
-@pytest.mark.parametrize("use_approx", [False, True])
-def test_routing_full_vs_ref(key, iters, use_approx):
-    u_hat = jax.random.normal(key, (4, 128, 10, 16))
-    v_k = rt_ops.dynamic_routing_fused(u_hat, iterations=iters,
-                                       use_approx=use_approx)
-    v_r = rt_ref.dynamic_routing_ref(u_hat, iters, use_approx)
-    np.testing.assert_allclose(v_k, v_r, rtol=1e-4, atol=1e-5)
+    v_k = rt_ops.dynamic_routing_procedure_fused(u_hat, iterations=3,
+                                                 l_tile=l_tile)
+    v_r = rt_ref.dynamic_routing_ref(u_hat, 3)
+    np.testing.assert_allclose(v_k, v_r, rtol=1e-5, atol=1e-5)
 
 
 def test_routing_fused_matches_core(key):
@@ -68,7 +56,7 @@ def test_routing_fused_matches_core(key):
     u_hat = jax.random.normal(key, (2, 128, 10, 16))
     v_core = core_routing.dynamic_routing(
         u_hat, core_routing.RoutingConfig(iterations=3))
-    v_fused = rt_ops.dynamic_routing_fused(u_hat, iterations=3)
+    v_fused = rt_ops.dynamic_routing_procedure_fused(u_hat, iterations=3)
     np.testing.assert_allclose(v_core, v_fused, rtol=1e-5, atol=1e-6)
 
 
@@ -79,12 +67,10 @@ def test_property_routing_kernel(b, lt, nl, h, c):
     L = lt * nl
     key = jax.random.PRNGKey(b * 7 + L)
     u_hat = jax.random.normal(key, (b, L, h, c))
-    bmat = jnp.zeros((L, h))
-    v0 = jnp.zeros((b, h, c))
-    s_k, b_k = routing_iteration_fused(u_hat, bmat, v0, l_tile=lt)
-    s_r, b_r = rt_ref.routing_iteration_ref(u_hat, bmat, v0)
-    np.testing.assert_allclose(s_k, s_r, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(b_k, b_r, rtol=1e-5, atol=1e-5)
+    v_k = rt_ops.dynamic_routing_procedure_fused(u_hat, iterations=2,
+                                                 l_tile=lt)
+    v_r = rt_ref.dynamic_routing_ref(u_hat, 2)
+    np.testing.assert_allclose(v_k, v_r, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -122,15 +108,6 @@ def test_routing_procedure_fused_vs_jnp(key, iters, use_approx,
         np.testing.assert_allclose(
             v_k, rt_ref.dynamic_routing_ref(u_hat, iters, use_approx),
             rtol=5e-2, atol=5e-2)
-
-
-def test_routing_procedure_matches_iteration_fused(key):
-    """Megakernel == the per-iteration kernel loop (same lazy schedule,
-    same tile order) to float tolerance."""
-    u_hat = jax.random.normal(key, (4, 128, 10, 16))
-    v_p = rt_ops.dynamic_routing_procedure_fused(u_hat, iterations=3)
-    v_i = rt_ops.dynamic_routing_fused(u_hat, iterations=3)
-    np.testing.assert_allclose(v_p, v_i, rtol=1e-6, atol=1e-6)
 
 
 def test_routing_procedure_non_divisible_l_fallback(key):
@@ -195,49 +172,32 @@ def test_l_tiles_keep_sublane_tiling_table1(network):
 
 
 def test_resolve_fusion_levels():
-    """fusion='auto' picks the megakernel iff shard-local + VMEM fit."""
-    small = (2, 64, 6, 8)
-    assert rt_ops.resolve_fusion("auto", small) == "procedure"
-    assert rt_ops.resolve_fusion("iteration", small) == "iteration"
-    assert rt_ops.resolve_fusion("auto", small, sharded=True) == \
-        "stage_split"
-    # (B,H,C) blocks alone blow the budget -> per-iteration fallback
-    big = (512, 1024, 32, 128)
-    assert rt_ops.procedure_vmem_bytes(*big[:4], l_tile=1) \
-        > rt_ops.PROCEDURE_VMEM_BUDGET
-    assert rt_ops.resolve_fusion("auto", big) == "iteration"
-    with pytest.raises(ValueError, match="shard-local"):
-        rt_ops.resolve_fusion("procedure", small, sharded=True)
-    with pytest.raises(ValueError, match="unknown fusion"):
-        rt_ops.resolve_fusion("mega", small)
+    """Shard-local execution runs the megakernel, sharded execution the
+    stage-split form — no shape, no option."""
+    assert rt_ops.resolve_fusion() == "procedure"
+    assert rt_ops.resolve_fusion("bf16") == "procedure"
+    assert rt_ops.resolve_fusion(sharded=True) == "stage_split"
+    assert rt_ops.resolve_fusion("bf16", sharded=True) == "stage_split"
 
 
 def test_resolve_fusion_capbound_shrinks_tile():
-    """A cap-bound (large B·H·C row) shape must shrink the megakernel's
-    l_tile to fit the total budget, not fall back to the per-iteration
-    kernel (regression: the 8MB-per-buffer pick structurally overflowed
-    2x into the 14MB budget whenever the cap bound bit).  L = 8192 makes
-    the megakernel's resident (L, H) logits — 4 MiB once padded to 128
-    lanes — cost it rows the per-iteration kernel keeps."""
+    """A cap-bound (large B·H·C row) shape shrinks the megakernel's l_tile
+    to fit the total budget (regression: the 8MB-per-buffer pick
+    structurally overflowed 2x into the 14MB budget whenever the cap bound
+    bit).  L = 8192 makes the megakernel's resident (L, H) logits — 4 MiB
+    once padded to 128 lanes — cost it rows the stage-split kernels keep."""
     capbound = (128, 8192, 16, 16)         # row = 128*16*16*4 = 128 KiB
     lt = rt_ops.procedure_l_tile(*capbound)
     assert lt < rt_ops.auto_l_tile(*capbound, "fp32")
     assert rt_ops.procedure_vmem_bytes(*capbound, l_tile=lt) \
         <= rt_ops.PROCEDURE_VMEM_BUDGET
-    assert rt_ops.resolve_fusion("auto", capbound) == "procedure"
 
 
 def test_fused_paths_stream_bf16_without_promotion(key):
     """The modeled DMA halving is real only if the pallas_call consumes the
     bf16 operand itself: no full-size fp32 copy of û may appear in the
-    jaxpr of either fused path (regression: the iteration wrapper used to
-    astype(f32) right before the call)."""
-    from repro.kernels.routing.kernel import routing_iteration_fused
-    u = jax.random.normal(key, (2, 64, 6, 8)).astype(jnp.bfloat16)
-    b0, v0 = jnp.zeros((64, 6)), jnp.zeros((2, 6, 8))
-    it_jaxpr = str(jax.make_jaxpr(functools.partial(
-        routing_iteration_fused, l_tile=32))(u, b0, v0))
-    assert "f32[2,64,6,8]" not in it_jaxpr
+    jaxpr of either fused form (regression: a wrapper used to astype(f32)
+    right before the call)."""
     # L=256 > l_tile=128 so an in-kernel fp32 *block* (legitimate) can't
     # alias the full-array shape the assertion hunts for
     u_l = jax.random.normal(key, (2, 256, 6, 8)).astype(jnp.bfloat16)
@@ -246,31 +206,35 @@ def test_fused_paths_stream_bf16_without_promotion(key):
         stream_dtype="bf16"))(u_l))
     assert "f32[2,256,6,8]" not in proc_jaxpr
     assert "f32[2,256,48]" not in proc_jaxpr    # lane-packed full copy
-    # and the bf16 iteration path matches the oracle on the rounded û
-    v = rt_ops.dynamic_routing_fused(u, iterations=3, stream_dtype="bf16")
+    u = jax.random.normal(key, (2, 64, 6, 8)).astype(jnp.bfloat16)
+    stage = functools.partial(rt_ops.dynamic_routing_fused_sharded,
+                              axes={}, iterations=3, l_tile=32,
+                              stream_dtype="bf16")
+    assert "f32[2,64,6,8]" not in str(jax.make_jaxpr(stage)(u))
+    # and the bf16 stage-split path matches the oracle on the rounded û
     pre = u.astype(jnp.float32)
-    np.testing.assert_allclose(v, rt_ref.dynamic_routing_ref(pre, 3),
+    np.testing.assert_allclose(stage(u), rt_ref.dynamic_routing_ref(pre, 3),
                                rtol=1e-4, atol=5e-5)
 
 
 def test_dma_model_three_forms():
-    """The DMA model's acceptance invariants: procedure-fusion eliminates
-    the per-iteration (L,H)/(B,H,C) round-trips, bf16 halves û stream
-    bytes, stage-split pays the distribution double-stream."""
+    """The DMA model's acceptance invariants over its two forms: the
+    megakernel's only non-stream traffic is the final v, bf16 halves û
+    stream bytes, stage-split pays the distribution double-stream."""
     B, L, H, C, iters = 4, 128, 10, 16, 3
-    it = rt_ops.dma_bytes_per_call(B, L, H, C, iters, form="iteration")
-    pr = rt_ops.dma_bytes_per_call(B, L, H, C, iters, form="procedure")
+    pr = rt_ops.dma_bytes_per_call(B, L, H, C, iters)
+    assert pr["form"] == "procedure"
     ss = rt_ops.dma_bytes_per_call(B, L, H, C, iters, form="stage_split")
     bf = rt_ops.dma_bytes_per_call(B, L, H, C, iters, form="procedure",
                                    stream_dtype="bf16")
     assert pr["roundtrip_bytes"] == B * H * C * 4
-    assert it["roundtrip_bytes"] == iters * (2 * L * H + 4 * B * H * C) * 4
-    assert pr["total_bytes"] < it["total_bytes"] < ss["total_bytes"]
+    assert ss["roundtrip_bytes"] == iters * (6 * L * H + 3 * B * H * C) * 4
+    assert pr["total_bytes"] < ss["total_bytes"]
     assert bf["u_hat_stream_bytes"] * 2 == pr["u_hat_stream_bytes"]
     assert bf["roundtrip_bytes"] == pr["roundtrip_bytes"]  # fp32 roundtrip
-    assert ss["u_hat_stream_bytes"] == 2 * it["u_hat_stream_bytes"]
+    assert ss["u_hat_stream_bytes"] == 2 * pr["u_hat_stream_bytes"]
     with pytest.raises(ValueError, match="unknown form"):
-        rt_ops.dma_bytes_per_call(B, L, H, C, form="fused")
+        rt_ops.dma_bytes_per_call(B, L, H, C, form="iteration")
 
 
 def test_dma_model_stage_split_fold():
@@ -335,20 +299,21 @@ def test_property_early_exit_eps0_bit_identical(b, lt, nl, iters,
         np.abs(np.asarray(v0) - np.asarray(base)).max())
 
 
-@pytest.mark.parametrize("fusion", ["auto", "procedure"])
+@pytest.mark.parametrize("plan", [None, "auto"])
 @pytest.mark.parametrize("stream_dtype", ["fp32", "bf16", "int8"])
-def test_early_exit_eps0_bit_identical_through_router(key, fusion,
+def test_early_exit_eps0_bit_identical_through_router(key, plan,
                                                       stream_dtype):
-    """Same bit-identity through the Router across fusion x stream_dtype
-    (both fusion levels that can reach the megakernel; the shape is small
-    enough that the early-exit VMEM model picks the same l_tile, which the
-    resolved plans pin down)."""
+    """Same bit-identity through the Router across plan x stream_dtype
+    (an early-exit router resolves shard-local to the megakernel under
+    either plan; the shape is small enough that the early-exit VMEM model
+    picks the same l_tile, which the resolved plans pin down)."""
     from repro.core.router import RouterSpec, build_router
     u_hat = jax.random.normal(key, (2, 96, 6, 8))
     spec = RouterSpec(algorithm="dynamic", backend="pallas",
-                      fusion=fusion, stream_dtype=stream_dtype)
+                      stream_dtype=stream_dtype)
     base = build_router(spec)
-    ee = build_router(spec._replace(early_exit_eps=0.0))
+    ee = build_router(spec._replace(early_exit_eps=0.0), plan)
+    assert tuple(ee.resolve(u_hat)) == ()
     assert base.resolve(u_hat).fusion == ee.resolve(u_hat).fusion \
         == "procedure"
     assert np.array_equal(np.asarray(base(u_hat)), np.asarray(ee(u_hat)))
@@ -428,10 +393,10 @@ def test_dma_model_int8_and_early_exit():
                                      early_exit_work_fraction=0.5)
     assert both["u_hat_stream_bytes"] * 8 == pr["u_hat_stream_bytes"]
     with pytest.raises(ValueError, match="procedure-megakernel tier"):
-        rt_ops.dma_bytes_per_call(B, L, H, C, form="iteration",
+        rt_ops.dma_bytes_per_call(B, L, H, C, form="stage_split",
                                   stream_dtype="int8")
     with pytest.raises(ValueError, match="forward procedure"):
-        rt_ops.dma_bytes_per_call(B, L, H, C, form="iteration",
+        rt_ops.dma_bytes_per_call(B, L, H, C, form="stage_split",
                                   early_exit_work_fraction=0.5)
     with pytest.raises(ValueError, match="in \\(0, 1\\]"):
         rt_ops.dma_bytes_per_call(B, L, H, C, form="procedure",
@@ -456,25 +421,15 @@ def test_vmem_model_early_exit_and_int8():
 
 
 def test_resolve_fusion_deep_edge_forms():
-    """int8 / early-exit resolve "auto" to "procedure" even for the
-    VMEM-overfull shape that fp32 auto sends to the iteration kernel, and
-    raise for the forms that cannot host them."""
-    big = (512, 1024, 32, 128)
-    assert rt_ops.resolve_fusion("auto", big, "fp32") == "iteration"
-    assert rt_ops.resolve_fusion("auto", big, "int8") == "procedure"
-    assert rt_ops.resolve_fusion("auto", big, "fp32",
-                                 early_exit=True) == "procedure"
-    # no shape needed: the deep-edge resolution is unconditional
-    assert rt_ops.resolve_fusion("auto", None, "int8") == "procedure"
-    with pytest.raises(ValueError, match="fusion='auto' or 'procedure'"):
-        rt_ops.resolve_fusion("iteration", big, "int8")
-    with pytest.raises(ValueError, match="fusion='auto' or 'procedure'"):
-        rt_ops.resolve_fusion("iteration", big, "fp32", early_exit=True)
+    """int8 / early-exit resolve shard-local to the megakernel, and raise
+    under a sharded plan, where the stage-split form cannot host them."""
+    assert rt_ops.resolve_fusion("int8") == "procedure"
+    assert rt_ops.resolve_fusion("fp32", early_exit=True) == "procedure"
+    assert rt_ops.resolve_fusion("int8", early_exit=True) == "procedure"
     with pytest.raises(ValueError, match="shard-local"):
-        rt_ops.resolve_fusion("auto", big, "int8", sharded=True)
+        rt_ops.resolve_fusion("int8", sharded=True)
     with pytest.raises(ValueError, match="shard-local"):
-        rt_ops.resolve_fusion("auto", big, "fp32", sharded=True,
-                              early_exit=True)
+        rt_ops.resolve_fusion("fp32", sharded=True, early_exit=True)
 
 
 def test_stage_update_fold_matches_split(key):
@@ -686,7 +641,7 @@ def test_procedure_bwd_dma_model():
     assert bw["total_bytes"] < bw["naive_bytes"]
     assert bw["backward"] is True and fw["backward"] is False
     with pytest.raises(ValueError, match="no custom VJP"):
-        rt_ops.dma_bytes_per_call(B, L, H, C, form="iteration",
+        rt_ops.dma_bytes_per_call(B, L, H, C, form="stage_split",
                                   backward=True)
 
 

@@ -1,12 +1,13 @@
 """Unified Router API (core.router): registry dispatch, plan="auto" vs the
 offline §5.1.2 planner, backend parity, sharded-vs-unsharded equivalence,
-legacy-shim equivalence, and the error surface."""
+the kernel form each plan resolves, and the error surface."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import compat
+from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
 from repro.core import distribution as D
 from repro.core import em_routing, routing
 from repro.core.router import (Algorithm, ExecutionPlan, RouterSpec,
@@ -32,7 +33,8 @@ def test_registry_has_both_paper_algorithms():
 
 
 def test_dispatch_dynamic_matches_legacy(u_hat):
-    """spec.algorithm='dynamic' == core.routing.dynamic_routing."""
+    """spec.algorithm='dynamic' == core.routing.dynamic_routing, the jnp
+    reference a RoutingConfig configures."""
     router = build_router(RouterSpec(algorithm="dynamic", iterations=3))
     want = routing.dynamic_routing(u_hat, routing.RoutingConfig(iterations=3))
     np.testing.assert_allclose(np.asarray(router(u_hat)), np.asarray(want),
@@ -159,11 +161,12 @@ def test_jnp_vs_pallas_backend_parity(key, use_approx):
 
 
 def test_pallas_matches_prerefactor_fused_path(key):
+    """The pallas router runs exactly the megakernel's public wrapper."""
     from repro.kernels.routing import ops as rt_ops
     u_hat = jax.random.normal(key, (2, 32, 6, 8))
     v = build_router(RouterSpec(backend="pallas", iterations=3))(u_hat)
-    want = rt_ops.dynamic_routing_fused(u_hat, iterations=3)
-    np.testing.assert_allclose(np.asarray(v), np.asarray(want), rtol=1e-6)
+    want = rt_ops.dynamic_routing_procedure_fused(u_hat, iterations=3)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -373,12 +376,11 @@ def test_auto_plan_may_pick_sharded_fused():
 # ---------------------------------------------------------------------------
 
 def test_fusion_procedure_matches_jnp(key):
-    """fusion='procedure' routes through the whole-procedure megakernel and
-    matches the jnp backend <=1e-5 (acceptance criterion)."""
+    """The shard-local pallas router runs the whole-procedure megakernel
+    and matches the jnp backend <=1e-5 (acceptance criterion)."""
     u = jax.random.normal(key, (2, 64, 6, 8))
     want = build_router(RouterSpec(iterations=3))(u)
-    router = build_router(RouterSpec(backend="pallas", iterations=3,
-                                     fusion="procedure"))
+    router = build_router(RouterSpec(backend="pallas", iterations=3))
     np.testing.assert_allclose(np.asarray(router(u)), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     resolved = router.resolve(u)
@@ -388,8 +390,7 @@ def test_fusion_procedure_matches_jnp(key):
 
 
 def test_fusion_auto_picks_procedure_when_unsharded(key):
-    """The default fusion='auto' resolves to the megakernel for a
-    shard-local plan whose VMEM working set fits."""
+    """A shard-local plan resolves to the megakernel."""
     u = jax.random.normal(key, (2, 64, 6, 8))
     router = build_router(RouterSpec(backend="pallas", iterations=3))
     assert router.resolve(u).fusion == "procedure"
@@ -404,7 +405,6 @@ def test_bf16_stream_through_router(key):
     u = jax.random.normal(key, (2, 64, 6, 8))
     want = build_router(RouterSpec(iterations=3))(u)
     router = build_router(RouterSpec(backend="pallas", iterations=3,
-                                     fusion="procedure",
                                      stream_dtype="bf16"))
     resolved = router.resolve(u)
     assert resolved.fusion == "procedure"
@@ -426,45 +426,43 @@ def test_resolve_reports_stage_split_and_jnp_none(u_hat):
     assert tuple(resolved) == (("L", "x"),)
     auto = build_router(RouterSpec(backend="pallas", iterations=3), "auto")
     r_auto = auto.resolve(u_hat)
-    assert r_auto.fusion in ("procedure", "iteration", "stage_split")
+    assert r_auto.fusion == ("stage_split" if tuple(r_auto)
+                             else "procedure")
     jnp_r = build_router(RouterSpec(iterations=3), "auto").resolve(u_hat)
     assert jnp_r.fusion is None and jnp_r.stream_dtype is None
 
 
 def test_resolve_without_args_static_plan(u_hat):
     """No-arg resolve() on a static plan keeps working (regression: it
-    raised IndexError reading shapes[0]); fusion resolves wherever the
-    votes shape isn't needed and reports None where it is."""
+    raised IndexError reading shapes[0]); the form needs no votes shape,
+    the differentiable router's backward fit does."""
     mesh = compat.make_mesh((1,), ("x",))
     sharded = build_router(RouterSpec(backend="pallas", iterations=3),
                            ExecutionPlan(mesh=mesh, axes=(("L", "x"),)))
     resolved = sharded.resolve()
     assert tuple(resolved) == (("L", "x"),)
     assert resolved.fusion == "stage_split"
-    forced = build_router(RouterSpec(backend="pallas", iterations=3,
-                                     fusion="procedure"))
-    assert forced.resolve().fusion == "procedure"
-    auto = build_router(RouterSpec(backend="pallas", iterations=3))
-    assert auto.resolve().fusion is None    # auto fit needs the votes shape
-    assert auto.resolve(u_hat).fusion == "procedure"
+    local = build_router(RouterSpec(backend="pallas", iterations=3))
+    assert local.resolve().fusion == "procedure"
+    assert local.resolve(u_hat).fusion == "procedure"
+    train = build_router(RouterSpec(backend="pallas", differentiable=True))
+    assert train.resolve().fusion is None
+    assert train.resolve(u_hat).fusion == "procedure"
 
 
 def test_fusion_and_stream_dtype_error_surface():
-    mesh = compat.make_mesh((1,), ("x",))
-    with pytest.raises(ValueError, match="shard-local"):
-        build_router(RouterSpec(backend="pallas", fusion="procedure"),
-                     ExecutionPlan(mesh=mesh, axes=(("L", "x"),)))
-    with pytest.raises(ValueError, match="unknown fusion"):
-        build_router(RouterSpec(backend="pallas", fusion="mega"))
+    """No option picks the kernel form (the code picks it from the plan);
+    stream_dtype is checked at build time."""
+    assert len(RouterSpec._fields) == 8
+    assert "fusion" not in RouterSpec._fields
+    assert "fused" not in routing.RoutingConfig._fields
     with pytest.raises(ValueError, match="unknown stream_dtype"):
         build_router(RouterSpec(backend="pallas", stream_dtype="fp16"))
-    with pytest.raises(ValueError, match="pallas-backend knob"):
-        build_router(RouterSpec(fusion="procedure"))          # jnp backend
-    with pytest.raises(ValueError, match="pallas-backend knob"):
-        build_router(RouterSpec(algorithm="em", backend="pallas",
-                                fusion="iteration"))          # em: no knob
     with pytest.raises(ValueError, match="requires the 'dynamic'"):
         build_router(RouterSpec(stream_dtype="bf16"))         # jnp backend
+    with pytest.raises(ValueError, match="requires the 'dynamic'"):
+        build_router(RouterSpec(algorithm="em", backend="pallas",
+                                stream_dtype="bf16"))         # em: fp32
 
 
 def test_deep_edge_error_surface():
@@ -482,12 +480,8 @@ def test_deep_edge_error_surface():
     with pytest.raises(ValueError, match="pallas-backend knob"):
         build_router(RouterSpec(algorithm="em", backend="pallas",
                                 early_exit_eps=0.1))
-    # both deep-edge knobs need the procedure megakernel ...
-    with pytest.raises(ValueError, match="procedure megakernel"):
-        build_router(pall._replace(fusion="iteration", early_exit_eps=0.1))
-    with pytest.raises(ValueError, match="procedure megakernel"):
-        build_router(pall._replace(fusion="iteration", stream_dtype="int8"))
-    # ... which is shard-local ...
+    # both deep-edge knobs live in the procedure megakernel, which is
+    # shard-local ...
     sharded = ExecutionPlan(mesh=mesh, axes=(("L", "x"),))
     with pytest.raises(ValueError, match="shard-local"):
         build_router(pall._replace(early_exit_eps=0.1), sharded)
@@ -535,56 +529,22 @@ def test_deep_edge_resolved_plan_roundtrip(key):
     assert sh.resolve(u_hat).early_exit_eps is None
 
 
-def test_legacy_fused_sharded_delegates(u_hat):
-    """RoutingConfig(fused=True) + sharded dims now runs the sharded-fused
-    path through the legacy shims (previously a ValueError)."""
-    mesh = compat.make_mesh((1,), ("x",))
-    want = routing.dynamic_routing(u_hat, routing.RoutingConfig(iterations=3))
-    cfg = routing.RoutingConfig(iterations=3, fused=True)
-    routed = routing.make_sharded_routing(mesh, "L", "x", cfg)
-    np.testing.assert_allclose(np.asarray(routed(u_hat)), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-    pose_ref, act_ref = em_routing.em_routing(*_em_like(u_hat))
-    votes, a_in = _em_like(u_hat)
-    routed_em = em_routing.make_sharded_em_routing(mesh, "L", "x",
-                                                   backend="pallas")
-    pose, act = routed_em(votes, a_in)
-    np.testing.assert_allclose(np.asarray(pose), np.asarray(pose_ref),
-                               rtol=1e-4, atol=1e-5)
-
-
-def _em_like(u_hat):
-    key = jax.random.PRNGKey(7)
-    votes = jax.random.normal(key, (4, 32, 5, 8))
-    a_in = jax.nn.sigmoid(jax.random.normal(jax.random.fold_in(key, 1),
-                                            (4, 32)))
-    return votes, a_in
-
-
-# ---------------------------------------------------------------------------
-# legacy shims
-# ---------------------------------------------------------------------------
-
-
-def test_legacy_shims_delegate_to_router(u_hat, em_inputs):
-    """make_sharded_routing / make_sharded_em_routing still work and agree
-    with the pre-refactor semantics (they now build Routers internally)."""
-    mesh = compat.make_mesh((1,), ("x",))
-    cfg = routing.RoutingConfig(iterations=3)
-    want = routing.dynamic_routing(u_hat, cfg)
-    routed = routing.make_sharded_routing(mesh, "L", "x", cfg)
-    np.testing.assert_allclose(np.asarray(routed(u_hat)), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    routed2 = routing.make_multi_sharded_routing(
-        mesh, (("B", "x"),), cfg)
-    np.testing.assert_allclose(np.asarray(routed2(u_hat)), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    votes, a_in = em_inputs
-    pose_ref, act_ref = em_routing.em_routing(votes, a_in)
-    routed3 = em_routing.make_sharded_em_routing(mesh, "L", "x")
-    pose, act = routed3(votes, a_in)
-    np.testing.assert_allclose(np.asarray(pose), np.asarray(pose_ref),
-                               rtol=1e-5, atol=1e-6)
+@pytest.mark.parametrize("network", sorted(CAPS_BENCHMARKS))
+def test_table1_resolves_procedure_shard_local(network):
+    """Every Table-1 network, from the serving microbatch to B = 1000,
+    runs the megakernel shard-local — at whatever L tile the VMEM model
+    leaves it — and the stage-split form under a sharded plan."""
+    c = CAPS_BENCHMARKS[network]
+    local = build_router(RouterSpec(backend="pallas",
+                                    iterations=c.routing_iters))
+    sharded = build_router(
+        local.spec, ExecutionPlan(mesh=compat.make_mesh((1,), ("x",)),
+                                  axes=(("B", "x"),)))
+    for B in (8, 100, 256, 1000):
+        votes = jax.ShapeDtypeStruct(
+            (B, c.num_l_caps, c.num_h_caps, c.h_caps_dim), jnp.float32)
+        assert local.resolve(votes).fusion == "procedure", B
+        assert sharded.resolve(votes).fusion == "stage_split", B
 
 
 # ---------------------------------------------------------------------------
@@ -601,7 +561,7 @@ def test_capsnet_forward_router_kwarg(key):
                                  cfg.image_channels))
     out_legacy = capsnet.forward(
         params, images, cfg,
-        routing_cfg=routing.RoutingConfig(iterations=cfg.routing_iters))
+        router=routing.RoutingConfig(iterations=cfg.routing_iters))
     out_router = capsnet.forward(
         params, images, cfg,
         router=build_router(RouterSpec(iterations=cfg.routing_iters)))
@@ -680,16 +640,13 @@ def test_differentiable_auto_plan_resolves_shard_local(u_hat):
 
 def test_differentiable_validation_errors():
     """The documented composition errors: sharded/pipelined plans,
-    use_approx, fusion='iteration', and non-dynamic algorithms have no
-    custom VJP."""
+    use_approx, and non-dynamic algorithms have no custom VJP."""
     spec = RouterSpec(backend="pallas", differentiable=True)
     mesh = compat.make_mesh((jax.device_count(),), ("x",))
     with pytest.raises(ValueError, match="shard-local"):
         build_router(spec, ExecutionPlan(mesh=mesh, axes=(("L", "x"),)))
     with pytest.raises(ValueError, match="no derivative"):
         build_router(spec._replace(use_approx=True))
-    with pytest.raises(ValueError, match="no custom VJP"):
-        build_router(spec._replace(fusion="iteration"))
     with pytest.raises(ValueError, match="'dynamic' algorithm"):
         build_router(RouterSpec(algorithm="em", backend="pallas",
                                 differentiable=True))
@@ -717,7 +674,6 @@ def test_differentiable_resolution_checks_backward_fit():
     fit (the larger working set): Caps-MN1 at its Table-1 batch trains
     through the custom VJP, while Caps-EN3 (H·C = 992 lanes) at batch 100
     has a backward that no tile fits and falls back to jnp autodiff."""
-    from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
     from repro.kernels.routing import ops as rt_ops
     router = build_router(RouterSpec(backend="pallas", differentiable=True))
     for name, fused in (("Caps-MN1", True), ("Caps-EN3", False)):

@@ -137,6 +137,7 @@ def test_sharded_em_routing():
     run_ok("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import em_routing
+from repro.core.router import ExecutionPlan, RouterSpec, build_router
 from repro.runtime.mesh_utils import make_mesh
 mesh = make_mesh((8,), ('x',))
 key = jax.random.PRNGKey(0)
@@ -144,7 +145,8 @@ votes = jax.random.normal(key, (8, 64, 4, 8))
 a_in = jax.nn.sigmoid(jax.random.normal(key, (8, 64)))
 pose_ref, act_ref = em_routing.em_routing(votes, a_in)
 for dim in ('B', 'L'):
-    routed = em_routing.make_sharded_em_routing(mesh, dim, 'x')
+    routed = build_router(RouterSpec(algorithm='em'),
+                          ExecutionPlan(mesh=mesh, axes=((dim, 'x'),)))
     pose, act = jax.jit(routed)(votes, a_in)
     np.testing.assert_allclose(np.asarray(pose), np.asarray(pose_ref),
                                rtol=2e-4, atol=2e-5)

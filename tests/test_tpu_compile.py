@@ -1,7 +1,8 @@
 """Compile the routing kernels of the main path for a described TPU v5e.
 
 No chip is attached: the TPU compiler is installed, so each test lowers a
-kernel at Caps-MN1 widths (L=1152, H=10, C=16) — and the EM stage kernels
+kernel at Caps-MN1 widths (L=1152, H=10, C=16) — the megakernel also at the
+Table-1 shapes that leave it its smallest tiles, and the EM stage kernels
 at the matrix-capsule cell's shapes — for the first device of a described
 ``v5e:2x2`` topology and compiles it — Mosaic refuses here what
 it would refuse on the chip (shape casts it cannot lay out, blocks that
@@ -72,6 +73,24 @@ def test_procedure_forward_compiles(one_chip, B, stream_dtype):
                                iterations=3, stream_dtype=stream_dtype,
                                interpret=False),
              _votes(one_chip, B))
+
+
+# The shapes at which the megakernel's VMEM model leaves it the smallest
+# tiles among the Table-1 networks: the widest row (Caps-EN3, H·C = 992)
+# at B = 256, and the largest batch at the narrowest and the longest L.
+EDGE_SHAPES = {"Caps-EN3": 256, "Caps-MN1": 1000, "Caps-CF3": 1000}
+
+
+@pytest.mark.parametrize("network", sorted(EDGE_SHAPES))
+def test_procedure_forward_compiles_at_edge_shapes(one_chip, network):
+    c = CAPS_BENCHMARKS[network]
+    shape = (EDGE_SHAPES[network], c.num_l_caps, c.num_h_caps, c.h_caps_dim)
+    text = _compile(functools.partial(rt_ops.dynamic_routing_procedure_fused,
+                                      iterations=c.routing_iters,
+                                      interpret=False),
+                    jax.ShapeDtypeStruct(shape, jnp.float32,
+                                         sharding=one_chip))
+    assert "routing_procedure_fused" in text
 
 
 @pytest.mark.parametrize("B", BATCHES)
