@@ -70,10 +70,28 @@ def init_caps_layer(key, n_l: int, n_h: int, c_l: int, c_h: int):
                                    jnp.float32) * scale}
 
 
+def pack_vote_weights(w: jax.Array) -> jax.Array:
+    """W (L, H, C_L, C_H) -> its lane-packed view (L, C_L, H·C_H)."""
+    n_l, n_h, c_l, c_h = w.shape
+    return w.transpose(0, 2, 1, 3).reshape(n_l, c_l, n_h * c_h)
+
+
+def project_votes(u: jax.Array, w_packed: jax.Array, n_h: int) -> jax.Array:
+    """Eq.1 against W's lane-packed view: u (B, L, C_L) -> û (B, L, H, C_H).
+
+    The matmul writes û lane-dense, (B, L, H·C_H), the layout the routing
+    kernels read (``kernel._pack`` undoes the final reshape as a bitcast);
+    the (L, H, C_L, C_H) form writes it with H on the lanes, padded to
+    128, and every consumer then pays two relayout copies."""
+    votes = jnp.einsum("blc,lck->blk", u, w_packed)
+    return votes.reshape(votes.shape[:2] + (n_h, votes.shape[-1] // n_h))
+
+
 def predict_votes(params, u: jax.Array) -> jax.Array:
     """Eq.1: u_hat[k,i,j] = u[k,i] @ W[i,j].   u:(B,L,C_L) -> (B,L,H,C_H)."""
     with jax.named_scope("capsnet.encode"):
-        return jnp.einsum("blc,lhcd->blhd", u, params["W"])
+        w = params["W"]
+        return project_votes(u, pack_vote_weights(w), w.shape[1])
 
 
 def caps_layer_forward(params, u: jax.Array, route) -> jax.Array:

@@ -71,23 +71,40 @@ def software_pipeline_scan(stage_a: Callable, stage_b: Callable,
 
     micro_inputs: pytree of (n_micro, ...) stacked microbatches (a bare
     array is the single-leaf case).  stage_a's output is handed to stage_b
-    as-is — return a tuple for a multi-input stage B (EM's (votes, a_in))
-    and it crosses the carry whole.  stage_b may itself be a shard_map
-    program (a sharded routing stage) — collectives trace fine under the
-    scan — and may return a pytree (EM's (pose, a_out)).  Returns stacked
-    stage_b outputs, each leaf (n_micro, ...).
+    with its values and shapes as returned — return a tuple for a
+    multi-input stage B (EM's (votes, a_in)) and it crosses the carry
+    whole.  stage_b may itself be a shard_map program (a sharded routing
+    stage) — collectives trace fine under the scan — and may return a
+    pytree (EM's (pose, a_out)).  Returns stacked stage_b outputs, each
+    leaf (n_micro, ...).
+
+    The carry holds each vote leaf (rank >= 4, (B, L, H, C)) with its two
+    minor dims merged, (B, L, H·C), and stage_b gets the shape back: a
+    4-D carry is laid out with its small minor dims padded to full tiles,
+    which costs a relayout copy on each side of the hand-off, while
+    (B, L, H·C) is the lane-packed layout the routing kernels read, so a
+    producer that writes it (``capsule_layers.project_votes``) crosses
+    the carry with no copy.  Leaves of lower rank (EM's (B, L) a_in)
+    cross as they are.
     """
     a0 = stage_a(_at(micro_inputs, 0))
     rest = jax.tree.map(lambda x: x[1:], micro_inputs)
 
-    def tick(carry, x_next):
-        prev_a = carry
-        b_out = stage_b(prev_a)          # bubble-filled stage B
-        a_out = stage_a(x_next)          # independent of b_out
-        return a_out, b_out
+    def carried(h):
+        return jax.tree.map(
+            lambda x: x.reshape(x.shape[:-2] + (-1,)) if x.ndim >= 4 else x,
+            h)
 
-    last_a, outs = lax.scan(tick, a0, rest)
-    final = stage_b(last_a)
+    def handed(h):
+        return jax.tree.map(lambda x, like: x.reshape(like.shape), h, a0)
+
+    def tick(carry, x_next):
+        b_out = stage_b(handed(carry))   # bubble-filled stage B
+        a_out = stage_a(x_next)          # independent of b_out
+        return carried(a_out), b_out
+
+    last_a, outs = lax.scan(tick, carried(a0), rest)
+    final = stage_b(handed(last_a))
     return jax.tree.map(lambda o, f: jnp.concatenate([o, f[None]], axis=0),
                         outs, final)
 

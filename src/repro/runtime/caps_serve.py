@@ -47,11 +47,14 @@ and score classes as the EM output activations ``a_out``.
 Padding note (DESIGN.md §Serving): the routing logits ``b`` are shared
 across the batch (the paper's Table-2 B-dim aggregation), so batch lanes
 couple through Eq.4 and naive zero-image padding would perturb real lanes
-once biases are non-zero.  The encoder stage therefore multiplies the votes
-by a per-lane mask — masked lanes contribute exactly zero to every
-cross-lane aggregation, making padding bit-invariant for the real lanes.
-(EM keeps no cross-batch state, but the same mask zeroes a padded lane's
-input activations so its votes never weight any Gaussian.)
+once biases are non-zero.  The encoder stage therefore multiplies the
+primary capsules u by a per-lane 0/1 mask before the Eq.1 projection — a
+masked lane's votes are exactly zero, so it contributes exactly zero to
+every cross-lane aggregation, making padding bit-invariant for the real
+lanes.  (Masking u rather than the votes lets the projection write the
+votes straight in the kernels' lane-packed layout.)  (EM keeps no
+cross-batch state, but the same mask zeroes a padded lane's input
+activations so its votes never weight any Gaussian.)
 
     server = CapsServer(params, caps_cfg)
     server.submit(images)           # any count, any tick, any thread
@@ -85,6 +88,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import capsule_layers as CL
 from repro.core import router as router_lib
 from repro.models import capsnet, matrix_capsnet
 from repro.runtime import wave_serve
@@ -132,8 +136,10 @@ def make_wave_fn(params, caps_cfg, spec: Optional[router_lib.RouterSpec],
           "mask":   (n_micro, microbatch)}) -> class_scores
                                                (n_micro, microbatch, N_H)
 
-    The encoder stage masks the Eq.1 votes per lane (padding invariance,
-    see module docstring) and the routing stage runs through
+    The encoder stage masks the primary capsules per lane before the Eq.1
+    projection (padding invariance, see module docstring), projecting
+    through W's lane-packed view built here once, and the routing stage
+    runs through
     ``core.router.build_router`` — pipelined per ``cfg.pipeline``, with the
     routing distribution per ``cfg.routing_plan``.  ``spec.algorithm``
     selects the stage hand-off: "dynamic" hands the pipeline the votes and
@@ -145,11 +151,14 @@ def make_wave_fn(params, caps_cfg, spec: Optional[router_lib.RouterSpec],
     if spec is None:
         spec = router_lib.RouterSpec(iterations=caps_cfg.routing_iters)
     algo = router_lib.get_algorithm(spec.algorithm)
+    # packed once per build, never per microbatch
+    w_votes = CL.pack_vote_weights(params["digit"]["W"])
 
     def encode(micro):
         with jax.named_scope("capsnet.encode"):
-            votes = capsnet.encode_votes(params, micro["images"], caps_cfg)
-            return votes * micro["mask"][:, None, None, None]
+            u = capsnet.primary_caps(params, micro["images"], caps_cfg)
+            u = u * micro["mask"][:, None, None]
+            return CL.project_votes(u, w_votes, caps_cfg.num_h_caps)
 
     if algo.num_inputs == 1:
         stage_a = encode
@@ -203,7 +212,7 @@ class CapsAdapter(wave_serve.WorkloadAdapter):
     """CapsNet classification as a ``WorkloadAdapter`` (DESIGN.md
     §WaveServe): payloads are ``(H, W, C)`` float32 images, the wave
     executable is ``make_wave_fn``'s §4 pipeline, packing zero-pads the
-    tail microbatch with a per-lane vote mask (bit-invariant, see module
+    tail microbatch with a per-lane mask (bit-invariant, see module
     docstring), and completions are argmax class predictions over the wave
     scores.  The output-guard reference is the jnp reference spec
     (``core.router.reference_spec``) — the same fallback target as the

@@ -8,8 +8,9 @@ import pytest
 
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS, smoke_caps
 from repro.core import capsule_layers as CL
+from repro.core.approx import exact_squash
 from repro.core import pipeline, routing
-from repro.core.router import RouterSpec
+from repro.core.router import RouterSpec, build_router
 from repro.data.synthetic import SyntheticCapsDataset
 from repro.models import capsnet
 
@@ -116,23 +117,69 @@ def test_caps_table1_configs_complete():
     assert sv3.routing_iters == 9 and sv3.num_l_caps == 576
 
 
-def test_software_pipeline_matches_sequential(key):
-    """paper §4 pipeline: overlapped schedule == sequential composition."""
+@pytest.mark.parametrize("network", ["Caps-MN1", "Caps-EN3"])
+def test_predict_votes_packed_matches_eq1(key, network):
+    """Eq.1 through W's lane-packed view (L, C_L, H·C_H) == the plain
+    (L, H, C_L, C_H) contraction, on squashed primary capsules."""
+    c = CAPS_BENCHMARKS[network]
+    ku, kw = jax.random.split(key)
+    u = exact_squash(jax.random.normal(ku, (4, c.num_l_caps, c.l_caps_dim)))
+    params = CL.init_caps_layer(kw, c.num_l_caps, c.num_h_caps,
+                                c.l_caps_dim, c.h_caps_dim)
+    with jax.default_matmul_precision("highest"):
+        got = CL.predict_votes(params, u)
+        want = jnp.einsum("blc,lhcd->blhd", u, params["W"])
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-6)
+
+
+def _scan_carries(fn, *args):
+    """Carry shapes of each top-level scan in fn's jaxpr."""
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return [tuple(v.aval.shape for v in e.invars[
+                e.params["num_consts"]:
+                e.params["num_consts"] + e.params["num_carry"]])
+            for e in eqns if e.primitive.name == "scan"]
+
+
+@pytest.mark.parametrize("hand_off", ["votes", "em"])
+def test_software_pipeline_matches_sequential(key, hand_off):
+    """paper §4 pipeline: overlapped schedule == sequential composition,
+    for a bare-votes hand-off (dynamic routing) and EM's (votes, a_in)
+    tuple; the scan carries the votes lane-packed, (B, L, H·C), and a_in
+    as it is."""
     cfg = smoke_caps()
     params = capsnet.init_capsnet(key, cfg)
     ds = SyntheticCapsDataset(cfg.image_hw, cfg.image_channels,
                               cfg.num_h_caps)
-    micro = jnp.stack([jnp.asarray(ds.batch(i, 4)["images"])
+    B = 4
+    micro = jnp.stack([jnp.asarray(ds.batch(i, B)["images"])
                        for i in range(3)])
-    rc = routing.RoutingConfig(iterations=cfg.routing_iters)
+    packed = (B, cfg.num_l_caps, cfg.num_h_caps * cfg.h_caps_dim)
 
     def stage_a(images):  # "host": conv + primary caps + votes
         u = capsnet.primary_caps(params, images, cfg)
-        return CL.predict_votes(params["digit"], u)
+        votes = CL.predict_votes(params["digit"], u)
+        if hand_off == "votes":
+            return votes
+        return votes, jax.nn.sigmoid(jnp.sum(u, axis=-1))
 
-    def stage_b(u_hat):   # "PIM": routing procedure
-        return routing.dynamic_routing(u_hat, rc)
+    if hand_off == "votes":
+        rc = routing.RoutingConfig(iterations=cfg.routing_iters)
+        stage_b = lambda u_hat: routing.dynamic_routing(u_hat, rc)  # noqa
+        carry = (packed,)
+    else:
+        core = build_router(RouterSpec(algorithm="em", iterations=2))
+        stage_b = lambda h: core(*h)                                # noqa
+        carry = (packed, (B, cfg.num_l_caps))
 
-    piped = pipeline.software_pipeline_scan(stage_a, stage_b, micro)
-    seq = jnp.stack([stage_b(stage_a(m)) for m in micro])
-    np.testing.assert_allclose(piped, seq, rtol=1e-5, atol=1e-6)
+    run = lambda m: pipeline.software_pipeline_scan(  # noqa: E731
+        stage_a, stage_b, m)
+    assert carry in _scan_carries(run, micro)
+    piped = run(micro)
+    seq = [stage_b(stage_a(m)) for m in micro]
+    for got, *want in zip(jax.tree.leaves(piped),
+                          *map(jax.tree.leaves, seq)):
+        np.testing.assert_allclose(got, jnp.stack(want),
+                                   rtol=1e-5, atol=1e-6)

@@ -61,6 +61,19 @@ def _micro(cfg, images, mask, n_micro, microbatch):
                 (n_micro, microbatch))}
 
 
+def _assert_pad_content_invariant(wave, cfg, padded, mask, got):
+    """The real lanes of a one-microbatch wave are bit-identical whatever
+    the padded lanes hold: the mask zeroes their capsules before Eq.1."""
+    n = int(mask.sum())
+    rng = np.random.default_rng(7)
+    for fill in (rng.uniform(0.0, 1.0, padded.shape),
+                 rng.normal(0.0, 50.0, padded.shape)):
+        other = np.where(mask[:, None, None, None] > 0, padded,
+                         fill.astype(np.float32))
+        again = wave(_micro(cfg, other, mask, 1, len(mask)))[0, :n]
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
 def test_padding_invariance(setup):
     """Padded lanes never change real outputs — even though routing couples
     batch lanes through the shared b logits and the (biased) encoder maps
@@ -84,6 +97,7 @@ def test_padding_invariance(setup):
     mask = np.zeros((microbatch,), np.float32)
     mask[:3] = 1.0
     got = wave(_micro(cfg, padded, mask, n_micro, microbatch))[0, :3]
+    _assert_pad_content_invariant(wave, cfg, padded, mask, got)
 
     ref_wave = make_wave_fn(params, cfg, None,
                             ServeConfig(microbatch=3, n_micro=1,
@@ -339,6 +353,7 @@ def test_em_padding_invariance(setup):
                         ServeConfig(microbatch=microbatch, n_micro=1,
                                     pipeline="software"))
     got = wave(_micro(cfg, padded, mask, 1, microbatch))[0, :3]
+    _assert_pad_content_invariant(wave, cfg, padded, mask, got)
     ref_wave = make_wave_fn(params, cfg, spec,
                             ServeConfig(microbatch=3, n_micro=1,
                                         pipeline="software"))

@@ -11,9 +11,11 @@ with ``interpret=False``; nothing runs.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
-imports this file.
+imports this file.  The Caps-EN3 serving wave is compiled whole, to check
+how its votes reach the routing kernel.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +26,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import compat
 from repro.configs.caps_benchmarks import CAPS_BENCHMARKS
+from repro.core import router as rt_router
 from repro.core.em_routing import ConvGeometry
 from repro.kernels.routing import kernel as rt_kernel
 from repro.kernels.routing import ops as rt_ops
+from repro.models import capsnet
+from repro.runtime.caps_serve import make_wave_fn
+from repro.runtime.wave_serve import ServeConfig
 
 MN1 = CAPS_BENCHMARKS["Caps-MN1"]
 L, H, C = MN1.num_l_caps, MN1.num_h_caps, MN1.h_caps_dim
@@ -176,3 +182,51 @@ def test_em_l_sharded_compiles(topo):
     text = _compile(compat.shard_map(routed, mesh, in_specs=(spec, spec),
                                      out_specs=(P(), P())), *structs)
     assert "em_stage_stats" in text and "em_stage_estep" in text
+
+
+def _relayout_passes(text: str, lead: str) -> int:
+    """Copies and loop fusions, outside fused computations, that write an
+    f32 array whose leading dims are ``lead``: each is one pass over it
+    that computes nothing the kernels need."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    count, body = 0, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head:
+            body = head.group(1)
+        elif body not in fused and re.match(
+                rf"\s*(?:ROOT )?%\S+ = f32\[{lead},[\d,]+\]\S* "
+                r"(copy\(|fusion\(.*kind=kLoop)", line):
+            count += 1
+    return count
+
+
+def test_serving_wave_hands_votes_lane_packed(one_chip, monkeypatch):
+    """The en3-serve-rate wave (Caps-EN3, microbatch 8, software pipeline):
+    the votes û reach each routing call after at most one relayout pass,
+    and no array of them has H = 62 on the lanes (padded to 128)."""
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "pallas_interpret_mode",
+                        lambda: False)
+    jax.clear_caches()           # no kernel traced in interpret mode
+    cfg = CAPS_BENCHMARKS["Caps-EN3"]
+    mb, n_micro = 8, 2
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: capsnet.init_capsnet(jax.random.key(0),
+                                                    cfg)))
+    micro = {"images": jax.ShapeDtypeStruct((n_micro, mb, 28, 28, 1),
+                                            jnp.float32, sharding=one_chip),
+             "mask": jax.ShapeDtypeStruct((n_micro, mb), jnp.float32,
+                                          sharding=one_chip)}
+    spec = rt_router.RouterSpec(backend="pallas",
+                                iterations=cfg.routing_iters)
+    scfg = ServeConfig(microbatch=mb, n_micro=n_micro, pipeline="software")
+    text = _compile(lambda p, m: make_wave_fn(p, cfg, spec, scfg)(m),
+                    params, micro)
+    H, C = cfg.num_h_caps, cfg.h_caps_dim
+    assert f"[{mb},{cfg.num_l_caps},{H},{C}]" not in text
+    calls = len(re.findall(r"= f32\[\d+,\d+\]\S* custom-call\(.*"
+                           r"routing_procedure_fused", text))
+    assert calls == n_micro
+    assert _relayout_passes(text, f"{mb},{cfg.num_l_caps}") <= calls
